@@ -2,9 +2,9 @@
 
 An implicit device→host sync — a stray ``float(loss)``, ``np.asarray(out)``,
 ``if x:`` on a device value — blocks the dispatch pipeline for a full device
-round-trip (a network RTT on a tunneled chip) and serializes the driver loop
-against device compute.  One of them inside the per-iteration hot loop undoes
-the entire dispatch-pipelining design.
+round-trip and serializes the driver loop against device compute.  One of
+them inside the per-iteration hot loop undoes the entire
+dispatch-pipelining design.
 
 Two detection tiers, both scoped to the ARMED region on the ARMING thread:
 
@@ -165,11 +165,7 @@ def host_pull(x: Any, what: str = "") -> Any:
     st = _tls()
     st.allow += 1
     try:
-        try:
-            ctx = jax.transfer_guard_device_to_host("allow")
-        except Exception:  # pragma: no cover - very old jax
-            ctx = contextlib.nullcontext()
-        with ctx:
+        with jax.transfer_guard_device_to_host("allow"):
             out = jax.device_get(x)
     finally:
         st.allow -= 1
@@ -217,11 +213,7 @@ class HostSyncGuard:
             # device_get stays allowed in both — that is the choke point.
             guard_level = "disallow" if self.mode == "strict" else "log"
             try:
-                ctx = jax.transfer_guard_device_to_host(guard_level)
-            except Exception:  # pragma: no cover - very old jax
-                ctx = contextlib.nullcontext()
-            try:
-                with ctx:
+                with jax.transfer_guard_device_to_host(guard_level):
                     yield
             except RuntimeError as e:
                 # the runtime-level guard (tier one, real accelerators)

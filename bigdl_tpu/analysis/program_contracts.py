@@ -299,6 +299,12 @@ def lm_full_contract() -> StepContract:
     return StepContract(label="lm_full", collectives=())
 
 
+def lm_kv_scrub_contract() -> StepContract:
+    """The paged KV cache's block scrub: a fixed-shape zero scatter
+    into both pools, single-device — collective-free."""
+    return StepContract(label="lm_kv_scrub", collectives=())
+
+
 def default_contracts() -> Dict[str, StepContract]:
     """Canonical contracts for every known family — what the OFFLINE
     auditor (``python -m bigdl_tpu.analysis.hlo_audit <cacheDir>``)
@@ -325,4 +331,5 @@ def default_contracts() -> Dict[str, StepContract]:
         "lm_decode": lm_decode_contract("lm_decode"),
         "lm_decode_int8": lm_decode_contract("lm_decode_int8"),
         "lm_full": lm_full_contract(),
+        "lm_kv_scrub": lm_kv_scrub_contract(),
     }

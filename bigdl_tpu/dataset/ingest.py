@@ -5,9 +5,9 @@ the production ImageNet lesson that host-side batch prep must overlap both
 itself (decode vs assemble) and device compute.  The synchronous
 :class:`~bigdl_tpu.dataset.mt_batch.MTLabeledBGRImgToBatch` executes
 read → decode → assemble serially *per batch* (``pool.map`` is a batch
-barrier, assemble runs while the pool sits idle); BENCH_r05 measured that
-structure at 0.56x of the decode-alone ceiling.  This module removes the
-barriers:
+barrier, assemble runs while the pool sits idle); round 5 measured that
+structure at 0.56x of the decode-alone ceiling (host-side stage rates;
+round-5 record, removed in PR 21).  This module removes the barriers:
 
     sharded seqfile readers ──► record ring ──► decode pool ──► ordered
     decoded window ──► assembler (native pack, GIL-released) ──► batch
@@ -79,7 +79,6 @@ Configuration (``bigdl.ingest.*``, see ``utils/config.py``):
                                  scaling (:class:`AutoscalePolicy`)
 ``bigdl.ingest.epochCache*``     decoded-frame cache across epochs
                                  (``dataset/epoch_cache.py``)
-``bigdl.ingest.zeroCopyUpload``  dlpack handoff at ``engine.to_device``
 ===============================  =============================================
 """
 

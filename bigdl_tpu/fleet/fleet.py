@@ -79,6 +79,9 @@ class _Service:
         self._active: List[Replica] = []      # guarded-by: _lock
         #: (handle, replica) for every admitted request not yet tallied
         self._pending: List[Tuple[RequestHandle, Replica]] = []  # guarded-by: _lock
+        #: handles a sweeper has swapped out of ``_pending`` and not yet
+        #: tallied — still pending to anyone asking (``quiesce``)
+        self._sweeping = 0                              # guarded-by: _lock
         self._counts: Dict[str, int] = dict.fromkeys(OUTCOMES, 0)  # guarded-by: _lock
         self._counts["submitted"] = 0
         self._rr = 0                 # guarded-by: _lock
@@ -194,7 +197,7 @@ class _Service:
 
     def pending_count(self) -> int:
         with self._lock:
-            return len(self._pending)
+            return len(self._pending) + self._sweeping
 
     def sweep(self) -> None:
         """Tally terminal handles into the service counts; abandon the
@@ -203,6 +206,11 @@ class _Service:
         sweeper owns the batch it swapped out."""
         with self._lock:
             batch, self._pending = self._pending, []
+            # the batch stays counted as pending until its tally lands:
+            # a concurrent quiesce() that found _pending empty while
+            # this sweeper was still writing an incident bundle would
+            # report an exact identity that is a whole batch short
+            self._sweeping += len(batch)
             cut_ns, cut_version = self._cut_ns, self._cut_version
         keep: List[Tuple[RequestHandle, Replica]] = []
         tally: Dict[str, int] = {}
@@ -264,6 +272,7 @@ class _Service:
             for k, v in tally.items():
                 self._counts[k] += v
             self._pending.extend(keep)
+            self._sweeping -= len(batch)
             if first_serve_ms is not None and self._cut_ns == cut_ns:
                 self.last_swap_to_serve_ms = first_serve_ms
                 self._cut_ns = None

@@ -1,22 +1,32 @@
 """Deterministic training-state fingerprints, on device and on host.
 
-A fingerprint is a ``[compensated sum, random projection]`` pair over
-every float element of a tree: the sum catches gross value corruption,
-the seed-fixed ±1 (Rademacher) projection catches compensating or
-permuting corruptions the plain sum cancels.  Both reduce on device in
-the accumulator dtype (f64 when x64 is enabled; f32 otherwise — the
-strict HLO precision audit flags ANY f64 op and tier-1 runs x64-off),
-and no host pull happens here: the driver reads fingerprints only
-through the explicit ``analysis.host_pull`` choke point.
+A fingerprint is a ``[sum, random projection]`` pair over every float
+element of a tree: the sum catches gross value corruption, the
+seed-fixed ±1 (Rademacher) projection catches compensating or permuting
+corruptions the plain sum cancels.  No host pull happens here: the
+driver reads fingerprints only through the explicit
+``analysis.host_pull`` choke point.
+
+On DEVICE both components are sums of the elements' IEEE-754 BIT
+PATTERNS in wrapping ``uint32`` arithmetic, not of their float values.
+Continuity compares a tree fingerprinted at the END of step k with the
+same bits fingerprinted at the START of step k+1 and demands bitwise
+equality, but XLA reduces the two sites in whatever order its fusion
+and (under GSPMD) partitioning decisions give each — and a float sum
+depends on that order (on jax 0.9.0 a clean GSPMD run broke continuity
+by one ulp at iteration 2).  Integer addition mod 2**32 is associative
+and commutative, so every reduction order, fusion and shard layout
+yields the identical fingerprint.  The two 32-bit sums are folded to 24
+bits each so they travel exactly in the float32 carry.
 
 The projection signs are NOT an embedded constant table: they are
 recomputed from ``iota`` with a multiplicative xorshift hash (~5 integer
 ops per element), pure in ``(position, seed)``, so the traced program
 stays O(1) in parameter count and the host mirror
-(:func:`host_fingerprint`) reproduces the identical sign stream with
-numpy.  Host and device fingerprints are each SELF-consistent (same
-algorithm, same seed ⇒ same value for the same bits) but are never
-compared to each other — summation order differs across backends.
+(:func:`host_fingerprint`, float sums in numpy's one fixed order)
+draws the identical sign stream.  Host and device fingerprints are each
+SELF-consistent (same algorithm, same seed ⇒ same value for the same
+bits) but are never compared to each other.
 
 Also here: :func:`first_nonfinite`, the diagnosed flavor of the
 divergence guard's ``all_finite`` — same per-leaf reductions, plus an
@@ -51,14 +61,21 @@ def acc_dtype():
     return jnp.float64 if jax.config.jax_enable_x64 else jnp.float32
 
 
-def _device_signs(n: int, seed: int):
-    """±1 signs for positions 0..n-1, pure in ``(n, seed)``."""
+def _device_negate(n: int, seed: int):
+    """True where position 0..n-1 draws the sign -1, pure in
+    ``(n, seed)``."""
     i = jax.lax.iota(jnp.uint32, n)
     x = (i * _MIX1) ^ np.uint32(seed & 0xFFFFFFFF)
     x = (x ^ (x >> 15)) * _MIX2
     x = (x ^ (x >> 13)) * _MIX3
     x = x ^ (x >> 16)
-    return 1.0 - 2.0 * (x >> 31).astype(acc_dtype())
+    return (x >> 31).astype(jnp.bool_)
+
+
+def _device_signs(n: int, seed: int):
+    """±1 signs for positions 0..n-1 (the stream :func:`_host_signs`
+    mirrors)."""
+    return jnp.where(_device_negate(n, seed), -1.0, 1.0).astype(acc_dtype())
 
 
 def _host_signs(n: int, seed: int) -> np.ndarray:
@@ -72,43 +89,56 @@ def _host_signs(n: int, seed: int) -> np.ndarray:
     return 1.0 - 2.0 * (x >> np.uint32(31)).astype(np.float64)
 
 
-def fingerprint_flat(vec, seed: int):
-    """``(2,)`` ``[sum, projection]`` of one flat float vector, in the
-    accumulator dtype.  Zero padding contributes exactly zero to both
-    components, so padded flat parameter vectors fingerprint their
-    payload.
-
-    The reductions run behind an ``optimization_barrier``: continuity
-    compares a value fingerprinted at the END of step k (where the
-    producer may be a concatenate/all-gather XLA would happily fuse the
-    reduce into, reassociating the float sum) against the SAME bits
-    fingerprinted at the START of step k+1 (a plain program input).
-    Bitwise equality needs both sites to reduce a materialized vector
-    with the identical loop structure, so the barrier pins the operand
-    and keeps producer fusion out of the sum."""
-    acc = acc_dtype()
-    v = jax.lax.optimization_barrier(jnp.asarray(vec).astype(acc))
+def _bit_sums(vec, seed: int):
+    """``(2,)`` uint32 ``[Σ bits, Σ ±bits]`` (mod 2**32) of one float
+    array's elements, each widened exactly to float32 first (float64
+    leaves contribute both 32-bit halves).  +0.0 has the all-zero
+    pattern, so zero padding contributes exactly zero to both."""
+    v = jnp.asarray(vec)
+    if v.dtype.itemsize > 4:
+        bits = jax.lax.bitcast_convert_type(v, jnp.uint32)
+        bits = bits[..., 0] + bits[..., 1] * _MIX2
+    else:
+        bits = jax.lax.bitcast_convert_type(v.astype(jnp.float32),
+                                            jnp.uint32)
     # the value keeps its native shape (and, under GSPMD, its sharding
     # — ravelling a tensor-parallel leaf would force the partitioner to
     # rematerialize the PARAMETER); the generated sign stream reshapes
     # to match instead, which costs a per-shard iota at worst
-    signs = _device_signs(v.size, seed).reshape(v.shape)
-    return jnp.stack([jnp.sum(v), jnp.sum(v * signs)])
+    negate = _device_negate(v.size, seed).reshape(v.shape)
+    signed = jnp.where(negate, jnp.uint32(0) - bits, bits)
+    return jnp.stack([jnp.sum(bits, dtype=jnp.uint32),
+                      jnp.sum(signed, dtype=jnp.uint32)])
+
+
+def _fold24(u):
+    """uint32 → the accumulator float holding a 24-bit integer (exact in
+    float32): the top byte is xor-folded in, so any change to the 32-bit
+    sum changes the result."""
+    return ((u & jnp.uint32(0xFFFFFF)) ^ (u >> 24)).astype(acc_dtype())
+
+
+def fingerprint_flat(vec, seed: int):
+    """``(2,)`` ``[sum, projection]`` of one flat float vector, in the
+    accumulator dtype (see the module docstring: order-independent
+    integer sums over the bit patterns).  Zero padding contributes
+    exactly zero to both components, so padded flat parameter vectors
+    fingerprint their payload."""
+    return _fold24(_bit_sums(vec, seed))
 
 
 def fingerprint_tree(tree, seed: int):
     """``(2,)`` fingerprint over every float leaf of a pytree; each leaf
     draws its own sign stream (seed advances by a golden-ratio stride
     per leaf) so swapping values between leaves changes the projection."""
-    acc = acc_dtype()
-    total = jnp.zeros((2,), acc)
+    total = jnp.zeros((2,), jnp.uint32)
     idx = 0
     for leaf in jax.tree_util.tree_leaves(tree):
         leaf = jnp.asarray(leaf)
         if jnp.issubdtype(leaf.dtype, jnp.floating):
             idx += 1
-            total = total + fingerprint_flat(leaf, seed + _LEAF_STRIDE * idx)
-    return total
+            total = total + _bit_sums(leaf, seed + _LEAF_STRIDE * idx)
+    return _fold24(total)
 
 
 #: seed offsets separating the three fingerprinted trees — params,

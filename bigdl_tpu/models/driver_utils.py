@@ -70,14 +70,15 @@ def base_parser(description: str) -> argparse.ArgumentParser:
 
 
 def init_logging() -> None:
-    """Driver logging bootstrap: console + ``bigdl.log`` via LoggerFilter
-    (the reference calls ``LoggerFilter.redirectSparkInfoLogs`` at the top
-    of every Train main).  Also honors an XLA_FLAGS virtual host-device
-    request (``Engine.honor_virtual_devices``), so
-    ``XLA_FLAGS=--xla_force_host_platform_device_count=N python -m ...``
-    gets the N-device CPU mesh it asked for."""
-    from bigdl_tpu.engine import Engine
-    Engine.honor_virtual_devices()
+    """Driver bootstrap, called at the top of every Train main: console
+    + ``bigdl.log`` via LoggerFilter (the reference calls
+    ``LoggerFilter.redirectSparkInfoLogs`` there), and the placement of
+    XLA's persistent compilation cache
+    (:func:`bigdl_tpu.engine.init_compile_cache`).  The platform is
+    JAX's to choose: an N-device CPU mesh is requested with
+    ``JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=N``."""
+    from bigdl_tpu.engine import init_compile_cache
+    init_compile_cache()
     from bigdl_tpu.utils.logger_filter import redirect_spark_info_logs
     redirect_spark_info_logs()
 

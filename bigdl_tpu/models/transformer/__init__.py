@@ -59,7 +59,11 @@ class PositionalEncoding(Module):
         pe = np.zeros((max_len, d_model), np.float32)
         pe[:, 0::2] = np.sin(pos * div)
         pe[:, 1::2] = np.cos(pos * div[: d_model // 2])
-        self.pe = jnp.asarray(pe)
+        # a HOST constant: every step that closes over the table embeds
+        # it at lowering, and a device array would be pulled back to the
+        # host for that each time (an implicit device→host transfer the
+        # host-sync guard rightly reports)
+        self.pe = pe
         self.sequence_parallel = None
 
     @property

@@ -36,14 +36,15 @@ from bigdl_tpu.models.transformer import (transformer_lm,
 VOCAB = 64
 
 
-def _synthetic(n: int, seq_len: int, seed: int = 1) -> list:
+def _synthetic(n: int, seq_len: int, seed: int = 1,
+               vocab: int = VOCAB) -> list:
     """Learnable next-token structure: token_{t+1} = f(token_t) pattern."""
     rng = np.random.RandomState(seed)
     out = []
     for _ in range(n):
-        start = rng.randint(1, VOCAB + 1)
+        start = rng.randint(1, vocab + 1)
         step = rng.randint(1, 5)
-        toks = (np.arange(seq_len + 1) * step + start) % VOCAB + 1
+        toks = (np.arange(seq_len + 1) * step + start) % vocab + 1
         out.append(Sample(toks[:-1].astype(np.float32),
                           toks[1:].astype(np.float32)))
     return out

@@ -89,9 +89,9 @@ def chunked_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     and reinstate the O(T^2) footprint.
 
     This is the single-chip fallback for shapes where the one-shot
-    standard path's O(T^2) program crashes the backend compiler (measured
-    at T16384: ``docs/longctx_t16384_repro.md``) and the pallas kernel's
-    constraints (head_dim % 128, TPU-only) don't hold.  For causal masks
+    standard path's O(T^2) score residuals do not fit and the pallas
+    kernel's constraints (head_dim % 128, TPU-only) don't hold.  For
+    causal masks
     it still computes the fully-masked upper blocks (~2x the minimal
     FLOPs) — static shapes keep XLA happy; the pallas flash kernel is the
     path that skips them."""
@@ -125,23 +125,15 @@ def chunked_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
 
 
 def _flash_block_sizes(t: int):
-    """Measured v5e tile sizes for the pallas flash kernel (r5,
-    ``_flash_tune`` protocol, B1/H8/Dh128, fwd+bwd, carried chain):
+    """Tile sizes for the pallas flash kernel: the largest of 1024, 512,
+    256 that divides the sequence length, else the kernel's stock 128.
 
-    ======  ==========  =========  ==========
-    tiles   T8192       T16384     speedup
-    ======  ==========  =========  ==========
-    128     32.6 ms     139.1 ms   1.0x (stock default)
-    512     10.8 ms      27.0 ms   3.0-5.2x
-    1024     8.4 ms      22.0 ms   3.9-6.3x
-    2048    compile-helper crash (same class as the T16384 standard
-            path, docs/longctx_t16384_repro.md)
-    ======  ==========  =========  ==========
-
-    The stock default (every tile 128) starves the kernel; 1024-square
-    tiles are the measured optimum at every shape that compiles.  Tiles
-    must divide the sequence length, so shorter/odd T fall back through
-    the power-of-two ladder."""
+    The ladder was chosen in round 5 on a v5e under another compiler
+    (B1/H8/Dh128, fwd+bwd: 128-square tiles 32.6 ms at T8192, 512-square
+    10.8 ms, 1024-square 8.4 ms — round-5 record, removed in PR 21).  On
+    the current machine 1024-square fwd/dkv/dq tiles compile and run
+    (``chip_smoke.py``, T2048); their speed against the other rungs is
+    not measured there, and 2048-square tiles are untried."""
     from jax.experimental.pallas.ops.tpu.flash_attention import BlockSizes
     blk = 128
     for cand in (1024, 512, 256):
@@ -159,19 +151,17 @@ class MultiHeadAttention(Module):
     """Self-attention over (B, T, D) input; table input (q_src, kv_src)
     gives cross-attention.
 
-    ``flash``: opt-in TPU pallas flash-attention kernel with v5e-tuned
-    tile sizes (:func:`_flash_block_sizes` — the stock 128 defaults are
-    3.9-6.3x slower and the reason earlier rounds measured flash losing).
-    Measured r5 in the full jitted train step: flash WINS at every
-    realistic shape tried — T2048/B8 +21%, 537M/T2048 +17% (76.0%
-    MFU), T8192 1.86x, T16384 65k tok/s where the one-shot standard path
-    exhausts HBM on saved O(T^2) residuals beyond 2 layers
-    (docs/longctx_t16384_repro.md; ``chunk`` or per-block remat also
-    recover that shape).  Default (False) stays the standard path — it
-    is bit-exact against the other paths, composes with the GSPMD head
-    split (pallas kernels do not partition), and has no shape
+    ``flash``: opt-in TPU pallas flash-attention kernel with the tile
+    sizes of :func:`_flash_block_sizes`.  In round 5 (another compiler;
+    record removed in PR 21) flash won every shape tried in the full
+    jitted train step, and at T16384 the one-shot standard path ran out
+    of HBM on saved O(T^2) residuals beyond 2 layers (``chunk`` or
+    per-block remat also recover that shape); its speed is not measured
+    on the current machine.  Default (False) stays the standard path —
+    it is bit-exact against the other paths, composes with the GSPMD
+    head split (pallas kernels do not partition), and has no shape
     constraints; perf-critical dense training opts in (bench.py's LM
-    legs do).  flash=True raises when the backend/shape constraints
+    legs and ``chip_smoke.py`` do).  flash=True raises when the backend/shape constraints
     aren't met (TPU only, T % 128 == 0, head_dim % 128 == 0,
     self-attention with Tq == Tkv — the kernel's causal mask is
     top-left aligned, which diverges from the reference's

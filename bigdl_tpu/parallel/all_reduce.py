@@ -32,18 +32,11 @@ import jax.numpy as jnp
 from jax import lax
 from jax.flatten_util import ravel_pytree
 
-try:  # jax >= 0.8
-    from jax import shard_map as _shard_map
 
-    def shard_map(f, mesh, in_specs, out_specs, check_rep=False):
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_vma=check_rep)
-except ImportError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map as _old_shard_map
+def shard_map(f, mesh, in_specs, out_specs, check_rep=False):
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_rep)
 
-    def shard_map(f, mesh, in_specs, out_specs, check_rep=False):
-        return _old_shard_map(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, check_rep=check_rep)
 
 Params = Any
 

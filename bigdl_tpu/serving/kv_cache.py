@@ -46,9 +46,14 @@ from bigdl_tpu.serving.engine import Overloaded
 DUMP_BLOCK = 0
 
 #: freed block ids are zero-scrubbed in fixed-size batches (padded with
-#: the dump block) so the eager scatter keeps ONE cached computation
-#: instead of one per distinct free-list length
+#: the dump block) so the scrub step has ONE signature instead of one
+#: per distinct free-list length
 _SCRUB_CHUNK = 8
+
+
+def _scrub_fn(k, v, ids):
+    zeros = jnp.zeros((k.shape[0], ids.shape[0]) + k.shape[2:], k.dtype)
+    return k.at[:, ids].set(zeros), v.at[:, ids].set(zeros)
 
 
 class PagedKVCache:
@@ -80,6 +85,13 @@ class PagedKVCache:
         preflight_pool(self.pool_nbytes, label)
         self.k = jnp.zeros(shape, self.dtype)
         self.v = jnp.zeros(shape, self.dtype)
+        # the scrub is a compiled step like prefill and decode: an eager
+        # ``.at[].set`` dispatches op by op and compiles each op at the
+        # FIRST free — on the decode clock, after warmup
+        from bigdl_tpu.analysis.program_contracts import lm_kv_scrub_contract
+        from bigdl_tpu.utils.compile_cache import tracked_jit
+        self.scrub_step = tracked_jit(_scrub_fn, "lm_kv_scrub",
+                                       contract=lm_kv_scrub_contract())
         self._free: List[int] = list(range(self.n_blocks - 1, 0, -1))
         self._tables: Dict[int, List[int]] = {}
         self._lock = analysis.make_lock("lm.kv_cache")
@@ -158,19 +170,23 @@ class PagedKVCache:
         self._publish_occupancy()
         return len(blocks)
 
+    def warmup(self) -> None:
+        """AOT-compile the scrub step, so the first free after warmup
+        pays no compile."""
+        import jax
+        pool = jax.ShapeDtypeStruct(self.k.shape, self.k.dtype)
+        self.scrub_step.warmup(
+            pool, pool, jax.ShapeDtypeStruct((_SCRUB_CHUNK,), jnp.int32))
+
     def _scrub(self, blocks: List[int]) -> None:
         """Zero the named blocks across all layers.  Ids are padded to
         ``_SCRUB_CHUNK`` with the dump block (re-zeroing junk is free),
-        so the eager scatter-set has a fixed shape and XLA caches one
-        computation for every free."""
-        zeros = jnp.zeros((self.n_layers, _SCRUB_CHUNK, self.block_size,
-                           self.n_head, self.head_dim), self.dtype)
+        so the scrub step keeps its one fixed shape for every free."""
         for at in range(0, len(blocks), _SCRUB_CHUNK):
             chunk = blocks[at:at + _SCRUB_CHUNK]
             ids = np.full((_SCRUB_CHUNK,), DUMP_BLOCK, np.int32)
             ids[:len(chunk)] = chunk
-            self.k = self.k.at[:, ids].set(zeros)
-            self.v = self.v.at[:, ids].set(zeros)
+            self.k, self.v = self.scrub_step(self.k, self.v, ids)
 
     def _publish_occupancy(self) -> None:
         denom = max(1, self.allocatable_blocks)

@@ -783,17 +783,38 @@ class LMServingEngine:
                 out[label] = s
         return out
 
+    @property
+    def timings(self) -> Dict[str, List[Dict[str, Any]]]:
+        """Label -> per-signature compile provenance (``CachedStep.
+        timings``: source compile|cache, trace/compile/load ms) of every
+        compiled LM step, the KV scrub included."""
+        steps = {"lm_decode": self._decode_cached,
+                 "lm_prefill": self._prefill_cached,
+                 "lm_full": self._full_cached,
+                 "lm_decode_int8": self._decode_q_cached,
+                 "lm_kv_scrub": self.cache.scrub_step}
+        return {label: list(step.timings)
+                for label, step in steps.items() if step is not None}
+
     def warmup(self) -> None:
-        """AOT-compile every planned signature (decode at its one fixed
-        shape, each prefill/full bucket, the int8 tier when enabled) so
-        no request ever pays a compile against its deadline."""
+        """AOT-compile every signature the scheduler dispatches (decode
+        at its one fixed shape, the KV scrub, each prefill bucket, the
+        int8 tier when enabled) so no request ever pays a compile
+        against its deadline: ``2 + len(buckets)`` cold compiles, one
+        more with int8."""
         self._decode_cached.warmup(*self._decode_specs)
+        self.cache.warmup()
         for specs in self._prefill_specs.values():
             self._prefill_cached.warmup(*specs)
-        for specs in self._full_specs.values():
-            self._full_cached.warmup(*specs)
         if self._decode_q_cached is not None:
             self._decode_q_cached.warmup(*self._decode_q_specs)
+
+    def warmup_sequential(self) -> None:
+        """AOT-compile the ``lm_full`` ladder of the offline baseline
+        (:meth:`generate_sequential`), which serving never dispatches —
+        without this each bucket compiles at its first use."""
+        for specs in self._full_specs.values():
+            self._full_cached.warmup(*specs)
 
     # -- int8 gate --------------------------------------------------------
 

@@ -225,10 +225,11 @@ def pad_batch(tree, n: int, padded_n: int):
     """Pad every leaf of a host batch from ``n`` to ``padded_n`` rows by
     repeating the last row (edge padding: always-valid values, so the
     padded rows cannot produce NaN/inf that a reduction might smear).
-    Callers slice model outputs back to ``n`` rows host-side — the
-    surviving rows are bit-identical to an unpadded forward for the
+    Callers slice model outputs back to ``n`` rows host-side — for the
     batch-independent eval-mode graphs this feeds (conv/BN-eval/
-    attention-per-row)."""
+    attention-per-row) the surviving rows equal an unpadded forward to
+    rounding; the padded and unpadded batch shapes are separately
+    compiled programs, which XLA does not promise to round alike."""
     import jax
     import numpy as np
     if padded_n == n:
@@ -264,14 +265,11 @@ def backend_fingerprint() -> Dict[str, str]:
     miss (recompile), never a deserialization crash."""
     import jax
     import jaxlib
-    try:
-        from jax.extend import backend as _xb
-        b = _xb.get_backend()
-        platform, pver = b.platform, str(b.platform_version)
-    except Exception:  # pragma: no cover - very old jax
-        platform, pver = "unknown", "unknown"
+    from jax.extend import backend as _xb
+    b = _xb.get_backend()
     return {"jax": jax.__version__, "jaxlib": jaxlib.__version__,
-            "platform": platform, "platform_version": pver}
+            "platform": b.platform,
+            "platform_version": str(b.platform_version)}
 
 
 class CompileCache:
@@ -280,7 +278,8 @@ class CompileCache:
     Layout per entry (``<key>`` is the hex digest of the full cache key):
 
     - ``<key>.bin``    — pickled ``(serialized_executable, in_tree,
-      out_tree)`` payload,
+      out_tree, device_ids)`` payload (``device_ids``: the executable's
+      device assignment, in order — a load binds to exactly those),
     - ``<key>.json``   — manifest: entry version, label, payload checksum
       + byte count, abstract-signature hash, topology, backend
       fingerprint, creation time,
@@ -674,6 +673,9 @@ class CachedStep:
         self.bucket_argnums = tuple(bucket_argnums)
         self.sentinel = None          # retrace sentinel fed by precompiles
         self._mem: Dict[Any, Any] = {}   # signature key -> loaded executable
+        #: signature keys whose executable came from the persistent
+        #: cache and has not dispatched yet (see :meth:`_first_dispatch`)
+        self._unproven: set = set()
         #: signature families seen (bucket-arg batch dims erased): the
         #: in-plan test for batch sizes beyond the largest bucket, which
         #: round to multiples the precompiler cannot enumerate ahead
@@ -702,11 +704,7 @@ class CachedStep:
         return abstract_signature(args)
 
     def __call__(self, *args):
-        key = self._sig_key(args)
-        exe = self._mem.get(key)
-        if exe is None:
-            exe = self._compile_entry(args, key)
-        return self._dispatch(exe, args)
+        return self.call_with_signature(args, self._sig_key(args))
 
     def call_with_signature(self, args: Tuple, key):
         """Dispatch with a signature the caller already computed — the
@@ -717,6 +715,35 @@ class CachedStep:
         exe = self._mem.get(key)
         if exe is None:
             exe = self._compile_entry(args, key)
+        # (empty in steady state: the signature is not hashed again)
+        if self._unproven and key in self._unproven:
+            return self._first_dispatch(exe, args, key)
+        return self._dispatch(exe, args)
+
+    def _first_dispatch(self, exe, args: Tuple, key):
+        """First execution of a cache-LOADED executable.  Deserializing
+        proves the bytes, not that the runtime will run them: a loaded
+        executable its first dispatch rejects (argument, sharding or
+        device mismatch — raised before anything executes, so the
+        donated inputs are still alive) degrades to a fresh compile
+        exactly as a failed deserialize does.  A classified device OOM
+        is the program's fault, not the entry's, and passes through."""
+        import jax
+        self._unproven.discard(key)
+        try:
+            return self._dispatch(exe, args)
+        except (jax.errors.JaxRuntimeError, ValueError, TypeError) as e:
+            if any(getattr(leaf, "is_deleted", lambda: False)()
+                   for leaf in jax.tree_util.tree_leaves(args)):
+                raise       # inputs already consumed: nothing to re-run on
+            logger.warning(
+                "compile cache: the cache-loaded executable of fused step "
+                "%r failed its first dispatch (%s: %s) — falling back to "
+                "a fresh compile", self.label, type(e).__name__, e)
+            self._cache._count_error()
+        del self._mem[key]
+        exe = self._compile_entry(args, key, precompile=False,
+                                  use_cache=False)
         return self._dispatch(exe, args)
 
     def _dispatch(self, exe, args: Tuple):
@@ -749,8 +776,26 @@ class CachedStep:
 
     # -- the miss path ----------------------------------------------------
 
-    def _compile_entry(self, args: Tuple, key, precompile: bool = True):
-        import jax
+    def _compile_entry(self, args: Tuple, key, precompile: bool = True,
+                       use_cache: bool = True):
+        """Lower, then load-or-compile, the executable for one
+        signature.  ``use_cache=False`` skips the persistent-cache LOAD
+        (the fresh compile still overwrites the entry).  Anything the
+        compiler itself rejects is tagged ``compile_phase`` — replaying
+        it from a restored snapshot would rebuild the identical program,
+        so the trainer's retry loop re-raises it at once; a watchdog
+        timeout stays healable (:class:`CompileTimeoutError`)."""
+        try:
+            return self._compile_entry_tagged(args, key, precompile,
+                                              use_cache)
+        except CompileTimeoutError:
+            raise
+        except Exception as e:
+            e.compile_phase = self.label
+            raise
+
+    def _compile_entry_tagged(self, args: Tuple, key, precompile: bool,
+                              use_cache: bool):
         from jax.experimental import serialize_executable as _se
 
         sig_hash = _signature_hash(args)
@@ -792,8 +837,9 @@ class CachedStep:
                 cache_key = CompileCache.entry_key(
                     self.label, sig_hash, hlo_digest, self.topology,
                     fingerprint)
-                exe = self._try_cache_load(cache_key, fingerprint, timeout,
-                                           diagnosis, _se)
+                if use_cache:
+                    exe = self._try_cache_load(cache_key, fingerprint,
+                                               timeout, diagnosis, _se)
             loaded = exe is not None
             if exe is None:
                 if self._cache is not None:
@@ -840,6 +886,8 @@ class CachedStep:
                 self._store(cache_key, exe, sig_hash, fingerprint, _se,
                             audit=audit_summary)
         self._mem[key] = exe
+        if loaded:
+            self._unproven.add(key)
         if self.bucket_argnums:
             self._families.add(self._family_key(args))
         if precompile and self.bucket_argnums:
@@ -887,9 +935,17 @@ class CachedStep:
             with telemetry.span(f"compile/cache_load/{self.label}"):
                 with compile_watchdog(self.label, "cache_load", timeout,
                                       diagnosis):
-                    payload, in_tree, out_tree = pickle.loads(data)
-                    exe = _se.deserialize_and_load(payload, in_tree,
-                                                   out_tree)
+                    payload, in_tree, out_tree, device_ids = \
+                        pickle.loads(data)
+                    # bind to the devices the step was compiled for:
+                    # the default is every device of the backend, which
+                    # turns a one-device step into one expecting a
+                    # shard per device
+                    import jax
+                    by_id = {d.id: d for d in jax.devices()}
+                    exe = _se.deserialize_and_load(
+                        payload, in_tree, out_tree,
+                        execution_devices=[by_id[i] for i in device_ids])
         except Exception as e:
             logger.warning(
                 "compile cache: entry %s failed to deserialize (%s: %s) "
@@ -914,7 +970,11 @@ class CachedStep:
                fingerprint: Dict[str, str], _se,
                audit: Optional[Dict[str, Any]] = None) -> None:
         try:
-            payload = pickle.dumps(_se.serialize(exe),
+            # the device assignment, in order (the attribute serialize()
+            # itself reads the executable from)
+            device_ids = [
+                d.id for d in exe._executable._unloaded_executable.device_list]
+            payload = pickle.dumps(_se.serialize(exe) + (device_ids,),
                                    protocol=pickle.HIGHEST_PROTOCOL)
         except Exception as e:
             logger.warning(

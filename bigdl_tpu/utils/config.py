@@ -80,14 +80,9 @@ _DEFAULTS: Dict[str, Any] = {
     "bigdl.watchdog.timelineDir": None,  # dump telemetry timeline here on fire
     "bigdl.check.singleton": False,
     "bigdl.summary.flushSecs": 2.0,
-    # SUPERSEDED and unread: kept only so existing setters don't error —
-    # the executable cache below (bigdl.compile.cacheDir) is the one that
-    # works; jax's own compile cache is armed via jax.config
-    # jax_compilation_cache_dir, not through this table
-    "bigdl.compilation.cacheDir": None,
     # resilient compilation (utils/compile_cache.py): persistent fused-step
-    # executable cache + AOT warmup watchdog + shape buckets.  NOT the
-    # near-namesake bigdl.compilation.cacheDir above.
+    # executable cache + AOT warmup watchdog + shape buckets.  XLA's own
+    # compilation cache is placed by engine.init_compile_cache, not here.
     "bigdl.compile.cacheDir": None,        # executable cache dir; None = off
     "bigdl.compile.timeoutSec": 0,         # compile watchdog abort; 0 = off
     "bigdl.compile.keepLast": 0,           # cache entries retained; 0 = all
@@ -95,6 +90,7 @@ _DEFAULTS: Dict[str, Any] = {
     "bigdl.compile.lockTimeoutSec": 30.0,  # single-writer lock wait cap
     "bigdl.compile.lockStaleSec": 600.0,   # steal writer locks older than this
     "bigdl.pipeline.depth": 8,             # driver-loop dispatch pipeline
+    # (not measured on the current machine)
     # overload-tolerant serving (bigdl_tpu/serving): admission-controlled
     # request path — bounded queue, per-request deadlines, shedding,
     # poison quarantine, hung-dispatch watchdog, graceful drain
@@ -163,11 +159,10 @@ _DEFAULTS: Dict[str, Any] = {
     "bigdl.ingest.recordRingDepth": 256,   # reader -> decode record ring
     "bigdl.ingest.decodedRingDepth": None, # in-flight decode window; None = 2x batch
     "bigdl.ingest.batchRingDepth": 2,      # assembled batches buffered ahead
-    "bigdl.ingest.batchesInFlight": 2,     # device uploads in flight (transfer-ahead)
+    "bigdl.ingest.batchesInFlight": 2,     # device uploads in flight (transfer-ahead;
+    # not measured on the current machine)
     "bigdl.ingest.deviceAugment": False,   # pack FULL uint8 frames + ride-along
     # crop offsets/flips; crop/flip/transpose runs on device (nn.DeviceAugment)
-    "bigdl.ingest.zeroCopyUpload": True,   # dlpack handoff of assembler buffers
-    # at the host->device crossing (engine.to_device); falls back per-array
     # stage autoscaling (dataset/ingest.py _Autoscaler): the supervisor
     # adds/retires decode workers (and native assemble threads) from the
     # per-stage starve/backpressure signals, governor as upper bound

@@ -27,9 +27,6 @@ def main():
     ap.add_argument("--out", default="MOE_r04.json")
     args = ap.parse_args()
 
-    from bigdl_tpu.engine import Engine
-    Engine.honor_virtual_devices()
-
     losses = []
 
     class LossTap(logging.Handler):

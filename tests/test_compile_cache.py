@@ -138,6 +138,33 @@ class TestStoreLifecycle:
         assert np.array_equal(_flat(m1.params), _flat(m2.params)), \
             "warm-start step results must be bit-identical to cold"
 
+    def test_loaded_executable_failing_first_dispatch_recompiles(
+            self, cache_dir, monkeypatch):
+        """Deserializing proves the bytes, not that the runtime will run
+        them.  A load that ignores the entry's device list comes back
+        bound to all 8 virtual devices (jax 0.9.0's default) and its
+        first dispatch raises INVALID_ARGUMENT — which must degrade to
+        a fresh compile like a failed deserialize, count a cache error,
+        and train to the cold run's weights."""
+        from jax.experimental import serialize_executable as se
+        real = se.deserialize_and_load
+        monkeypatch.setattr(
+            se, "deserialize_and_load",
+            lambda payload, in_tree, out_tree, **kw:
+                real(payload, in_tree, out_tree))
+        samples = _samples()
+        _pin_shuffle()
+        o1, m1 = _trainer(samples)
+        o1.optimize()
+        errors_before = _counter("Compile/cache_errors")
+        _pin_shuffle()
+        o2, m2 = _trainer(samples)
+        o2.optimize()
+        cached = _cached_of(o2)
+        assert cached.cache_hits == 1 and cached.compiles == 1
+        assert _counter("Compile/cache_errors") == errors_before + 1
+        assert np.array_equal(_flat(m1.params), _flat(m2.params))
+
     def test_corrupt_entry_skipped_with_recompile_parity(self, cache_dir):
         """A bit-rotted committed payload fails its manifest checksum,
         degrades to a recompile (never a crash), and the recompiled run
@@ -527,9 +554,17 @@ class TestBuckets:
             m._eval_jit = {}
 
     def test_predictor_bucketed_parity(self):
-        """Ragged predict batches under buckets: outputs identical to
-        the unbucketed run, and execution stays inside the precompiled
-        signature set."""
+        """Ragged predict batches under buckets: outputs match the
+        unbucketed run, and execution stays inside the precompiled
+        signature set.
+
+        Parity is to float32 rounding, not bitwise: the ragged 3-row
+        batch runs a 3-row program unbucketed and the 4-row program
+        bucketed, and XLA does not promise that two separately compiled
+        batch shapes round a row identically (jaxlib 0.9.0's CPU
+        backend: a 2-row and a 24-row compile of the same
+        Linear-Tanh-Linear-LogSoftMax differ by 1 ulp in the same
+        row)."""
         from bigdl_tpu.optim.predictor import Predictor
         m = self._eval_model()
         samples = [Sample(np.random.RandomState(i).normal(
@@ -545,7 +580,7 @@ class TestBuckets:
         finally:
             config.clear_property("bigdl.compile.buckets")
             m._eval_jit = {}
-        np.testing.assert_array_equal(ref, got)
+        np.testing.assert_allclose(ref, got, rtol=1e-6, atol=1e-6)
 
 
 # ---------------------------------------------------------------------------

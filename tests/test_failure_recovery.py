@@ -421,3 +421,68 @@ class TestRemoteCheckpointIntegration:
         reloaded = file_io.load(model_path)
         x = np.zeros((1, 4), np.float32)
         assert np.asarray(reloaded.forward(x)).shape == (1, 2)
+
+
+class TestUnhealableFailuresNotRetried:
+    """What a snapshot restore cannot change re-raises at once: the
+    backoff (120 s, 240 s, ... by default) is for faults a restore can
+    heal, and sleeping through it only hides the real error."""
+
+    def _opt(self):
+        samples = synthetic_separable(64, 4, n_classes=2)
+        ds = LocalDataSet(samples).transform(SampleToMiniBatch(32))
+        opt = optim.Optimizer.create(_mlp(4, 2), ds, nn.ClassNLLCriterion())
+        opt.set_end_when(optim.max_iteration(2))
+        return opt
+
+    def test_invalid_argument_runtime_error_reraises_without_sleep(
+            self, monkeypatch):
+        import jax
+        from bigdl_tpu.optim import optimizer as optimizer_mod
+        slept, calls = [], []
+        monkeypatch.setattr(optimizer_mod, "_sleep", slept.append)
+        opt = self._opt()
+
+        def boom():
+            calls.append(1)
+            raise jax.errors.JaxRuntimeError(
+                "INVALID_ARGUMENT: Expected args to "
+                "execute_sharded_on_local_devices to have 8 shards")
+        monkeypatch.setattr(opt, "_optimize", boom)
+        with pytest.raises(jax.errors.JaxRuntimeError,
+                           match="INVALID_ARGUMENT"):
+            opt.optimize()
+        assert calls == [1] and slept == []
+
+    def test_compile_phase_failure_reraises_without_sleep(self, monkeypatch):
+        from bigdl_tpu.optim import optimizer as optimizer_mod
+        from bigdl_tpu.utils.compile_cache import CachedStep
+        slept, calls = [], []
+        monkeypatch.setattr(optimizer_mod, "_sleep", slept.append)
+
+        def rejected(self, *a, **kw):
+            calls.append(1)
+            raise RuntimeError("RESOURCE_EXHAUSTED: XLA:TPU compile "
+                               "permanent error. Ran out of memory")
+        monkeypatch.setattr(CachedStep, "_compile_entry_tagged", rejected)
+        with pytest.raises(RuntimeError, match="compile permanent error"):
+            self._opt().optimize()
+        assert calls == [1] and slept == []
+
+    def test_other_runtime_errors_still_back_off(self, monkeypatch):
+        """The control: an unclassified runtime failure keeps its
+        retry budget and its backoff."""
+        from bigdl_tpu.optim import optimizer as optimizer_mod
+        slept = []
+        monkeypatch.setattr(optimizer_mod, "_sleep", slept.append)
+        opt = self._opt()
+        monkeypatch.setattr(
+            opt, "_optimize",
+            lambda: (_ for _ in ()).throw(RuntimeError("link flapped")))
+        config.set_property("bigdl.failure.retryTimes", 3)
+        try:
+            with pytest.raises(RuntimeError, match="link flapped"):
+                opt.optimize()
+        finally:
+            config.clear_property("bigdl.failure.retryTimes")
+        assert len(slept) == 2

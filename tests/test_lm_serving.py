@@ -90,6 +90,15 @@ def _assert_identity(stats_or_rec):
     assert total == stats_or_rec["submitted"], stats_or_rec
 
 
+#: the combined-chaos plan's hung decode step.  It must be a step the
+#: load is SURE to reach: the seed-9 workload decodes 58 tokens, less 3
+#: for the poisoned prompt and at most 7 for the evicted stream, over 4
+#: slots — 12 steps when arrivals batch perfectly, more when they do
+#: not.  (It was 20, which a host whose decode step outlasts the 5 ms
+#: arrival gap never reaches: 17 steps on jax 0.9.0's CPU backend.)
+_COMBINED_HANG = "12:3.0"
+
+
 def _zero_retraces(eng):
     retr = {label: s.retraces for label, s in eng.sentinels.items()}
     assert retr and all(v == 0 for v in retr.values()), \
@@ -392,7 +401,7 @@ class TestLMChaos:
         config.set_property("bigdl.lm.warmupSteps", 2)
         config.set_property("bigdl.chaos.poisonPromptAt", "2")
         config.set_property("bigdl.chaos.evictBlockAt", 6)
-        config.set_property("bigdl.chaos.hangDecodeAt", "20:3.0")
+        config.set_property("bigdl.chaos.hangDecodeAt", _COMBINED_HANG)
         chaos.install()
         reqs = sample_lm_workload(12, VOCAB, seed=9,
                                   prompt_lens=(4, 6, 8),
@@ -428,7 +437,7 @@ class TestLMChaos:
         config.set_property("bigdl.lm.warmupSteps", 2)
         config.set_property("bigdl.chaos.poisonPromptAt", "2")
         config.set_property("bigdl.chaos.evictBlockAt", 6)
-        config.set_property("bigdl.chaos.hangDecodeAt", "20:3.0")
+        config.set_property("bigdl.chaos.hangDecodeAt", _COMBINED_HANG)
         config.set_property("bigdl.incident.dir", str(tmp_path))
         config.set_property("bigdl.incident.autoDump", True)
         request_trace.arm()

@@ -441,10 +441,16 @@ class TestCombinedChaosPlan:
     def test_poison_plus_hang_plus_sigterm_exact_accounting(self):
         """One plan, three fault classes, mid-load: a poison request, a
         hung dispatch, and a SIGTERM.  Every submitted request ends in
-        exactly one of the four outcomes, non-poison completions are
-        bit-identical to a clean Predictor.predict over the same inputs,
-        and the strict retrace sentinel stays at zero across the ragged
-        batches the faults leave behind."""
+        exactly one of the four outcomes, non-poison completions match a
+        clean Predictor.predict over the same inputs, and the strict
+        retrace sentinel stays at zero across the ragged batches the
+        faults leave behind.
+
+        The match is to float32 rounding, not bitwise: the reference is
+        one 24-row program and the engine serves the same rows through
+        1-to-4-row bucket programs, and XLA does not promise that two
+        separately compiled batch shapes round a row identically
+        (jaxlib 0.9.0's CPU backend differs by 1 ulp)."""
         config.set_property("bigdl.chaos.poisonRequestAt", "6")
         config.set_property("bigdl.chaos.hangDispatchAt", "5:1.0")
         config.set_property("bigdl.serving.warmupBatches", 2)
@@ -500,12 +506,11 @@ class TestCombinedChaosPlan:
                       if isinstance(e, Overloaded)]
         assert rejections and all(e.retriable for e in rejections)
 
-        # -- non-poison completions: bit-identical to the clean batch
-        #    Predictor over the same inputs
+        # -- non-poison completions: the clean batch Predictor's rows
         assert rec["completed"] >= 5
         for key, out in rec["results"].items():
             idx = int(key.split("+")[0])
-            np.testing.assert_array_equal(out, ref[idx])
+            np.testing.assert_allclose(out, ref[idx], rtol=1e-6, atol=1e-6)
 
         # -- zero post-warmup retraces across all the ragged batches
         assert sentinel is not None and sentinel.retraces == 0
