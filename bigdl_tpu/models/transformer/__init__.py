@@ -134,16 +134,28 @@ class _Residual(Container):
     TrainSummary "Parameters" histogram walk all see the trained weights,
     and tp_specs/sequence-parallel wiring recurse naturally."""
 
-    def __init__(self, d_model: int, inner: Module, name=None):
+    def __init__(self, d_model: int, inner: Module, name=None,
+                 scopes=("norm", "inner")):
         super().__init__(name)
+        #: the named scopes of the two children (``ln1``/``attn``,
+        #: ``ln2``/``ffn`` in a decoder block).  Scopes, not module names:
+        #: they repeat in every block, and names key the parameter table
+        self.scopes = tuple(scopes)
         self.add(LayerNorm(d_model)).add(inner)
+
+    def scope_name(self, index: int):
+        """None: the two children's scopes stand directly under the
+        block's (``layer0/ln1``, not ``layer0/0__Residual/ln1``)."""
+        return None
 
     def apply(self, params, input, state, training=False, rng=None):
         norm, inner = self.children
-        h, _ = norm.apply(params[0], input, state[0], training=training)
-        h, new_inner = inner.apply(params[1], h, state[1],
-                                   training=training,
-                                   rng=_child_rng(rng, 1))
+        with jax.named_scope(self.scopes[0]):
+            h, _ = norm.apply(params[0], input, state[0], training=training)
+        with jax.named_scope(self.scopes[1]):
+            h, new_inner = inner.apply(params[1], h, state[1],
+                                       training=training,
+                                       rng=_child_rng(rng, 1))
         return input + h, [state[0], new_inner]
 
 
@@ -185,8 +197,9 @@ def transformer_block(d_model: int, n_head: int, ff_mult: int = 4,
     return (nn.Sequential()
             .add(_Residual(d_model,
                            nn.MultiHeadAttention(d_model, n_head,
-                                                 causal=True)))
-            .add(_Residual(d_model, ffn)))
+                                                 causal=True),
+                           scopes=("ln1", "attn")))
+            .add(_Residual(d_model, ffn, scopes=("ln2", "ffn"))))
 
 
 def _default_remat(remat):
@@ -232,19 +245,19 @@ def transformer_lm(vocab_size: int, d_model: int = 128, n_head: int = 4,
     preset applies (see :func:`_default_remat`)."""
     remat = _default_remat(remat)
     m = (nn.Sequential()
-         .add(nn.LookupTable(vocab_size, d_model))
-         .add(PositionalEncoding(d_model, max_len)))
-    for _ in range(n_layers):
+         .add(nn.LookupTable(vocab_size, d_model, name="embed"))
+         .add(PositionalEncoding(d_model, max_len, name="pos")))
+    for i in range(n_layers):
         block = transformer_block(d_model, n_head, tp=tp,
                                   moe_experts=moe_experts,
-                                  moe_top_k=moe_top_k)
+                                  moe_top_k=moe_top_k).set_name(f"layer{i}")
         if remat:
             block = nn.Remat(block,
                              policy=None if remat is True else remat)
         m.add(block)
-    m.add(LayerNorm(d_model))
-    m.add(nn.Linear(d_model, vocab_size))
-    m.add(nn.LogSoftMax())
+    m.add(LayerNorm(d_model, name="ln_f"))
+    m.add(nn.Linear(d_model, vocab_size, name="head"))
+    m.add(nn.LogSoftMax(name="log_softmax"))
     return m
 
 
@@ -265,16 +278,17 @@ def transformer_lm_pipeline(vocab_size: int, d_model: int = 128,
     ``--pipeline --tensor-parallel``)."""
     remat = _default_remat(remat)
     embed = (nn.Sequential()
-             .add(nn.LookupTable(vocab_size, d_model))
-             .add(PositionalEncoding(d_model, max_len)))
+             .add(nn.LookupTable(vocab_size, d_model, name="embed"))
+             .add(PositionalEncoding(d_model, max_len, name="pos")))
     blocks = [transformer_block(d_model, n_head, moe_experts=moe_experts,
-                                moe_top_k=moe_top_k, tp=tp)
-              for _ in range(n_layers)]
+                                moe_top_k=moe_top_k,
+                                tp=tp).set_name(f"layer{i}")
+              for i in range(n_layers)]
     if remat:
         policy = None if remat is True else remat
         blocks = [nn.Remat(b, policy=policy) for b in blocks]
     head = (nn.Sequential()
-            .add(LayerNorm(d_model))
-            .add(nn.Linear(d_model, vocab_size))
-            .add(nn.LogSoftMax()))
+            .add(LayerNorm(d_model, name="ln_f"))
+            .add(nn.Linear(d_model, vocab_size, name="head"))
+            .add(nn.LogSoftMax(name="log_softmax")))
     return embed, blocks, head

@@ -314,44 +314,58 @@ class MultiHeadAttention(Module):
         tp_axis = self.model_parallel
         if not (tp_axis and _axis_bound(tp_axis)):
             tp_axis = None
-        q = self._project(params, q_src, "wq", "bq")
-        k = self._project(params, kv_src, "wk", "bk")
-        v = self._project(params, kv_src, "wv", "bv")
-        if self.sequence_parallel and _axis_bound(self.sequence_parallel):
-            if q_src is not kv_src:
-                raise ValueError("sequence-parallel MHA is self-attention "
-                                 "only (q and kv must be the same source)")
-            from bigdl_tpu.parallel.ring_attention import (
-                _ring_attention_shard)
-            out = _ring_attention_shard(q, k, v,
-                                        axis_name=self.sequence_parallel,
-                                        causal=self.causal)
-        elif self.chunk:
-            out = chunked_attention(q, k, v, causal=self.causal,
-                                    chunk=self.chunk)
-        elif self._flash_ok(q, k):
-            from jax.experimental.pallas.ops.tpu.flash_attention import (
-                flash_attention)
-            out = flash_attention(
-                jnp.transpose(q, (0, 2, 1, 3)),
-                jnp.transpose(k, (0, 2, 1, 3)),
-                jnp.transpose(v, (0, 2, 1, 3)),
-                causal=self.causal,
-                sm_scale=1.0 / math.sqrt(self.head_dim),
-                block_sizes=_flash_block_sizes(q.shape[1]))
-            out = jnp.transpose(out, (0, 2, 1, 3))
-        else:
-            out = scaled_dot_product_attention(q, k, v, causal=self.causal)
+        with jax.named_scope("qkv"):
+            q = self._project(params, q_src, "wq", "bq")
+            k = self._project(params, kv_src, "wk", "bk")
+            v = self._project(params, kv_src, "wv", "bv")
+        # the attention core, whichever path runs it, under one scope
+        # (the Pallas kernels keep their own names inside it)
+        with jax.named_scope("flash"):
+            if self.sequence_parallel and _axis_bound(self.sequence_parallel):
+                if q_src is not kv_src:
+                    raise ValueError("sequence-parallel MHA is self-attention "
+                                     "only (q and kv must be the same source)")
+                from bigdl_tpu.parallel.ring_attention import (
+                    _ring_attention_shard)
+                out = _ring_attention_shard(q, k, v,
+                                            axis_name=self.sequence_parallel,
+                                            causal=self.causal)
+            elif self.chunk:
+                out = chunked_attention(q, k, v, causal=self.causal,
+                                        chunk=self.chunk)
+            elif self._flash_ok(q, k):
+                from jax.experimental.pallas.ops.tpu.flash_attention import (
+                    flash_attention)
+                # A Mosaic call is named in the device trace after the
+                # innermost scope it is born in.  Through the library's
+                # jit wrapper that is whatever stands before
+                # ``jit(flash_attention)`` (``jvp_jit_flash_attention``
+                # with no scope around the model, ``flash_attention``
+                # under one), so the forward kernel gets a scope of its
+                # own, beside the library's ``flash_mha_bwd_dkv`` and
+                # ``flash_mha_bwd_dq``: one name whatever encloses it.
+                with jax.named_scope("flash_mha_fwd"):
+                    out = flash_attention.__wrapped__(
+                        jnp.transpose(q, (0, 2, 1, 3)),
+                        jnp.transpose(k, (0, 2, 1, 3)),
+                        jnp.transpose(v, (0, 2, 1, 3)),
+                        causal=self.causal,
+                        sm_scale=1.0 / math.sqrt(self.head_dim),
+                        block_sizes=_flash_block_sizes(q.shape[1]))
+                out = jnp.transpose(out, (0, 2, 1, 3))
+            else:
+                out = scaled_dot_product_attention(q, k, v, causal=self.causal)
         # names the attention context for selective rematerialization:
         # nn.Remat(policy="save_attn") saves THIS tensor (O(B*T*d) per
         # block) so the VJP recomputes only projections/elementwise, never
         # the attention kernel itself.  A no-op outside jax.checkpoint.
         out = checkpoint_name(out, "attn_ctx")
-        bsz, t = out.shape[0], out.shape[1]
-        # -1: local heads * head_dim under the explicit Megatron split
-        out = out.reshape(bsz, t, -1) @ params["wo"]
-        if tp_axis:
-            out = lax.psum(out, tp_axis)   # the head-split pair's one psum
-        if self.with_bias:
-            out = out + params["bo"]
+        with jax.named_scope("out"):
+            bsz, t = out.shape[0], out.shape[1]
+            # -1: local heads * head_dim under the explicit Megatron split
+            out = out.reshape(bsz, t, -1) @ params["wo"]
+            if tp_axis:
+                out = lax.psum(out, tp_axis)   # the head-split pair's one psum
+            if self.with_bias:
+                out = out + params["bo"]
         return out, state

@@ -31,6 +31,7 @@ from __future__ import annotations
 import itertools
 import pickle
 import time
+from contextlib import nullcontext
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -116,6 +117,14 @@ def _child_rng(rng, i: int):
     return None if rng is None else jax.random.fold_in(rng, i)
 
 
+def child_scope(child: "Module", i: int):
+    """The ``jax.named_scope`` a container runs child ``i``'s ``apply``
+    under (:meth:`Module.scope_name`): metadata only, so that every device
+    operation carries the path of the module it was born in."""
+    name = child.scope_name(i)
+    return jax.named_scope(name) if name else nullcontext()
+
+
 class Module:
     """Base class of all layers and containers.
 
@@ -124,6 +133,9 @@ class Module:
     """
 
     _name_seq = itertools.count()
+    #: whether ``name`` came from the user (constructor or set_name); a
+    #: class default, so modules pickled before it existed read False
+    _named = False
 
     # ---- data-layout contract (channels-last compute path) --------------
     # How this module relates to the data format of image activations
@@ -155,6 +167,7 @@ class Module:
 
     def __init__(self, name: Optional[str] = None):
         self.name = name or f"{type(self).__name__}_{next(Module._name_seq)}"
+        self._named = name is not None
         self.train_mode: bool = True
         # Imperative-shell caches (reference AbstractModule.output/gradInput)
         self.output: Activity = None
@@ -175,6 +188,22 @@ class Module:
         # forward/backward nanosecond timing (reference AbstractModule:193-204)
         self.forward_time: int = 0
         self.backward_time: int = 0
+
+    def set_name(self, name: str) -> "Module":
+        """Name this module (reference ``AbstractModule.setName``): the
+        key of ``get_parameters_table`` and the module's named scope."""
+        self.name = name
+        self._named = True
+        return self
+
+    def scope_name(self, index: int) -> Optional[str]:
+        """The named scope a container wraps this child's ``apply`` in:
+        the user-given name, else child index and class
+        (``2_Sequential/0_Linear``) — never the default name, whose
+        sequence number is process-wide, so two processes, and two models
+        built in a different order, lower to the same scope names.  A
+        wrapper whose children name themselves returns None."""
+        return self.name if self._named else f"{index}_{type(self).__name__}"
 
     # ---- pure core ------------------------------------------------------
 
@@ -649,7 +678,8 @@ class Sequential(Container):
         x = input
         new_states = []
         for i, child in enumerate(self.children):
-            x, s = child.apply(params[i], x, state[i],
-                               training=training, rng=_child_rng(rng, i))
+            with child_scope(child, i):
+                x, s = child.apply(params[i], x, state[i],
+                                   training=training, rng=_child_rng(rng, i))
             new_states.append(s)
         return x, new_states

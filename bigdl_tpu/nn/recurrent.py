@@ -31,7 +31,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from bigdl_tpu.nn.module import Container, Module
+from bigdl_tpu.nn.module import Container, Module, child_scope
 
 
 def _uniform(rng, shape, stdv, dtype=jnp.float32):
@@ -481,8 +481,9 @@ class TimeDistributed(Container):
     def apply(self, params, input, state, training=False, rng=None):
         B, T = input.shape[0], input.shape[1]
         flat = input.reshape((B * T,) + input.shape[2:])
-        out, new_state = self.children[0].apply(
-            params[0], flat, state[0], training=training, rng=rng)
+        with child_scope(self.children[0], 0):
+            out, new_state = self.children[0].apply(
+                params[0], flat, state[0], training=training, rng=rng)
         return out.reshape((B, T) + out.shape[1:]), [new_state]
 
 

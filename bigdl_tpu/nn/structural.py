@@ -20,7 +20,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bigdl_tpu.nn.module import Container, Module, _child_rng
+from bigdl_tpu.nn.module import (Container, Module, _child_rng,
+                                 child_scope)
 
 
 def _axis(dim_1based: int, ndim: int, n_input_dims: int = -1) -> int:
@@ -440,8 +441,9 @@ class Bottle(Module):
         lead = input.shape[:input.ndim - self.n_input_dim + 1]
         rest = input.shape[input.ndim - self.n_input_dim + 1:]
         flat = jnp.reshape(input, (-1,) + rest)
-        out, s = self.module.apply(params[0], flat, state[0],
-                                   training=training, rng=rng)
+        with child_scope(self.module, 0):
+            out, s = self.module.apply(params[0], flat, state[0],
+                                       training=training, rng=rng)
         out = jnp.reshape(out, lead + out.shape[1:])
         return out, [s]
 
@@ -493,6 +495,11 @@ class Remat(Container):
                              "Sequential inside it instead")
         return super().add(module)
 
+    def scope_name(self, index: int):
+        """No scope of its own: the wrapped module's scope stands where it
+        would without the wrapper (under jax's own ``checkpoint``)."""
+        return None
+
     def checkpoint_policy(self):
         if callable(self.policy):
             return self.policy
@@ -511,7 +518,8 @@ class Remat(Container):
         inner = self.children[0]
 
         def fn(p, x, s, r):
-            return inner.apply(p, x, s, training=training, rng=r)
+            with child_scope(inner, 0):
+                return inner.apply(p, x, s, training=training, rng=r)
 
         out, new_s = jax.checkpoint(fn, policy=self.checkpoint_policy())(
             params[0], input, state[0], _child_rng(rng, 0))

@@ -15,7 +15,8 @@ from typing import List, Optional
 
 import jax.numpy as jnp
 
-from bigdl_tpu.nn.module import Module, Container, _child_rng
+from bigdl_tpu.nn.module import (Module, Container, _child_rng,
+                                 child_scope)
 from bigdl_tpu.nn.structural import _axis
 
 
@@ -30,8 +31,9 @@ class Concat(Container):
     def apply(self, params, input, state, training=False, rng=None):
         outs, new_states = [], []
         for i, child in enumerate(self.children):
-            o, s = child.apply(params[i], input, state[i], training=training,
-                               rng=_child_rng(rng, i))
+            with child_scope(child, i):
+                o, s = child.apply(params[i], input, state[i],
+                                   training=training, rng=_child_rng(rng, i))
             outs.append(o)
             new_states.append(s)
         ax = _axis(self.dimension, outs[0].ndim)
@@ -45,8 +47,9 @@ class ConcatTable(Container):
     def apply(self, params, input, state, training=False, rng=None):
         outs, new_states = [], []
         for i, child in enumerate(self.children):
-            o, s = child.apply(params[i], input, state[i], training=training,
-                               rng=_child_rng(rng, i))
+            with child_scope(child, i):
+                o, s = child.apply(params[i], input, state[i],
+                                   training=training, rng=_child_rng(rng, i))
             outs.append(o)
             new_states.append(s)
         return outs, new_states
@@ -58,8 +61,9 @@ class ParallelTable(Container):
     def apply(self, params, input, state, training=False, rng=None):
         outs, new_states = [], []
         for i, child in enumerate(self.children):
-            o, s = child.apply(params[i], input[i], state[i], training=training,
-                               rng=_child_rng(rng, i))
+            with child_scope(child, i):
+                o, s = child.apply(params[i], input[i], state[i],
+                                   training=training, rng=_child_rng(rng, i))
             outs.append(o)
             new_states.append(s)
         return outs, new_states
@@ -80,8 +84,9 @@ class MapTable(Container):
         outs = []
         s = state[0]
         for i, x in enumerate(input):
-            o, s = child.apply(params[0], x, s, training=training,
-                               rng=_child_rng(rng, i))
+            with child_scope(child, 0):
+                o, s = child.apply(params[0], x, s, training=training,
+                                   rng=_child_rng(rng, i))
             outs.append(o)
         return outs, [s]
 

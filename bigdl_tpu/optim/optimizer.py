@@ -124,15 +124,22 @@ def mixed_precision_forward(model: Module, params, inputs, mstate,
     bf16: parameters/inputs/state are cast down for the forward (autodiff
     casts gradients back up, so the update sees fp32 master-weight grads);
     outputs and new state return as fp32 for the loss and the carries.
+
+    The forward runs under the ``forward`` named scope (the backward
+    pass inherits it as ``transpose(jvp(forward))``) and the bf16 casts
+    under ``cast_params``: metadata only, so that a device trace can say
+    which part of the step an operation belongs to.
     """
     if precision == "bf16":
-        cp = cast_floats(params, jnp.bfloat16)
-        cx = cast_floats(inputs, jnp.bfloat16)
+        with jax.named_scope("cast_params"):
+            cp = cast_floats(params, jnp.bfloat16)
+            cx = cast_floats(inputs, jnp.bfloat16)
         # module state (BatchNorm running statistics) stays fp32 like the
         # master weights: EMA increments below bf16 resolution must not
         # round away, and fp32 state promotes the EMA arithmetic itself
-        out, new_mstate = model.apply(cp, cx, mstate, training=training,
-                                      rng=rng)
+        with jax.named_scope("forward"):
+            out, new_mstate = model.apply(cp, cx, mstate,
+                                          training=training, rng=rng)
         out = cast_floats(out, jnp.float32)
         from bigdl_tpu.utils import config
         if (config.get_bool("bigdl.chaos.f32Upcast", False)
@@ -144,7 +151,19 @@ def mixed_precision_forward(model: Module, params, inputs, mstate,
             eye = jnp.eye(jnp.shape(out)[-1], dtype=jnp.float32)
             out = out @ eye
         return out, cast_floats(new_mstate, jnp.float32)
-    return model.apply(params, inputs, mstate, training=training, rng=rng)
+    with jax.named_scope("forward"):
+        return model.apply(params, inputs, mstate, training=training,
+                           rng=rng)
+
+
+def _profiler_options():
+    """What the driver starts ``jax.profiler`` with: the Python tracer
+    off.  On, it records thousands of host events a step and slows the
+    host it looks at; the ``driver/*`` spans' twins (telemetry/tracer.py)
+    are what the host plane is for."""
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    return opts
 
 
 def moe_aux_penalty(model: Module, new_mstate, weight: float):
@@ -184,9 +203,15 @@ def select_tree(ok, new_tree, old_tree):
     """Per-leaf ``where(ok, new, old)`` — the divergence guard's in-step
     skip: when the step produced a non-finite loss or gradient, every
     carry keeps its pre-step value (``where(True, new, old)`` is exactly
-    ``new``, so a healthy step is numerically untouched)."""
-    return jax.tree_util.tree_map(
-        lambda n, o: jnp.where(ok, n, o), new_tree, old_tree)
+    ``new``, so a healthy step is numerically untouched).
+
+    Under the ``optimizer_update`` scope: XLA fuses these selects with
+    the update's arithmetic and names the fused operation after them, so
+    without it the update's device time reads ``jit(_where)/select_n``
+    (seen on the chip, PR 24)."""
+    with jax.named_scope("optimizer_update"):
+        return jax.tree_util.tree_map(
+            lambda n, o: jnp.where(ok, n, o), new_tree, old_tree)
 
 
 def regularization_penalty(module: Module, params) -> jnp.ndarray:
@@ -292,12 +317,6 @@ class Optimizer:
         self._profile_n: int = 3
         #: recompile sentinel wrapped around the fused step (analysis pass 1)
         self._retrace_sentinel = None
-        #: the unwrapped jitted step (the sentinel hides .lower) — the
-        #: telemetry FLOPs estimate lowers THIS
-        self._raw_step_fn = None
-        #: fused-step FLOPs from cost_analysis (bigdl.telemetry.mfu)
-        self._step_flops: Optional[float] = None
-        self._want_step_flops = False
         #: per-run step-time decomposition (bigdl_tpu.telemetry)
         self._step_account = None
         #: microbatch re-plan state (resources.microbatch): the fused
@@ -320,7 +339,10 @@ class Optimizer:
         the per-module clocks attribute time WITHIN the model graph, the
         trace shows the whole step — XLA fusions, collectives, host gaps.
         ``start_iteration`` defaults past compile+warmup so the captured
-        window is the steady state the throughput logs report."""
+        window is the steady state the throughput logs report.  The
+        session arms the program's own spans (``driver/*`` and every
+        other ``telemetry.span``) for as long as it lasts and gives each a
+        twin on the trace's host plane; the Python tracer stays off."""
         if n_iterations < 1:
             raise ValueError(f"n_iterations must be >= 1, got {n_iterations}")
         if start_iteration < 1:
@@ -716,7 +738,6 @@ class Optimizer:
         surfaced as ``Analysis/retraces`` in TrainSummary.  Host-driven
         feval methods (LBFGS) are not jitted per-step, so they pass
         through unwrapped."""
-        self._raw_step_fn = step_fn
         if getattr(self.optim_method, "requires_feval", False):
             return step_fn
         from bigdl_tpu.analysis.retrace import RetraceSentinel
@@ -726,43 +747,6 @@ class Optimizer:
             return step_fn
         self._retrace_sentinel = sentinel
         return sentinel.wrap(step_fn)
-
-    def _estimate_step_flops(self, args: Tuple) -> None:
-        """One-shot FLOPs estimate of the fused step from the lowered
-        HLO's ``cost_analysis()`` — a re-trace + lower, never a second
-        XLA compile (array args become ``ShapeDtypeStruct``s, so no
-        device data moves).  Enabled by ``bigdl.telemetry.mfu``; the
-        drain logs achieved TFLOP/s (or MFU against
-        ``bigdl.telemetry.peakTflops``) alongside the throughput line."""
-        self._want_step_flops = False
-        fn = self._raw_step_fn
-        if fn is None or not hasattr(fn, "lower"):
-            return
-        try:
-            def spec(x):
-                if hasattr(x, "shape") and hasattr(x, "dtype"):
-                    return jax.ShapeDtypeStruct(x.shape, x.dtype)
-                return x
-
-            specs = jax.tree_util.tree_map(spec, args)
-            # cost-analysis lowering only — never compiled, so it stays
-            # outside the executable cache
-            self._step_flops = telemetry.step_flops(
-                fn.lower(*specs))  # lint: allow(untracked-jit)
-            if self._step_flops:
-                logger.info("Fused step cost estimate: %.3f GFLOP/step",
-                            self._step_flops / 1e9)
-        except Exception as e:  # diagnostics must never fail a train step
-            logger.debug("fused-step FLOPs estimate unavailable: %s", e)
-
-    def _probe_step_flops(self, inputs, targets, hyper, rng) -> None:
-        """One-shot driver-side FLOPs probe: trainers that can reproduce
-        their step's full argument tuple install ``_cost_args_fn``; the
-        others simply have no MFU estimate."""
-        self._want_step_flops = False
-        args_fn = getattr(self, "_cost_args_fn", None)
-        if args_fn is not None:
-            self._estimate_step_flops(args_fn(inputs, targets, hyper, rng))
 
     def _warmup_compiles(self, inputs, targets, hyper, rng) -> None:
         """The AOT warmup phase: compile — or warm-load from the
@@ -919,17 +903,18 @@ class Optimizer:
 
         from bigdl_tpu.utils import config as _config
 
-        # -- telemetry: arm the tracer if configured, name the driver lane,
-        # and start a fresh per-run step account.  Per-run gauges from a
-        # previous optimize() in this process are dropped so a run that no
-        # longer produces them cannot re-chart stale values.
+        # -- telemetry: arm the tracer if configured, name the driver lane
+        # (always: a profiler session that starts mid-run arms the spans,
+        # and its readers find the lane by name), and start a fresh
+        # per-run step account.  Per-run gauges from a previous optimize()
+        # in this process are dropped so a run that no longer produces
+        # them cannot re-chart stale values.
         telemetry.maybe_arm_from_config()
-        if telemetry.tracing_enabled():
-            telemetry.name_thread("driver")
-            # per-run timeline: a second optimize() in this process must
-            # export only its own spans (rings stay registered, events
-            # and the trace epoch reset)
-            telemetry.reset_tracer()
+        telemetry.name_thread("driver")
+        # per-run timeline: a second optimize() in this process must
+        # export only its own spans (rings stay registered, events and
+        # the trace epoch reset)
+        telemetry.reset_tracer()
         telemetry.REGISTRY.drop_prefix("Telemetry/")
         telemetry.REGISTRY.drop_prefix("Analysis/")
         step_account = telemetry.StepAccount(
@@ -945,10 +930,6 @@ class Optimizer:
             "bigdl.telemetry.profileOnSlowStep")
         #: one-shot jax.profiler capture requested by the slow-step detector
         slow_req = {"due": False, "captured": False}
-        self._want_step_flops = (_config.get_bool("bigdl.telemetry.mfu",
-                                                  False)
-                                 and self._step_flops is None)
-        peak_tflops = _config.get_float("bigdl.telemetry.peakTflops", 0.0)
 
         # Dispatch pipeline: iteration i's loss is read (a blocking device
         # round-trip) only after up to ``bigdl.pipeline.depth`` further
@@ -983,17 +964,6 @@ class Optimizer:
             self.metrics.add("computing time for each node", dt)
             state["Loss"] = loss
             throughput = bsz / max(dt / 1e9, 1e-9)
-            mfu_note = ""
-            if self._step_flops:
-                tflops = self._step_flops / max(dt / 1e9, 1e-9) / 1e12
-                telemetry.gauge("Telemetry/tflops", summary=True).set(tflops)
-                if peak_tflops > 0:
-                    telemetry.gauge("Telemetry/mfu", summary=True).set(
-                        tflops / peak_tflops)
-                    mfu_note = (f" MFU is "
-                                f"{100 * tflops / peak_tflops:.1f}%.")
-                else:
-                    mfu_note = f" Achieved {tflops:.3f} TFLOP/s."
             # bigdl.telemetry.logEveryN rate-limits the per-iteration log
             # line (default 1 = the reference protocol, unchanged); the
             # skipped path formats nothing
@@ -1001,9 +971,9 @@ class Optimizer:
                 logger.info(
                     "[Epoch %d %d/%d][Iteration %d] Train %d in %.4f "
                     "seconds. Throughput is %.1f records/second. Loss is "
-                    "%.6f.%s",
+                    "%.6f.",
                     epoch, recs, epoch_size, neval, bsz, dt / 1e9,
-                    throughput, loss, mfu_note)
+                    throughput, loss)
             # divergence guard, host side: the in-step guard already kept
             # the params/slots/state carries at their pre-step values, so
             # a bad step costs one wasted iteration, not a poisoned model;
@@ -1196,7 +1166,8 @@ class Optimizer:
                     if jax.process_count() > 1:   # one capture per host
                         pdir = os.path.join(
                             pdir, f"process_{jax.process_index()}")
-                    jax.profiler.start_trace(pdir)
+                    jax.profiler.start_trace(
+                        pdir, profiler_options=_profiler_options())
                     profiling = profiled = True
                     profile_end = state["neval"] + self._profile_n
                 if (slow_req["due"] and not slow_req["captured"] and
@@ -1213,7 +1184,8 @@ class Optimizer:
                         pdir = os.path.join(
                             pdir, f"process_{jax.process_index()}")
                     self._profile_dir = pdir
-                    jax.profiler.start_trace(pdir)
+                    jax.profiler.start_trace(
+                        pdir, profiler_options=_profiler_options())
                     profiling = True
                     profile_end = state["neval"] + 1
                 if watchdog is not None:
@@ -1275,8 +1247,6 @@ class Optimizer:
                         # unguarded 15-45 s implicit compile
                         warmed["done"] = True
                         self._warmup_compiles(inputs, targets, hyper, rng)
-                    if self._want_step_flops:
-                        self._probe_step_flops(inputs, targets, hyper, rng)
                     t0 = telemetry.clock_ns()
                     with telemetry.span("driver/device_step"):
                         out = run_step(inputs, targets, hyper, rng)
@@ -1668,9 +1638,11 @@ class LocalOptimizer(Optimizer):
             def loss_fn(p):
                 out, new_mstate = mixed_precision_forward(
                     model, p, inputs, mstate, precision, True, rng)
-                loss = criterion.apply(out, targets)
-                loss = loss + regularization_penalty(model, p)
-                loss = loss + moe_aux_penalty(model, new_mstate, aux_weight)
+                with jax.named_scope("loss"):
+                    loss = criterion.apply(out, targets)
+                    loss = loss + regularization_penalty(model, p)
+                    loss = loss + moe_aux_penalty(model, new_mstate,
+                                                  aux_weight)
                 return loss, new_mstate
 
             if mb_k > 1:
@@ -1686,10 +1658,11 @@ class LocalOptimizer(Optimizer):
                     def chunk_loss(p):
                         out, nm = mixed_precision_forward(
                             model, p, cin, mstate, precision, True, rng)
-                        closs = criterion.apply(out, ctg)
-                        closs = closs + regularization_penalty(model, p)
-                        closs = closs + moe_aux_penalty(model, nm,
-                                                        aux_weight)
+                        with jax.named_scope("loss"):
+                            closs = criterion.apply(out, ctg)
+                            closs = closs + regularization_penalty(model, p)
+                            closs = closs + moe_aux_penalty(model, nm,
+                                                            aux_weight)
                         return closs, nm
 
                     (closs, nm), cg = jax.value_and_grad(
@@ -1701,8 +1674,9 @@ class LocalOptimizer(Optimizer):
             else:
                 (loss, new_mstate), grads = jax.value_and_grad(
                     loss_fn, has_aux=True)(params)
-            new_params, new_slots = optim.pure_update(grads, params, slots,
-                                                      hyper)
+            with jax.named_scope("optimizer_update"):
+                new_params, new_slots = optim.pure_update(grads, params,
+                                                          slots, hyper)
             aux: Dict[str, Any] = {}
             ok = None
             if guard:
@@ -1876,8 +1850,8 @@ class LocalOptimizer(Optimizer):
              loss) = out
             return loss
 
-        # telemetry MFU probe: the fused step's full argument tuple, for
-        # the one-shot cost_analysis lowering (bigdl.telemetry.mfu)
+        # the fused step's full argument tuple, for the AOT compile
+        # warmup (_warmup_compiles)
         def _cost_args(inputs, targets, hyper, rng):
             args = (carry["params"], carry["slots"], carry["mstate"],
                     inputs, targets, hyper, rng)

@@ -318,9 +318,11 @@ class DistriOptimizer(Optimizer):
                 p = arp.unflatten(flat)
                 out, new_mstate = mixed_precision_forward(
                     model, p, inputs, mstate, precision, True, rng)
-                loss = criterion.apply(out, targets)
-                loss = loss + regularization_penalty(model, p)
-                loss = loss + moe_aux_penalty(model, new_mstate, aux_weight)
+                with jax.named_scope("loss"):
+                    loss = criterion.apply(out, targets)
+                    loss = loss + regularization_penalty(model, p)
+                    loss = loss + moe_aux_penalty(model, new_mstate,
+                                                  aux_weight)
                 return loss, new_mstate
 
             (loss, new_mstate), flat_grads = jax.value_and_grad(
@@ -388,8 +390,9 @@ class DistriOptimizer(Optimizer):
                         g_k = arp.reduce_scatter_bucket(gmat[:, a:b],
                                                         axis) / n
                     s_k = jax.tree_util.tree_map(lambda v: v[a:b], slots)
-                    p_k, ns_k = optim.pure_update(g_k, param_row[a:b],
-                                                  s_k, hyper)
+                    with jax.named_scope("optimizer_update"):
+                        p_k, ns_k = optim.pure_update(
+                            g_k, param_row[a:b], s_k, hyper)
                     grad_b.append(g_k)
                     new_p.append(p_k)
                     new_s.append(ns_k)
@@ -446,8 +449,9 @@ class DistriOptimizer(Optimizer):
                                                           axis) / n
                 # ZeRO-1: update only this device's parameter slice + slots
                 param_shard = arp.local_shard(flat_params, axis)
-                new_shard, new_slots = optim.pure_update(
-                    grad_shard, param_shard, slots, hyper)
+                with jax.named_scope("optimizer_update"):
+                    new_shard, new_slots = optim.pure_update(
+                        grad_shard, param_shard, slots, hyper)
                 okf = None
                 if guard:
                     # divergence guard: non-finite loss/grad → every shard
@@ -777,8 +781,8 @@ class DistriOptimizer(Optimizer):
             (carry["flat"], carry["slots"], carry["mstate"], loss) = out
             return loss
 
-        # telemetry MFU probe (bigdl.telemetry.mfu): the fused sharded
-        # step's argument tuple for the one-shot cost_analysis lowering
+        # the fused sharded step's argument tuple, for the AOT compile
+        # warmup (Optimizer._warmup_compiles)
         def _cost_args(inputs, targets, hyper, rng):
             args = (carry["flat"], carry["slots"], carry["mstate"],
                     inputs, targets, hyper, rng)
@@ -1011,8 +1015,8 @@ class DistriOptimizer(Optimizer):
              loss) = out
             return loss
 
-        # telemetry MFU probe (bigdl.telemetry.mfu): the GSPMD step's
-        # argument tuple for the one-shot cost_analysis lowering
+        # the GSPMD step's argument tuple, for the AOT compile warmup
+        # (Optimizer._warmup_compiles)
         def _cost_args(inputs, targets, hyper, rng):
             args = (carry["params"], carry["slots"], carry["mstate"],
                     inputs, targets, hyper, rng)
@@ -1086,19 +1090,22 @@ class DistriOptimizer(Optimizer):
             def loss_fn(p):
                 out, new_mstate = mixed_precision_forward(
                     model, p, inputs, mstate, precision, True, rng)
-                loss = criterion.apply(out, targets)
-                loss = loss + regularization_penalty(model, p)
-                loss = loss + moe_aux_penalty(model, new_mstate, aux_weight)
+                with jax.named_scope("loss"):
+                    loss = criterion.apply(out, targets)
+                    loss = loss + regularization_penalty(model, p)
+                    loss = loss + moe_aux_penalty(model, new_mstate,
+                                                  aux_weight)
                 return loss, new_mstate
 
             (loss, new_mstate), grads = jax.value_and_grad(
                 loss_fn, has_aux=True)(params)
-            if groups is not None and len(groups) > 1:
-                new_params, new_slots = _bucketed_leaf_update(
-                    optim, groups, grads, params, slots, hyper)
-            else:
-                new_params, new_slots = optim.pure_update(grads, params,
-                                                          slots, hyper)
+            with jax.named_scope("optimizer_update"):
+                if groups is not None and len(groups) > 1:
+                    new_params, new_slots = _bucketed_leaf_update(
+                        optim, groups, grads, params, slots, hyper)
+                else:
+                    new_params, new_slots = optim.pure_update(
+                        grads, params, slots, hyper)
             aux = {}
             ok = None
             if guard:

@@ -272,6 +272,27 @@ def _layer_norm(x, p, eps):
     return out * p["w"] + p["b"]
 
 
+def _ffn(x, lyr, eps):
+    """The block's second residual, ``x + down(relu(up(ln2(x))))``, under
+    the ``ln2`` and ``ffn`` scopes of the layer's scope."""
+    with jax.named_scope("ln2"):
+        h = _layer_norm(x, lyr["ln2"], eps)
+    with jax.named_scope("ffn"):
+        h = jax.nn.relu(_apply_linear(h, lyr["ffn"]["up"]))
+        return x + _apply_linear(h, lyr["ffn"]["down"])
+
+
+def _head(x, dp, eps_f):
+    """Final norm, head and log-softmax of ``(rows, d)`` activations, each
+    under its scope."""
+    with jax.named_scope("ln_f"):
+        x = _layer_norm(x, dp["lnf"], eps_f)
+    with jax.named_scope("head"):
+        logits = _apply_linear(x, dp["head"])
+    with jax.named_scope("log_softmax"):
+        return jax.nn.log_softmax(logits, axis=-1)
+
+
 # ---------------------------------------------------------------------------
 # step builders (pure functions over the decode pytree + KV pools)
 # ---------------------------------------------------------------------------
@@ -296,33 +317,38 @@ def _build_decode_fn(graph: _LMGraph, block_size: int, max_blocks: int):
 
     def decode(dp, pool_k, pool_v, tokens, positions, tables, active):
         B = tokens.shape[0]
-        idx = jnp.clip(tokens.astype(jnp.int32) - 1, 0, vocab_in - 1)
-        x = jnp.take(dp["embed"], idx, axis=0)
-        x = x + jnp.take(pe, positions, axis=0)[:, None, :].astype(x.dtype)
-        blk = jnp.where(active, tables[jnp.arange(B),
-                                       positions // block_size],
-                        DUMP_BLOCK)
-        slot = positions % block_size
-        valid = ((jnp.arange(S)[None, :] <= positions[:, None]) &
-                 active[:, None])
+        with jax.named_scope("embed"):
+            idx = jnp.clip(tokens.astype(jnp.int32) - 1, 0, vocab_in - 1)
+            x = jnp.take(dp["embed"], idx, axis=0)
+            x = x + jnp.take(pe, positions,
+                             axis=0)[:, None, :].astype(x.dtype)
+            blk = jnp.where(active, tables[jnp.arange(B),
+                                           positions // block_size],
+                            DUMP_BLOCK)
+            slot = positions % block_size
+            valid = ((jnp.arange(S)[None, :] <= positions[:, None]) &
+                     active[:, None])
         for li, lyr in enumerate(dp["layers"]):
-            h = _layer_norm(x, lyr["ln1"], eps1[li])
-            q = _apply_linear(h, lyr["attn"]["wq"]).reshape(B, 1, H, Dh)
-            k = _apply_linear(h, lyr["attn"]["wk"]).reshape(B, 1, H, Dh)
-            v = _apply_linear(h, lyr["attn"]["wv"]).reshape(B, 1, H, Dh)
-            pool_k = pool_k.at[li, blk, slot].set(k[:, 0])
-            pool_v = pool_v.at[li, blk, slot].set(v[:, 0])
-            k_ctx = pool_k[li][tables].reshape(B, S, H, Dh)
-            v_ctx = pool_v[li][tables].reshape(B, S, H, Dh)
-            att = paged_attention(q, k_ctx, v_ctx, valid)
-            x = x + _apply_linear(att.reshape(B, 1, H * Dh),
-                                  lyr["attn"]["wo"])
-            h = _layer_norm(x, lyr["ln2"], eps2[li])
-            h = jax.nn.relu(_apply_linear(h, lyr["ffn"]["up"]))
-            x = x + _apply_linear(h, lyr["ffn"]["down"])
-        x = _layer_norm(x, dp["lnf"], eps_f)
-        logits = _apply_linear(x[:, 0], dp["head"])
-        return jax.nn.log_softmax(logits, axis=-1), pool_k, pool_v
+            with jax.named_scope(f"layer{li}"):
+                with jax.named_scope("ln1"):
+                    h = _layer_norm(x, lyr["ln1"], eps1[li])
+                with jax.named_scope("qkv"):
+                    q, k, v = (_apply_linear(h, lyr["attn"][w])
+                               .reshape(B, 1, H, Dh)
+                               for w in ("wq", "wk", "wv"))
+                with jax.named_scope("kv_scatter"):
+                    pool_k = pool_k.at[li, blk, slot].set(k[:, 0])
+                    pool_v = pool_v.at[li, blk, slot].set(v[:, 0])
+                with jax.named_scope("kv_gather"):
+                    k_ctx = pool_k[li][tables].reshape(B, S, H, Dh)
+                    v_ctx = pool_v[li][tables].reshape(B, S, H, Dh)
+                with jax.named_scope("attn"):
+                    att = paged_attention(q, k_ctx, v_ctx, valid)
+                with jax.named_scope("out"):
+                    x = x + _apply_linear(att.reshape(B, 1, H * Dh),
+                                          lyr["attn"]["wo"])
+                x = _ffn(x, lyr, eps2[li])
+        return _head(x[:, 0], dp, eps_f), pool_k, pool_v
 
     return decode
 
@@ -343,29 +369,32 @@ def _build_prefill_fn(graph: _LMGraph, block_size: int):
 
     def prefill(dp, pool_k, pool_v, tokens, length, table):
         T = tokens.shape[1]
-        idx = jnp.clip(tokens.astype(jnp.int32) - 1, 0, vocab_in - 1)
-        x = jnp.take(dp["embed"], idx, axis=0)
-        x = x + pe[:T][None].astype(x.dtype)
-        pos = jnp.arange(T)
-        blkrow = jnp.where(pos < length, table[pos // block_size],
-                           DUMP_BLOCK)
-        slotrow = pos % block_size
+        with jax.named_scope("embed"):
+            idx = jnp.clip(tokens.astype(jnp.int32) - 1, 0, vocab_in - 1)
+            x = jnp.take(dp["embed"], idx, axis=0)
+            x = x + pe[:T][None].astype(x.dtype)
+            pos = jnp.arange(T)
+            blkrow = jnp.where(pos < length, table[pos // block_size],
+                               DUMP_BLOCK)
+            slotrow = pos % block_size
         for li, lyr in enumerate(dp["layers"]):
-            h = _layer_norm(x, lyr["ln1"], eps1[li])
-            q = _apply_linear(h, lyr["attn"]["wq"]).reshape(1, T, H, Dh)
-            k = _apply_linear(h, lyr["attn"]["wk"]).reshape(1, T, H, Dh)
-            v = _apply_linear(h, lyr["attn"]["wv"]).reshape(1, T, H, Dh)
-            pool_k = pool_k.at[li, blkrow, slotrow].set(k[0])
-            pool_v = pool_v.at[li, blkrow, slotrow].set(v[0])
-            att = scaled_dot_product_attention(q, k, v, causal=True)
-            x = x + _apply_linear(att.reshape(1, T, H * Dh),
-                                  lyr["attn"]["wo"])
-            h = _layer_norm(x, lyr["ln2"], eps2[li])
-            h = jax.nn.relu(_apply_linear(h, lyr["ffn"]["up"]))
-            x = x + _apply_linear(h, lyr["ffn"]["down"])
-        x = _layer_norm(x, dp["lnf"], eps_f)
-        logits = _apply_linear(x[0], dp["head"])
-        logp = jax.nn.log_softmax(logits, axis=-1)
+            with jax.named_scope(f"layer{li}"):
+                with jax.named_scope("ln1"):
+                    h = _layer_norm(x, lyr["ln1"], eps1[li])
+                with jax.named_scope("qkv"):
+                    q, k, v = (_apply_linear(h, lyr["attn"][w])
+                               .reshape(1, T, H, Dh)
+                               for w in ("wq", "wk", "wv"))
+                with jax.named_scope("kv_scatter"):
+                    pool_k = pool_k.at[li, blkrow, slotrow].set(k[0])
+                    pool_v = pool_v.at[li, blkrow, slotrow].set(v[0])
+                with jax.named_scope("attn"):
+                    att = scaled_dot_product_attention(q, k, v, causal=True)
+                with jax.named_scope("out"):
+                    x = x + _apply_linear(att.reshape(1, T, H * Dh),
+                                          lyr["attn"]["wo"])
+                x = _ffn(x, lyr, eps2[li])
+        logp = _head(x[0], dp, eps_f)
         return jnp.take(logp, length - 1, axis=0), pool_k, pool_v
 
     return prefill
@@ -385,23 +414,25 @@ def _build_full_fn(graph: _LMGraph):
 
     def full(dp, tokens):
         T = tokens.shape[1]
-        idx = jnp.clip(tokens.astype(jnp.int32) - 1, 0, vocab_in - 1)
-        x = jnp.take(dp["embed"], idx, axis=0)
-        x = x + pe[:T][None].astype(x.dtype)
+        with jax.named_scope("embed"):
+            idx = jnp.clip(tokens.astype(jnp.int32) - 1, 0, vocab_in - 1)
+            x = jnp.take(dp["embed"], idx, axis=0)
+            x = x + pe[:T][None].astype(x.dtype)
         for li, lyr in enumerate(dp["layers"]):
-            h = _layer_norm(x, lyr["ln1"], eps1[li])
-            q = _apply_linear(h, lyr["attn"]["wq"]).reshape(1, T, H, Dh)
-            k = _apply_linear(h, lyr["attn"]["wk"]).reshape(1, T, H, Dh)
-            v = _apply_linear(h, lyr["attn"]["wv"]).reshape(1, T, H, Dh)
-            att = scaled_dot_product_attention(q, k, v, causal=True)
-            x = x + _apply_linear(att.reshape(1, T, H * Dh),
-                                  lyr["attn"]["wo"])
-            h = _layer_norm(x, lyr["ln2"], eps2[li])
-            h = jax.nn.relu(_apply_linear(h, lyr["ffn"]["up"]))
-            x = x + _apply_linear(h, lyr["ffn"]["down"])
-        x = _layer_norm(x, dp["lnf"], eps_f)
-        logits = _apply_linear(x[0], dp["head"])
-        return jax.nn.log_softmax(logits, axis=-1)
+            with jax.named_scope(f"layer{li}"):
+                with jax.named_scope("ln1"):
+                    h = _layer_norm(x, lyr["ln1"], eps1[li])
+                with jax.named_scope("qkv"):
+                    q, k, v = (_apply_linear(h, lyr["attn"][w])
+                               .reshape(1, T, H, Dh)
+                               for w in ("wq", "wk", "wv"))
+                with jax.named_scope("attn"):
+                    att = scaled_dot_product_attention(q, k, v, causal=True)
+                with jax.named_scope("out"):
+                    x = x + _apply_linear(att.reshape(1, T, H * Dh),
+                                          lyr["attn"]["wo"])
+                x = _ffn(x, lyr, eps2[li])
+        return _head(x[0], dp, eps_f)
 
     return full
 
@@ -672,6 +703,18 @@ class LMServingEngine:
         self._latency = telemetry.histogram(
             "LM/latency_ms", window=window,
             help="per-request submit-to-terminal latency")
+        self._queue_wait = telemetry.histogram(
+            "LM/queue_wait_ms", window=window,
+            help="submit to the scheduler's pop, per admitted request")
+        self._prefill_ms = telemetry.histogram(
+            "LM/prefill_ms", window=window,
+            help="prefill dispatch to its log-probs on the host, per "
+                 "admitted request")
+        self._h2d = telemetry.counter(
+            "LM/h2d_bytes",
+            help="host arrays handed to the prefill and decode steps")
+        self._d2h = telemetry.counter(
+            "LM/d2h_bytes", help="log-probs pulled from the two steps")
 
         self.quantization_report: Optional[Dict[str, Any]] = None
         if self._dp_q is not None:
@@ -1150,14 +1193,25 @@ class LMServingEngine:
                         drained = True
                         continue
                 active = True
+                # one lm/iteration tree per pass that has work; a pass
+                # that only polls the empty queue is lm/idle_wait alone.
+                # An admission pass over nothing finds nothing, so one
+                # look at the queue decides both
                 try:
                     # the watchdog abort can surface during admission
                     # (validate/prefill) just as during decode — both run
                     # on the step clock, so both sit under one handler
-                    self._admit_waiting(wd)
-                    active = self._any_active()
-                    if active:
-                        self._decode_iteration(wd)
+                    n_active = sum(s is not None for s in self._slots)
+                    waiting = bool(self._pending) or not self._q.empty()
+                    with (telemetry.span("lm/iteration",
+                                         step=self.decode_steps + 1,
+                                         active=n_active)
+                          if n_active or waiting else nullcontext()):
+                        if waiting:
+                            self._admit_waiting(wd)
+                        active = self._any_active()
+                        if active:
+                            self._decode_iteration(wd)
                 except HungDispatchError:
                     ema = self._ema.ema
                     baseline = (f"{ema:.1f} ms EMA" if ema is not None
@@ -1176,7 +1230,8 @@ class LMServingEngine:
                 if not active:
                     try:
                         with (wd.paused() if wd is not None
-                              else nullcontext()):
+                              else nullcontext()), \
+                                telemetry.span("lm/idle_wait"):
                             stream = self._q.get(
                                 timeout=self.poll_interval)
                         with self._lock:
@@ -1291,12 +1346,18 @@ class LMServingEngine:
         (neither consumes a slot); a block-starved prompt goes back to
         the FRONT of the holdover (FIFO) and admission stops until a
         finishing sequence frees blocks."""
+        with telemetry.span("lm/admit") as sp:
+            sp.set(admitted=self._admit_into_slots(wd))
+
+    def _admit_into_slots(self, wd) -> int:
+        """The admission pass itself; the number of prompts prefilled."""
         from bigdl_tpu.utils import chaos
+        admitted = 0
         for _ in range(self.max_batch):
             slot_idx = next((i for i, s in enumerate(self._slots)
                              if s is None), None)
             if slot_idx is None:
-                return
+                return admitted
             stream = None
             with self._lock:
                 if self._pending:
@@ -1305,7 +1366,7 @@ class LMServingEngine:
                 try:
                     stream = self._q.get_nowait()
                 except queue.Empty:
-                    return
+                    return admitted
             # published so the watchdog's async abort cannot strand a
             # stream that lives only in this local (cleared at every
             # resting point; double-finish below is a guarded no-op)
@@ -1343,13 +1404,18 @@ class LMServingEngine:
                 with self._lock:
                     self._pending.appendleft(stream)
                 self._admitting = None
-                return
+                return admitted
             self.cache.allocate(stream.seq_id,
                                 prompt.size + stream.max_new_tokens)
             t_pf = telemetry.clock_ns()
             try:
-                tok, table_row = self._prefill_step_raw(stream.seq_id,
-                                                        prompt)
+                with telemetry.span(
+                        "lm/prefill", prompt_tokens=int(prompt.size),
+                        bucket=self._prefill_bucket(int(prompt.size)),
+                        active=sum(s is not None for s in self._slots),
+                        trace_id=stream.trace_id):
+                    tok, table_row = self._prefill_step_raw(stream.seq_id,
+                                                            prompt)
             except Exception as e:  # noqa: BLE001 — fail one stream
                 self.cache.free_seq(stream.seq_id)
                 self._finish_stream(stream, "shed", error=ServingInfraError(
@@ -1358,9 +1424,15 @@ class LMServingEngine:
                 continue
             if wd is not None:
                 wd.heartbeat()
+            t_done = telemetry.clock_ns()
             request_trace.record_span(stream.trace_id, "request/prefill",
-                                      t_pf, telemetry.clock_ns(),
+                                      t_pf, t_done,
                                       prompt_tokens=int(prompt.size))
+            # observed here, not in _prefill_step_raw: generate()'s
+            # offline prefills are no admissions and must not count
+            admitted += 1
+            self._queue_wait.observe((now - stream.submit_ns) / 1e6)
+            self._prefill_ms.observe((t_done - t_pf) / 1e6)
             stream._emit(tok)
             request_trace.instant(stream.trace_id, "request/emit",
                                   token=int(tok), first=True)
@@ -1378,6 +1450,7 @@ class LMServingEngine:
                                           table_row)
             self._admitting = None
         telemetry.gauge("LM/queue_depth").set(self.queue_depth())
+        return admitted
 
     def _prefill_step_raw(self, seq_id: int, prompt: np.ndarray
                           ) -> Tuple[int, np.ndarray]:
@@ -1386,7 +1459,6 @@ class LMServingEngine:
         (1-based) and the dump-padded table row the decode step
         gathers through."""
         from bigdl_tpu.analysis.hostsync import host_pull
-        t0 = telemetry.clock_ns()
         P = int(prompt.size)
         bucket = self._prefill_bucket(P)
         padded = np.ones((1, bucket), np.int32)
@@ -1399,12 +1471,13 @@ class LMServingEngine:
                                          np.int32(P), table_row)
         with self._lock:
             self.cache.k, self.cache.v = new_k, new_v
-        lp = np.asarray(host_pull(lp, what="lm prefill logits"))
+        with telemetry.span("lm/prefill_wait"):
+            lp = np.asarray(host_pull(lp, what="lm prefill logits"))
+        self._h2d.inc(padded.nbytes + 4 + table_row.nbytes)
+        self._d2h.inc(lp.nbytes)
         with self._lock:
             self.prefills += 1
         telemetry.counter("LM/prefills").inc()
-        telemetry.gauge("LM/prefill_ms").set(
-            (telemetry.clock_ns() - t0) / 1e6)
         return int(np.argmax(lp)) + 1, table_row
 
     def _decode_iteration(self, wd) -> None:
@@ -1438,25 +1511,50 @@ class LMServingEngine:
                 return
         t0 = telemetry.clock_ns()
         B, MB = self.max_batch, self._max_blocks
-        tokens = np.ones((B, 1), np.int32)
-        positions = np.zeros((B,), np.int32)
-        tables = np.full((B, MB), DUMP_BLOCK, np.int32)
-        active = np.zeros((B,), bool)
-        for i, slot in enumerate(self._slots):
-            if slot is None:
-                continue
-            tokens[i, 0] = slot.last_token
-            positions[i] = slot.position
-            tables[i] = slot.table_row
-            active[i] = True
+        with telemetry.span("lm/decode_build"):
+            tokens = np.ones((B, 1), np.int32)
+            positions = np.zeros((B,), np.int32)
+            tables = np.full((B, MB), DUMP_BLOCK, np.int32)
+            active = np.zeros((B,), bool)
+            for i, slot in enumerate(self._slots):
+                if slot is None:
+                    continue
+                tokens[i, 0] = slot.last_token
+                positions[i] = slot.position
+                tables[i] = slot.table_row
+                active[i] = True
         dp, fn = ((self._dp_q, self._decode_q)
                   if self._dp_q is not None else (self._dp, self._decode))
-        lp, new_k, new_v = fn(dp, self.cache.k, self.cache.v, tokens,
-                              positions, tables, active)
+        with telemetry.span("lm/decode_dispatch"):
+            lp, new_k, new_v = fn(dp, self.cache.k, self.cache.v, tokens,
+                                  positions, tables, active)
         with self._lock:
             self.cache.k, self.cache.v = new_k, new_v
-        lp = np.asarray(host_pull(lp, what="lm decode logits"))
+        # the device time still to run plus the transfer of the whole
+        # (max_batch, vocab) float32 log-probs
+        with telemetry.span("lm/decode_wait", bytes=lp.nbytes):
+            lp = np.asarray(host_pull(lp, what="lm decode logits"))
+        self._h2d.inc(tokens.nbytes + positions.nbytes + tables.nbytes
+                      + active.nbytes)
+        self._d2h.inc(lp.nbytes)
         now = telemetry.clock_ns()
+        with telemetry.span("lm/emit", tokens=int(active.sum())):
+            self._emit_tokens(lp, t0, now, step)
+        ms = (telemetry.clock_ns() - t0) / 1e6
+        self._ema.observe(ms)
+        if wd is not None:
+            wd.heartbeat()
+        with self._lock:
+            if self._cooldown > 0:
+                self._cooldown -= 1
+        telemetry.gauge("LM/decode_ms").set(ms)
+        telemetry.gauge("LM/slot_occupancy").set(
+            sum(s is not None for s in self._slots) / max(1, B))
+
+    def _emit_tokens(self, lp: np.ndarray, t0: int, now: int,
+                     step: int) -> None:
+        """The per-slot half of a decode iteration: arg-max, emit, and
+        for a sequence that ends here finish, vacate and free."""
         for i, slot in enumerate(self._slots):
             if slot is None:
                 continue
@@ -1495,16 +1593,6 @@ class LMServingEngine:
                     reason="expired")
                 self._slots[i] = None
                 self.cache.free_seq(stream.seq_id)
-        ms = (telemetry.clock_ns() - t0) / 1e6
-        self._ema.observe(ms)
-        if wd is not None:
-            wd.heartbeat()
-        with self._lock:
-            if self._cooldown > 0:
-                self._cooldown -= 1
-        telemetry.gauge("LM/decode_ms").set(ms)
-        telemetry.gauge("LM/slot_occupancy").set(
-            sum(s is not None for s in self._slots) / max(1, B))
 
     # -- offline generation (parity + baseline) ---------------------------
 
@@ -1563,6 +1651,9 @@ class LMServingEngine:
                                       tokens, positions, tables, active)
                 with self._lock:
                     self.cache.k, self.cache.v = new_k, new_v
+                self._h2d.inc(tokens.nbytes + positions.nbytes
+                              + tables.nbytes + active.nbytes)
+                self._d2h.inc(lp.nbytes)
                 row = np.asarray(host_pull(
                     lp, what="lm offline decode logits"))[0]
                 out_tokens.append(int(np.argmax(row)) + 1)

@@ -12,9 +12,11 @@ archaeology.  Three pillars, one package:
    fetch/transfer threads, the async checkpoint writer, and the
    compile-warmup phase (``driver/compile_warmup`` wrapping one
    ``compile/<step>`` span per trace/cache-load/compile, from
-   ``utils/compile_cache``) into one Perfetto-loadable timeline.  Free
-   when disarmed; allocation-light and device-value-free when armed
-   (the strict host-sync guard stays green over traced runs).
+   ``utils/compile_cache``) into one Perfetto-loadable timeline.  Armed
+   explicitly (``bigdl.telemetry.trace``) or by a running ``jax.profiler``
+   session, under which every span has a twin on the profiler's host
+   plane; free otherwise; allocation-light and device-value-free when
+   armed (the strict host-sync guard stays green over traced runs).
 2. **Step-time decomposition** (:mod:`~bigdl_tpu.telemetry.step_stats`)
    — every optimizer step is accounted into data-wait / compute /
    host-pull / bookkeeping plus an explicit signed ``unaccounted``
@@ -45,13 +47,13 @@ from __future__ import annotations
 from bigdl_tpu.telemetry.tracer import (add_span, add_span_s, arm, clock_ns,
                                         disarm, events, export_chrome_trace,
                                         instant, maybe_arm_from_config,
-                                        name_thread, span, tracing_enabled)
+                                        name_thread, recording, self_times,
+                                        span, tracing_enabled)
 from bigdl_tpu.telemetry.tracer import reset as reset_tracer
 from bigdl_tpu.telemetry.metrics import (Counter, Gauge, Histogram,
                                          MetricsRegistry, REGISTRY)
 from bigdl_tpu.telemetry.step_stats import (PARTS, SlowStepDetector,
-                                            StepAccount, WindowedPercentiles,
-                                            step_flops)
+                                            StepAccount, WindowedPercentiles)
 from bigdl_tpu.telemetry import incident, request_trace
 
 
@@ -80,13 +82,13 @@ __all__ = [
     # tracer
     "span", "instant", "add_span", "add_span_s", "clock_ns", "arm",
     "disarm", "tracing_enabled", "maybe_arm_from_config", "name_thread",
-    "events", "export_chrome_trace", "reset_tracer",
+    "events", "export_chrome_trace", "reset_tracer", "recording",
+    "self_times",
     # metrics
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "REGISTRY",
     "counter", "gauge", "histogram", "summary_scalars",
     # step stats
     "PARTS", "StepAccount", "WindowedPercentiles", "SlowStepDetector",
-    "step_flops",
     # per-request tracing + incident flight recorder
     "request_trace", "incident",
 ]

@@ -30,11 +30,12 @@ observations (:meth:`~bigdl_tpu.telemetry.metrics.Histogram.observe`
 with ``exemplar=``), so "show me a p99 request" is
 ``hist.tail_exemplar()`` → :func:`get` — one lookup.
 
-Cost model (the ``bench.py --trace-only`` contract: armed < 1% of the
-serving p50, disarmed ≤ 0.25%): disarmed, :func:`mint` is one dict load
-returning ``None`` and every recorder no-ops on a ``None`` id; armed, a
-span is one tuple append onto the trace's bounded list plus the tracer
-ring mirror.  The registry is bounded two ways: at most
+Cost model: disarmed, :func:`mint` is one dict load returning ``None``
+and every recorder no-ops on a ``None`` id; armed, a span is one tuple
+append onto the trace's bounded list plus the tracer ring mirror (the
+tracer's own cost per span, measured on the chip's host, is in PERF.md
+section 6).  This module keeps its own switch: a profiler session arms
+the span tracer, not request traces.  The registry is bounded two ways: at most
 ``bigdl.trace.maxTraces`` retained traces (oldest evicted first) and at
 most ``bigdl.trace.maxSpansPerTrace`` spans per trace (the trace is
 flagged ``truncated`` instead of growing without bound).

@@ -212,20 +212,3 @@ class StepAccount:
                 out[f"p{q}_ms"] = v
         return out
 
-
-def step_flops(lowered) -> Optional[float]:
-    """Pull the per-step FLOP count out of a ``jax.stages.Lowered`` cost
-    analysis (no XLA compile — the estimate comes from the lowered HLO).
-    None when the backend/version exposes nothing usable."""
-    try:
-        ca = lowered.cost_analysis()
-    except Exception:
-        return None
-    if isinstance(ca, (list, tuple)):       # older jax: one dict per device
-        ca = ca[0] if ca else None
-    if not isinstance(ca, dict):
-        return None
-    flops = ca.get("flops")
-    if flops is None or not math.isfinite(flops) or flops <= 0:
-        return None
-    return float(flops)

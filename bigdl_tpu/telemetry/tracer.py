@@ -10,13 +10,29 @@ trace-event JSON (the ``chrome://tracing`` / Perfetto format), one lane
 per thread, so a single file shows whether the pipeline stages actually
 overlap.
 
-Cost model (the <1%-of-step-time contract ``bench.py --telemetry-only``
-measures): disarmed, ``span()`` is one module-dict load plus a shared
-no-op context manager — no clock read, no allocation.  Armed, a span
-costs two ``monotonic_ns`` reads and one tuple append into a
-``deque(maxlen=...)``.  Spans never touch device values — arming the
-tracer cannot introduce a host sync (the strict host-sync guard stays
-armed over traced runs in the tier-1 suite to prove it).
+Arming.  ``span()`` records when the tracer was armed explicitly
+(:func:`arm`, ``bigdl.telemetry.trace``) OR while a ``jax.profiler``
+session runs (``TraceAnnotation.is_enabled()``): a profiler capture arms
+the program's own spans for as long as it lasts, with no switch of its
+own.  A span recorded under a session also enters a
+``jax.profiler.TraceAnnotation`` of the same name and args for its
+duration, so the same interval lands on the host plane of the
+``.xplane.pb``, on the clock the device operations are on — one ``with``
+block, two sinks, no offset arithmetic.  :func:`add_span`,
+:func:`add_span_s` and :func:`instant` write the ring only.
+
+A span's parent is the enclosing span on the same lane (spans are
+context managers, so containment is exact); :func:`self_times` gives
+each span its duration less the children it covers.
+
+Cost model (measured on the chip's host, PERF.md section 6): with no
+session and no explicit arm, ``span()`` is one ``is_enabled()`` call,
+one module-dict load and a shared no-op context manager — no clock
+read, no allocation.  Armed, a span costs two ``monotonic_ns`` reads and
+one tuple append into a ``deque(maxlen=...)``; under a session the twin
+adds one ``TraceAnnotation``.  Spans never touch device values — arming
+the tracer cannot introduce a host sync (the strict host-sync guard
+stays armed over traced runs in the tier-1 suite to prove it).
 
 The tracer's clock — :func:`clock_ns` — is THE timer for hot-path code:
 the ``raw-clock-in-hot-path`` lint rule flags direct ``time.*`` reads in
@@ -31,7 +47,13 @@ import json
 import threading
 import time
 from collections import deque
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, Optional, Tuple
+
+from jax.profiler import TraceAnnotation as _Twin
+
+#: True between ``jax.profiler.start_trace`` and ``stop_trace`` (about
+#: 20 ns): the second way, beside :func:`arm`, in which spans are armed
+_session_on = _Twin.is_enabled
 
 #: the one hot-path clock: monotonic (immune to wall-clock steps), ns
 #: resolution, same epoch as ``time.monotonic()`` (fractional seconds
@@ -106,29 +128,56 @@ class _NullSpan:
     def __exit__(self, *exc):
         return False
 
+    def set(self, **args):
+        pass
+
 
 _NULL_SPAN = _NullSpan()
 
 
 class _Span:
-    __slots__ = ("name", "args", "t0")
+    __slots__ = ("name", "args", "t0", "twin")
 
-    def __init__(self, name: str, args: Optional[dict]):
+    def __init__(self, name: str, args: Optional[dict],
+                 twin: Optional[_Twin]):
         self.name = name
         self.args = args
+        self.twin = twin
 
     def __enter__(self):
+        if self.twin is not None:
+            self.twin.__enter__()
         self.t0 = clock_ns()
         return self
 
     def __exit__(self, *exc):
-        _tls_ring().events.append((self.name, self.t0, clock_ns(),
-                                   self.args))
+        t1 = clock_ns()
+        if self.twin is not None:
+            self.twin.__exit__(*exc)
+        _tls_ring().events.append((self.name, self.t0, t1, self.args))
         return False
+
+    def set(self, **args):
+        """Args known only inside the block (``admitted=3``): added to
+        the ring's record and to the twin's stats."""
+        if self.args is None:
+            self.args = args
+        else:
+            self.args.update(args)
+        if self.twin is not None:
+            self.twin.set_metadata(**args)
 
 
 def tracing_enabled() -> bool:
+    """Armed explicitly (:func:`arm`).  A profiler session arms
+    :func:`span` too, but owns no timeline export: see :func:`recording`."""
     return _STATE["enabled"]
+
+
+def recording() -> bool:
+    """Whether a span made now is recorded: armed explicitly, or a
+    ``jax.profiler`` session is running."""
+    return _STATE["enabled"] or _session_on()
 
 
 def arm(ring_size: Optional[int] = None) -> None:
@@ -170,10 +219,14 @@ def reset() -> None:
 
 def span(name: str, **args):
     """``with telemetry.span("optim/device_step"): ...`` — record the
-    enclosed wall interval on this thread's lane.  Free when disarmed."""
-    if not _STATE["enabled"]:
-        return _NULL_SPAN
-    return _Span(name, args or None)
+    enclosed wall interval on this thread's lane, and under a profiler
+    session on the profiler's host plane too.  With neither switch: one
+    ``is_enabled()`` call and the shared no-op."""
+    if _session_on():
+        return _Span(name, args or None, _Twin(name, **args))
+    if _STATE["enabled"]:
+        return _Span(name, args or None, None)
+    return _NULL_SPAN
 
 
 def add_span(name: str, t0_ns: int, t1_ns: int,
@@ -181,7 +234,7 @@ def add_span(name: str, t0_ns: int, t1_ns: int,
     """Record an already-measured interval (both endpoints from
     :func:`clock_ns`).  For call sites that time work anyway (the ingest
     stage counters): no extra clock reads."""
-    if _STATE["enabled"]:
+    if recording():
         _tls_ring().events.append((name, t0_ns, t1_ns, args))
 
 
@@ -189,7 +242,7 @@ def add_span_s(name: str, t0_s: float, t1_s: float,
                args: Optional[dict] = None) -> None:
     """:func:`add_span` for endpoints measured with ``time.monotonic()``
     (fractional seconds, same epoch as the ns clock)."""
-    if _STATE["enabled"]:
+    if recording():
         _tls_ring().events.append((name, int(t0_s * 1e9), int(t1_s * 1e9),
                                    args))
 
@@ -197,7 +250,7 @@ def add_span_s(name: str, t0_s: float, t1_s: float,
 def instant(name: str, **args) -> None:
     """A zero-duration marker on this thread's lane (slow-step flags,
     epoch rollovers)."""
-    if _STATE["enabled"]:
+    if recording():
         _tls_ring().events.append((name, clock_ns(), None, args or None))
 
 
@@ -218,6 +271,26 @@ def events() -> List[dict]:
             out.append({"lane": lane, "thread": lname, "name": name,
                         "t0_ns": t0, "t1_ns": t1, "args": args})
     return out
+
+
+def self_times(spans: List[dict]) -> List[Tuple[dict, int]]:
+    """``[(span, self_ns)]`` by start time for the spans of ONE lane (as
+    :func:`events` gives them; instants are skipped): a span's parent is
+    the span that encloses it, and its self time is its duration less the
+    children it covers.  The self times of a lane sum to what its
+    top-level spans cover, so a lane whose loop is spanned end to end
+    accounts for its whole slice."""
+    spans = sorted((e for e in spans if e["t1_ns"] is not None),
+                   key=lambda e: (e["t0_ns"], -e["t1_ns"]))
+    own = [e["t1_ns"] - e["t0_ns"] for e in spans]
+    stack: List[int] = []
+    for i, e in enumerate(spans):
+        while stack and spans[stack[-1]]["t1_ns"] < e["t1_ns"]:
+            stack.pop()
+        if stack:
+            own[stack[-1]] -= e["t1_ns"] - e["t0_ns"]
+        stack.append(i)
+    return list(zip(spans, own))
 
 
 def export_chrome_trace(path: Optional[str] = None) -> dict:

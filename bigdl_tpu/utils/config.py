@@ -254,8 +254,6 @@ _DEFAULTS: Dict[str, Any] = {
     "bigdl.telemetry.slowStepWarmup": 5,   # EMA warmup steps before detection
     "bigdl.telemetry.slowStepCooldown": 50,  # min steps between anomaly windows
     "bigdl.telemetry.profileOnSlowStep": None,  # dir: capture jax.profiler + timeline
-    "bigdl.telemetry.mfu": False,          # estimate fused-step FLOPs -> MFU logging
-    "bigdl.telemetry.peakTflops": None,    # chip peak for MFU% (None: log TFLOP/s)
     "bigdl.telemetry.maxTimelineDumps": 8,  # timeline dump files per run
     # (slow-step detector + watchdog), oldest-first eviction; 0 disables
     # resource-exhaustion resilience (bigdl_tpu/resources): HBM preflight

@@ -410,52 +410,3 @@ def test_slow_step_capture_writes_profile_and_timeline(tmp_path):
                   "profileOnSlowStep"):
             config.clear_property(f"bigdl.telemetry.{k}")
         config.clear_property("bigdl.pipeline.depth")
-
-
-def test_mfu_estimate_logged_with_throughput_line():
-    """bigdl.telemetry.mfu: the fused step's cost_analysis FLOPs extend
-    the reference throughput line and chart Telemetry/tflops.  A direct
-    handler (not caplog) — earlier tests may leave the bigdl_tpu logger
-    non-propagating via redirect_spark_info_logs."""
-    import logging
-
-    import jax
-
-    import bigdl_tpu.nn as nn
-    import bigdl_tpu.optim as optim
-    from bigdl_tpu.dataset import LocalDataSet, SampleToMiniBatch
-    from bigdl_tpu.dataset.datasets import synthetic_separable
-
-    class Tap(logging.Handler):
-        def __init__(self):
-            super().__init__()
-            self.lines = []
-
-        def emit(self, record):
-            msg = record.getMessage()
-            if "Throughput is" in msg:
-                self.lines.append(msg)
-
-    config.set_property("bigdl.telemetry.mfu", True)
-    config.set_property("bigdl.telemetry.peakTflops", 100.0)
-    lg = logging.getLogger("bigdl_tpu")
-    tap = Tap()
-    level = lg.level
-    lg.addHandler(tap)
-    lg.setLevel(logging.INFO)
-    try:
-        samples = synthetic_separable(64, 8, n_classes=2, seed=2)
-        ds = LocalDataSet(samples).transform(SampleToMiniBatch(16))
-        model = (nn.Sequential().add(nn.Linear(8, 4)).add(nn.LogSoftMax()))
-        model.reset(jax.random.PRNGKey(1))
-        opt = optim.Optimizer.create(model, ds, nn.ClassNLLCriterion())
-        opt.set_optim_method(optim.SGD(learning_rate=0.1))
-        opt.set_end_when(optim.max_iteration(3))
-        opt.optimize()
-    finally:
-        lg.removeHandler(tap)
-        lg.setLevel(level)
-        config.clear_property("bigdl.telemetry.mfu")
-        config.clear_property("bigdl.telemetry.peakTflops")
-    assert opt._step_flops and opt._step_flops > 0
-    assert tap.lines and all("MFU is" in ln for ln in tap.lines)
