@@ -24,22 +24,31 @@ Three invariants the serving tests bit-assert:
   :func:`bigdl_tpu.resources.device.preflight_pool`), so running out of
   blocks is an admission-control answer, never a device OOM.
 
-The pool arrays are functional: the compiled prefill/decode steps take
-them as inputs and return the updated pools, which the engine writes
-back to :attr:`k` / :attr:`v`.  The free-list and tables are plain host
-state under a lock (allocation is scheduler-thread work, microseconds).
+The pool arrays are DONATED: the compiled prefill, decode and scrub
+steps consume the pools they are given (XLA aliases each to its output
+and scatters in place; the array that went in reports ``is_deleted()``
+once the step is dispatched) and return the updated pools, which the
+caller writes back to :attr:`k` / :attr:`v` in the statement that makes
+the call.  A dispatch that consumed the pools and never wrote them back
+(the step raised, or the watchdog's abort landed between the two) leaves
+dead handles: :meth:`PagedKVCache.ensure_live` replaces them by zeros,
+which is the right content exactly when no sequence holds a block.
+``LM/kv_aliased_bytes`` says whether XLA took the donation.  The
+free-list and tables are plain host state under a lock (allocation is
+scheduler-thread work, microseconds).
 """
 
 from __future__ import annotations
 
 import math
-from typing import Dict, List
+from typing import Dict, Iterable, List, Optional
 
 import jax.numpy as jnp
 import numpy as np
 
 from bigdl_tpu import analysis, telemetry
-from bigdl_tpu.serving.engine import Overloaded
+from bigdl_tpu.serving.engine import Overloaded, ServingInfraError
+from bigdl_tpu.telemetry import incident
 
 #: block id every padded / inactive-slot scatter targets — reserved at
 #: construction, never handed out by the free-list
@@ -85,13 +94,30 @@ class PagedKVCache:
         preflight_pool(self.pool_nbytes, label)
         self.k = jnp.zeros(shape, self.dtype)
         self.v = jnp.zeros(shape, self.dtype)
+        #: bytes of the pools that the compiled steps alias input to
+        #: output, the smallest over every executable seen so far:
+        #: ``pool_nbytes`` when donation engaged everywhere, 0 when XLA
+        #: declined it somewhere, None before the first compile
+        self.aliased_bytes: Optional[int] = None
+        #: dead pools replaced by zeros (see :meth:`ensure_live`)
+        self.rebuilds = 0
+        self._aliased_gauge = telemetry.gauge(
+            "LM/kv_aliased_bytes",
+            help="KV-pool bytes the compiled steps update in place "
+                 "(smallest over the steps)")
+        self._rebuild_counter = telemetry.counter(
+            "LM/kv_pool_rebuilds",
+            help="dead (donated, never written back) KV pools replaced "
+                 "by zeros")
         # the scrub is a compiled step like prefill and decode: an eager
         # ``.at[].set`` dispatches op by op and compiles each op at the
         # FIRST free — on the decode clock, after warmup
         from bigdl_tpu.analysis.program_contracts import lm_kv_scrub_contract
         from bigdl_tpu.utils.compile_cache import tracked_jit
         self.scrub_step = tracked_jit(_scrub_fn, "lm_kv_scrub",
-                                       contract=lm_kv_scrub_contract())
+                                       contract=lm_kv_scrub_contract(),
+                                       donate_argnums=(0, 1))
+        self.scrub_step.on_executable = self.note_executable
         self._free: List[int] = list(range(self.n_blocks - 1, 0, -1))
         self._tables: Dict[int, List[int]] = {}
         self._lock = analysis.make_lock("lm.kv_cache")
@@ -178,10 +204,59 @@ class PagedKVCache:
         self.scrub_step.warmup(
             pool, pool, jax.ShapeDtypeStruct((_SCRUB_CHUNK,), jnp.int32))
 
+    # -- donation -------------------------------------------------------
+
+    def note_executable(self, exe) -> None:
+        """``CachedStep.on_executable`` of every step that takes the
+        pools: keep the smallest aliased size any of them reports and
+        publish it.  An executable that cannot answer counts as 0 — a
+        step that may be copying is what the gauge exists to show."""
+        try:
+            aliased = int(exe.memory_analysis().alias_size_in_bytes)
+        except Exception:  # noqa: BLE001 — a backend without the analysis
+            aliased = 0
+        if self.aliased_bytes is None or aliased < self.aliased_bytes:
+            self.aliased_bytes = aliased
+        self._aliased_gauge.set(self.aliased_bytes)
+
+    def ensure_live(self, releasing: Iterable[int] = ()) -> bool:
+        """Replace pools that a dispatch consumed and nobody wrote back.
+
+        The steps donate, so a call that raised after dispatch — or a
+        watchdog abort that landed before the write-back — leaves
+        :attr:`k` / :attr:`v` deleted, and the next step would fail on
+        them.  Both are then rebuilt as zeros of the same shape, dtype
+        and placement, counted (``LM/kv_pool_rebuilds``) and recorded as
+        an incident.  Zeros are the right content only when no sequence
+        holds a block (freed blocks are zero by the second invariant):
+        a table held by anything but ``releasing`` — the sequences the
+        caller is about to free — has lost its context, which is a
+        :class:`ServingInfraError` and never a silent rebuild.  Returns
+        whether it rebuilt."""
+        if not (self.k.is_deleted() or self.v.is_deleted()):
+            return False
+        with self._lock:
+            held = sorted(set(self._tables) - set(releasing))
+        if held:
+            raise ServingInfraError(
+                f"KV pool was consumed by a failed dispatch while "
+                f"sequence(s) {held} still hold blocks — their context "
+                "is lost; shed them and retry")
+        # a deleted array keeps its shape, dtype and sharding
+        self.k, self.v = (jnp.zeros(a.shape, a.dtype, device=a.sharding)
+                          for a in (self.k, self.v))
+        self.rebuilds += 1
+        self._rebuild_counter.inc()
+        incident.record("lm/kv_pool_rebuild", label=self.label,
+                        nbytes=self.pool_nbytes)
+        return True
+
     def _scrub(self, blocks: List[int]) -> None:
-        """Zero the named blocks across all layers.  Ids are padded to
-        ``_SCRUB_CHUNK`` with the dump block (re-zeroing junk is free),
-        so the scrub step keeps its one fixed shape for every free."""
+        """Zero the named blocks across all layers, in place (the step
+        donates both pools).  Ids are padded to ``_SCRUB_CHUNK`` with
+        the dump block (re-zeroing junk is free), so the scrub step
+        keeps its one fixed shape for every free."""
+        self.ensure_live()
         for at in range(0, len(blocks), _SCRUB_CHUNK):
             chunk = blocks[at:at + _SCRUB_CHUNK]
             ids = np.full((_SCRUB_CHUNK,), DUMP_BLOCK, np.int32)

@@ -771,8 +771,13 @@ class LMServingEngine:
         prefill = _build_prefill_fn(self.graph, self.block_size)
         full = _build_full_fn(self.graph)
 
-        def wire(fn, label, contract, specs_list):
-            cached = tracked_jit(fn, label, contract=contract)
+        def wire(fn, label, contract, specs_list, donate=(1, 2)):
+            # a step that takes the two pools consumes them: XLA aliases
+            # each to its output and the scatters run in place
+            cached = tracked_jit(fn, label, contract=contract,
+                                 donate_argnums=donate)
+            if donate:
+                cached.on_executable = self.cache.note_executable
             sentinel = RetraceSentinel.from_config(label)
             if sentinel is not None:
                 cached.register_sentinel(sentinel)
@@ -800,7 +805,7 @@ class LMServingEngine:
             for b in self._buckets}
         self._full, self._full_cached, self._full_sentinel = wire(
             full, "lm_full", lm_full_contract(),
-            list(self._full_specs.values()))
+            list(self._full_specs.values()), donate=())
         if self._dp_q is not None:
             self._decode_q_specs = (_tree_spec(self._dp_q),) + dec_tail
             (self._decode_q, self._decode_q_cached,
@@ -851,6 +856,13 @@ class LMServingEngine:
             self._prefill_cached.warmup(*specs)
         if self._decode_q_cached is not None:
             self._decode_q_cached.warmup(*self._decode_q_specs)
+        if (self.cache.aliased_bytes or 0) < self.cache.pool_nbytes:
+            logger.warning(
+                "LM engine: the compiled steps alias %s of the KV pools' "
+                "%d bytes — XLA declined the donation somewhere, and a "
+                "step that does not update the pools in place copies "
+                "them on every call", self.cache.aliased_bytes,
+                self.cache.pool_nbytes)
 
     def warmup_sequential(self) -> None:
         """AOT-compile the ``lm_full`` ladder of the offline baseline
@@ -866,7 +878,14 @@ class LMServingEngine:
         auditor's precision-drift pass over the quantized program, (2)
         fp-vs-int8 next-token log-probs allclose on IDENTICAL KV-pool
         inputs.  Either failing raises :class:`QuantizationGateError` —
-        the engine never silently serves drifted logits."""
+        the engine never silently serves drifted logits.
+
+        Both steps donate the pools, so the int8 step runs on what the
+        fp step wrote back, and the inputs are still identical where a
+        step reads them: the prompt's blocks hold the one prefill's
+        k/v, the decode position is overwritten by each step before it
+        is gathered, and the dump block (the inactive slots' junk) is
+        masked out of the one row compared."""
         from bigdl_tpu.analysis import hlo_audit
         from bigdl_tpu.analysis.hostsync import host_pull
         from bigdl_tpu.analysis.program_contracts import lm_decode_contract
@@ -890,10 +909,13 @@ class LMServingEngine:
             active = np.zeros((B,), bool)
             tokens[0, 0], positions[0] = tok, P
             tables[0], active[0] = table_row, True
-            args = (self.cache.k, self.cache.v, tokens, positions, tables,
-                    active)
-            lp_fp = self._decode(self._dp, *args)[0]
-            lp_q = self._decode_q(self._dp_q, *args)[0]
+            tail = (tokens, positions, tables, active)
+            with self._lock:
+                lp_fp, self.cache.k, self.cache.v = self._decode(
+                    self._dp, self.cache.k, self.cache.v, *tail)
+            with self._lock:
+                lp_q, self.cache.k, self.cache.v = self._decode_q(
+                    self._dp_q, self.cache.k, self.cache.v, *tail)
             a = np.asarray(host_pull(lp_fp, what="lm gate fp logits"))[0]
             b = np.asarray(host_pull(lp_q, what="lm gate int8 logits"))[0]
         finally:
@@ -1148,6 +1170,11 @@ class LMServingEngine:
         out["active_slots"] = sum(s is not None for s in self._slots)
         out["free_blocks"] = self.cache.free_blocks
         out["used_blocks"] = self.cache.used_blocks
+        # donation: pool bytes the steps update in place (pool_nbytes
+        # when engaged, 0 when XLA declined, None before a compile), and
+        # dead pools replaced by zeros
+        out["kv_aliased_bytes"] = self.cache.aliased_bytes
+        out["kv_pool_rebuilds"] = self.cache.rebuilds
         return out
 
     # -- the scheduler thread --------------------------------------------
@@ -1304,6 +1331,12 @@ class LMServingEngine:
         interleave tracebacks across client threads."""
         failed = 0
         first_trace: Optional[str] = None
+        # the failure may be a dispatch that consumed the pools and never
+        # wrote them back; everything holding a block is freed below
+        doomed = [s.stream.seq_id for s in self._slots if s is not None]
+        if self._admitting is not None:
+            doomed.append(self._admitting.seq_id)
+        self.cache.ensure_live(releasing=doomed)
         for i, slot in enumerate(self._slots):
             if slot is None:
                 continue
@@ -1466,11 +1499,12 @@ class LMServingEngine:
         blocks = self.cache.table(seq_id)
         table_row = np.full((self._max_blocks,), DUMP_BLOCK, np.int32)
         table_row[:len(blocks)] = blocks
-        lp, new_k, new_v = self._prefill(self._dp, self.cache.k,
-                                         self.cache.v, padded,
-                                         np.int32(P), table_row)
+        # the step consumes the pools: written back in the statement
+        # that dispatches, so no line can see a deleted array
         with self._lock:
-            self.cache.k, self.cache.v = new_k, new_v
+            lp, self.cache.k, self.cache.v = self._prefill(
+                self._dp, self.cache.k, self.cache.v, padded, np.int32(P),
+                table_row)
         with telemetry.span("lm/prefill_wait"):
             lp = np.asarray(host_pull(lp, what="lm prefill logits"))
         self._h2d.inc(padded.nbytes + 4 + table_row.nbytes)
@@ -1525,11 +1559,10 @@ class LMServingEngine:
                 active[i] = True
         dp, fn = ((self._dp_q, self._decode_q)
                   if self._dp_q is not None else (self._dp, self._decode))
-        with telemetry.span("lm/decode_dispatch"):
-            lp, new_k, new_v = fn(dp, self.cache.k, self.cache.v, tokens,
-                                  positions, tables, active)
-        with self._lock:
-            self.cache.k, self.cache.v = new_k, new_v
+        with telemetry.span("lm/decode_dispatch"), self._lock:
+            lp, self.cache.k, self.cache.v = fn(
+                dp, self.cache.k, self.cache.v, tokens, positions, tables,
+                active)
         # the device time still to run plus the transfer of the whole
         # (max_batch, vocab) float32 log-probs
         with telemetry.span("lm/decode_wait", bytes=lp.nbytes):
@@ -1647,10 +1680,10 @@ class LMServingEngine:
                 active = np.zeros((B,), bool)
                 tokens[0, 0], positions[0] = out_tokens[-1], position
                 tables[0], active[0] = table_row, True
-                lp, new_k, new_v = fn(dp, self.cache.k, self.cache.v,
-                                      tokens, positions, tables, active)
                 with self._lock:
-                    self.cache.k, self.cache.v = new_k, new_v
+                    lp, self.cache.k, self.cache.v = fn(
+                        dp, self.cache.k, self.cache.v, tokens, positions,
+                        tables, active)
                 self._h2d.inc(tokens.nbytes + positions.nbytes
                               + tables.nbytes + active.nbytes)
                 self._d2h.inc(lp.nbytes)

@@ -672,6 +672,10 @@ class CachedStep:
         self._cache = cache if cache is not None else CompileCache.from_config()
         self.bucket_argnums = tuple(bucket_argnums)
         self.sentinel = None          # retrace sentinel fed by precompiles
+        #: called with every executable this step compiles or loads, as
+        #: it is registered (the LM server reads how many bytes of the
+        #: KV pools each one aliases)
+        self.on_executable = None
         self._mem: Dict[Any, Any] = {}   # signature key -> loaded executable
         #: signature keys whose executable came from the persistent
         #: cache and has not dispatched yet (see :meth:`_first_dispatch`)
@@ -886,6 +890,8 @@ class CachedStep:
                 self._store(cache_key, exe, sig_hash, fingerprint, _se,
                             audit=audit_summary)
         self._mem[key] = exe
+        if self.on_executable is not None:
+            self.on_executable(exe)
         if loaded:
             self._unproven.add(key)
         if self.bucket_argnums:

@@ -547,6 +547,188 @@ class TestInt8Tier:
 
 
 # ---------------------------------------------------------------------------
+# donated KV pools: every step that takes the pools consumes them
+# ---------------------------------------------------------------------------
+
+def _pools_zero_outside_dump(cache):
+    return bool((np.asarray(cache.k)[:, 1:] == 0).all() and
+                (np.asarray(cache.v)[:, 1:] == 0).all())
+
+
+class TestDonatedPools:
+    @pytest.mark.parametrize("owner,step", [("engine", "_decode"),
+                                            ("engine", "_prefill"),
+                                            ("cache", "scrub_step")])
+    def test_step_consumes_the_pools_it_is_given(self, owner, step):
+        """One ``generate()`` runs a prefill, decode steps and the frees'
+        scrubs: every pool array handed to the named step is dead once
+        the call returns, and what the cache holds afterwards is live."""
+        eng = _engine()
+        obj = eng if owner == "engine" else eng.cache
+        real, seen = getattr(obj, step), []
+        at = 0 if owner == "cache" else 1       # (dp, k, v, ...) vs (k, v, ids)
+
+        def spy(*args):
+            seen.extend(args[at:at + 2])
+            return real(*args)
+
+        setattr(obj, step, spy)
+        first = (eng.cache.k, eng.cache.v)
+        toks = eng.generate(_prompt(6, seed=1), max_new_tokens=3)
+        assert len(toks) == 3 and len(seen) >= 2
+        assert all(a.is_deleted() for a in seen), step
+        assert all(a.is_deleted() for a in first)
+        assert not (eng.cache.k.is_deleted() or eng.cache.v.is_deleted())
+        # the in-place scrub is still the bit-exact one
+        assert _pools_zero_outside_dump(eng.cache)
+        eng.close()
+
+    @pytest.mark.parametrize("quantize", ["off", "int8"])
+    def test_warmup_reports_the_pools_aliased(self, quantize, caplog):
+        """(b), (f): XLA took the donation in every step — the gauge
+        reads the pools' own size, warmup has nothing to warn about, and
+        the int8 gate (two steps on one pool) leaves live pools."""
+        import logging
+
+        from bigdl_tpu import telemetry
+        with caplog.at_level(logging.WARNING, logger="bigdl_tpu"):
+            eng = _engine(quantize=quantize)
+        stats = eng.stats()
+        assert stats["kv_aliased_bytes"] == eng.cache.pool_nbytes
+        assert stats["kv_pool_rebuilds"] == 0
+        assert telemetry.REGISTRY.gauge("LM/kv_aliased_bytes").value == \
+            eng.cache.pool_nbytes
+        assert not [r for r in caplog.records if "alias" in r.getMessage()]
+        assert not (eng.cache.k.is_deleted() or eng.cache.v.is_deleted())
+        assert eng.cache.used_blocks == 0
+        assert _pools_zero_outside_dump(eng.cache)
+        if quantize == "int8":
+            assert eng.quantization_report["allclose"]
+        eng.close()
+
+    def test_warmup_warns_when_a_step_copies(self, caplog):
+        import logging
+        eng = _engine(warm=False)
+        eng.cache.note_executable(object())      # cannot answer: counts as 0
+        with caplog.at_level(logging.WARNING, logger="bigdl_tpu"):
+            eng.warmup()
+        assert eng.stats()["kv_aliased_bytes"] == 0
+        assert [r for r in caplog.records if "declined the donation"
+                in r.getMessage()]
+        eng.close()
+
+    @pytest.mark.parametrize("how", ["deleted_by_hand",
+                                     "raise_after_dispatch"])
+    def test_consumed_pool_is_rebuilt_and_the_engine_serves_on(self, how):
+        """(d): a dispatch that consumed the pools and never wrote them
+        back must not take the engine down — the shed sweep finds the
+        dead pool, rebuilds zeros, and the next request completes."""
+        eng = _engine()
+        if how == "deleted_by_hand":
+            s = eng.submit(_prompt(4), max_new_tokens=4)
+            eng._admitting = eng._q.get_nowait()
+            eng.cache.allocate(s.seq_id, 8)
+            eng.cache.k.delete()
+            eng._shed_active(ServingInfraError("injected"), "infra")
+            eng.start()
+        else:
+            real, calls = eng._decode, []
+
+            def consume_then_raise(*args):
+                out = real(*args)
+                if not calls:
+                    calls.append(1)
+                    raise RuntimeError("injected after dispatch")
+                return out
+
+            eng._decode = consume_then_raise
+            eng.start()
+            s = eng.submit(_prompt(4), max_new_tokens=4)
+        with pytest.raises(ServingInfraError):
+            s.result(timeout=30)
+        assert s.outcome == "shed"
+        fresh = eng.submit(_prompt(5, seed=3), max_new_tokens=5)
+        assert len(fresh.result(timeout=30)) == 5
+        eng.stop()
+        stats = eng.stats()
+        assert stats["kv_pool_rebuilds"] == 1
+        assert not (eng.cache.k.is_deleted() or eng.cache.v.is_deleted())
+        assert eng.cache._tables == {} and eng.cache.used_blocks == 0
+        assert _pools_zero_outside_dump(eng.cache)
+        _assert_identity(stats)
+        ref = _engine()
+        assert fresh.result() == ref.generate(_prompt(5, seed=3),
+                                              max_new_tokens=5)
+        ref.close()
+
+    def test_failed_offline_decode_leaves_a_live_pool(self):
+        eng = _engine()
+        real = eng._decode
+
+        def consume_then_raise(*args):
+            real(*args)
+            raise RuntimeError("injected after dispatch")
+
+        eng._decode = consume_then_raise
+        with pytest.raises(RuntimeError, match="injected"):
+            eng.generate(_prompt(4), max_new_tokens=4)
+        eng._decode = real
+        assert eng.stats()["kv_pool_rebuilds"] == 1
+        assert eng.cache.used_blocks == 0
+        assert eng.generate(_prompt(4), max_new_tokens=4) == \
+            eng.generate_sequential(_prompt(4), max_new_tokens=4)
+        eng.close()
+
+    def test_dead_pool_under_a_held_table_is_an_error_not_a_rebuild(self):
+        cache = PagedKVCache(2, 2, 8, n_blocks=5, block_size=4)
+        cache.allocate(1, 4)
+        cache.allocate(2, 4)
+        assert cache.ensure_live() is False          # live: nothing to do
+        cache.v.delete()
+        with pytest.raises(ServingInfraError, match=r"\[1\] still hold"):
+            cache.free_seq(2)
+        assert cache.rebuilds == 0
+        assert cache.ensure_live(releasing=[1]) is True
+        assert cache.rebuilds == 1
+        assert cache.k.sharding == cache.v.sharding
+        assert (np.asarray(cache.k) == 0).all()
+        assert (np.asarray(cache.v) == 0).all()
+
+    def test_cache_entry_of_a_copying_scrub_is_not_served(self, tmp_path):
+        """(g): aliasing is in the lowered text, so an entry compiled
+        without donation has another key; one compiled with it is loaded
+        and still reports what it aliases."""
+        from bigdl_tpu.analysis.program_contracts import lm_kv_scrub_contract
+        from bigdl_tpu.serving import kv_cache
+        from bigdl_tpu.utils.compile_cache import tracked_jit
+        config.set_property("bigdl.compile.cacheDir", str(tmp_path / "cc"))
+        try:
+            cache = PagedKVCache(2, 2, 8, n_blocks=5, block_size=4)
+            pool = jax.ShapeDtypeStruct(cache.k.shape, cache.k.dtype)
+            ids = jax.ShapeDtypeStruct((kv_cache._SCRUB_CHUNK,), np.int32)
+            old = tracked_jit(kv_cache._scrub_fn, "lm_kv_scrub",
+                              contract=lm_kv_scrub_contract())
+            old.warmup(pool, pool, ids)
+            assert old.compiles == 1                 # stored, no donation
+            cache.warmup()
+            assert cache.scrub_step.cache_hits == 0
+            assert cache.scrub_step.compiles == 1
+            assert cache.aliased_bytes == cache.pool_nbytes
+            again = PagedKVCache(2, 2, 8, n_blocks=5, block_size=4)
+            again.warmup()
+            assert again.scrub_step.cache_hits == 1
+            assert again.scrub_step.compiles == 0
+            assert again.aliased_bytes == again.pool_nbytes
+            before = (again.k, again.v)
+            again.allocate(1, 8)
+            again.free_seq(1)                        # the loaded one runs
+            assert all(a.is_deleted() for a in before)
+            assert (np.asarray(again.k) == 0).all()
+        finally:
+            config.clear_property("bigdl.compile.cacheDir")
+
+
+# ---------------------------------------------------------------------------
 # lint rule: unbounded-decode-loop
 # ---------------------------------------------------------------------------
 
