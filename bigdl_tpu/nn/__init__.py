@@ -23,7 +23,9 @@ from bigdl_tpu.nn.pooling import (SpatialMaxPooling, SpatialAveragePooling,
 from bigdl_tpu.ops.nms import Nms, nms_mask
 from bigdl_tpu.nn.attention import (MultiHeadAttention,
                                     scaled_dot_product_attention)
-from bigdl_tpu.nn.moe import MixtureOfExperts
+from bigdl_tpu.nn.latent_attention import (LatentAttention, RMSNorm,
+                                           SwiGLU)
+from bigdl_tpu.nn.moe import MixtureOfExperts, SigmoidRoutedExperts
 from bigdl_tpu.nn.tf_ops import (Const, Fill, Shape, SplitAndSelect,
                                  StrideSlice)
 from bigdl_tpu.nn.activation import (ReLU, ReLU6, LeakyReLU, ELU, PReLU,

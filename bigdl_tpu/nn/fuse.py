@@ -93,12 +93,14 @@ def _fold_in(container: Module) -> None:
             else:
                 conv.with_bias = True
                 cp["bias"] = shift
-                container._grads[i]["bias"] = jnp.zeros_like(shift)
+                if container._grads_tree is not None:
+                    container._grads_tree[i]["bias"] = jnp.zeros_like(shift)
             ident = Identity()
             ident._ensure_init()
             container.children[i + 1] = ident
             container._params[i + 1] = ident._params
             container._state[i + 1] = ident._state
-            container._grads[i + 1] = ident._grads
+            if container._grads_tree is not None:
+                container._grads_tree[i + 1] = ident._grads
     for c in container.children:
         _fold_in(c)

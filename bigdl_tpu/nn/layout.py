@@ -90,7 +90,8 @@ def _insert_child(seq: Container, i: int, module: Module) -> None:
         module._ensure_init()          # boundary modules: {} params/state
         seq._params.insert(i, module._params)
         seq._state.insert(i, module._state)
-        seq._grads.insert(i, module._grads)
+        if seq._grads_tree is not None:
+            seq._grads_tree.insert(i, module._grads)
     seq._jit_apply = None
 
 
@@ -107,7 +108,8 @@ def _wrapped(child: Module, before: Module = None,
             m._ensure_init()
         w._params = [m._params for m in mods]
         w._state = [m._state for m in mods]
-        w._grads = [m._grads for m in mods]
+        if child._grads_tree is not None:
+            w._grads = [m._grads for m in mods]
     return w
 
 
@@ -117,7 +119,8 @@ def _replace_child(container: Container, i: int, wrapper: Module) -> None:
         wrapper._ensure_init()
         container._params[i] = wrapper._params
         container._state[i] = wrapper._state
-        container._grads[i] = wrapper._grads
+        if container._grads_tree is not None:
+            container._grads_tree[i] = wrapper._grads
     container._jit_apply = None
 
 

@@ -180,7 +180,6 @@ class Module:
         self.b_regularizer = None
         self._params: Optional[Params] = None
         self._state: Optional[State] = None
-        self._grads: Optional[Params] = None
         self._last_rng = None
         self._fwd_state_in = None
         self._rng_seq = itertools.count(1)
@@ -222,15 +221,71 @@ class Module:
 
     # ---- parameter lifecycle -------------------------------------------
 
+    # ---- what a model holds per parameter -------------------------------
+    #: dtype floating parameters are created at; None leaves each
+    #: initialiser's own (float32)
+    param_dtype = None
+    #: the gradient accumulator of the imperative shell (the reference's
+    #: ``gradWeight``), behind :attr:`_grads`.  None until something reads
+    #: or adds to it: ``reset()`` allocates parameters and state only, so
+    #: a model that is only ever run (``Optimizer.optimize()`` and the
+    #: serving engines never read the accumulator) holds its parameters
+    #: once.  A class default, so modules pickled before it existed load
+    _grads_tree = None
+
+    def set_param_dtype(self, dtype) -> "Module":
+        """Create the floating parameters of this module and everything
+        below it at ``dtype`` from the next ``reset()`` on (parameters that
+        exist are left alone).  ``jnp.bfloat16`` for a model that is
+        served in bf16: after ``reset(key)`` it then holds two bytes a
+        parameter and nothing else."""
+        for m in self.modules():
+            m.param_dtype = None if dtype is None else jnp.dtype(dtype)
+        return self
+
+    def init_params(self, rng) -> Params:
+        """``_init_params`` at :attr:`param_dtype`: what ``reset`` installs."""
+        params = self._init_params(rng)
+        if self.param_dtype is None:
+            return params
+        return jax.tree_util.tree_map(
+            lambda x: x.astype(self.param_dtype)
+            if jnp.issubdtype(x.dtype, jnp.floating) else x, params)
+
     def reset(self, rng=None) -> "Module":
-        """(Re-)initialise parameters (reference ``reset()``)."""
+        """(Re-)initialise parameters (reference ``reset()``).  Under a
+        :attr:`param_dtype` narrower than float32 the initialisers run as
+        ONE compiled call on the default device, not leaf by leaf, so no
+        float32 copy of the whole tree ever exists beside the narrow one.
+        The gradient accumulator is dropped, and allocated again on its
+        first use."""
         if rng is None:
             rng = jax.random.PRNGKey(Engine.get_seed() + hash(self.name) % (2 ** 31))
-        self._params = self._init_params(rng)
+        narrow = any(m.param_dtype is not None and m.param_dtype.itemsize < 4
+                     for m in self.modules())
+        # a one-off initialisation, not a step: nothing to track or cache
+        init = (jax.jit(self.init_params)  # lint: allow(untracked-jit)
+                if narrow else self.init_params)
+        self._params = init(rng)
         self._state = self._init_state()
-        self._grads = tree_zeros_like(self._params)
+        self._grads_tree = None
         self._jit_apply = None
         return self
+
+    @property
+    def _grads(self) -> Optional[Params]:
+        """The gradient accumulator, zeros on first use (None before the
+        parameters exist)."""
+        if self._grads_tree is None and self._params is not None:
+            self._grads_tree = self._new_grads()
+        return self._grads_tree
+
+    @_grads.setter
+    def _grads(self, value: Optional[Params]) -> None:
+        self._grads_tree = value
+
+    def _new_grads(self) -> Params:
+        return tree_zeros_like(self._params)
 
     def _ensure_init(self):
         if self._params is None:
@@ -507,15 +562,18 @@ class Module:
         d["_last_rng"] = None
         d["_fwd_state_in"] = None
         d["_rng_seq"] = None
-        for key in ("_params", "_state", "_grads"):
+        for key in ("_params", "_state", "_grads_tree"):
             if d.get(key) is not None:
                 d[key] = jax.tree_util.tree_map(np.asarray, d[key])
         return d
 
     def __setstate__(self, d):
+        if "_grads" in d:            # pickled before the accumulator was lazy
+            d = dict(d, _grads_tree=d["_grads"])
+            del d["_grads"]
         self.__dict__.update(d)
         self._rng_seq = itertools.count(1)
-        for key in ("_params", "_state", "_grads"):
+        for key in ("_params", "_state", "_grads_tree"):
             if getattr(self, key, None) is not None:
                 setattr(self, key,
                         jax.tree_util.tree_map(jnp.asarray, getattr(self, key)))
@@ -592,8 +650,8 @@ class Container(Module):
             module._ensure_init()
             self._params.append(module._params)
             self._state.append(module._state)
-            if self._grads is not None:
-                self._grads.append(module._grads)
+            if self._grads_tree is not None:
+                self._grads_tree.append(module._grads)
         self._jit_apply = None
         self.__dict__.pop("_eval_jit", None)
         return self
@@ -617,7 +675,8 @@ class Container(Module):
         for i, c in enumerate(self.children):
             c._params = self._params[i]
             c._state = self._state[i]
-            c._grads = self._grads[i]
+            c._grads_tree = (None if self._grads_tree is None
+                             else self._grads_tree[i])
             if isinstance(c, Container):
                 c._adopt()
 
@@ -629,9 +688,16 @@ class Container(Module):
                     c._ensure_init()
                 self._params = [c._params for c in self.children]
                 self._state = [c._state for c in self.children]
-                self._grads = [c._grads for c in self.children]
             else:
                 self.reset()
+
+    def _new_grads(self) -> Params:
+        """The children's accumulators (each allocated on this first use,
+        or as far as a child has accumulated on its own), as one list."""
+        if not (isinstance(self._params, list)
+                and len(self._params) == len(self.children)):
+            return tree_zeros_like(self._params)
+        return [c._grads for c in self.children]
 
     def training(self) -> "Module":
         super().training()

@@ -356,3 +356,165 @@ class MixtureOfExperts(Module):
         else:
             y = jnp.einsum("tec,ecd->td", combine, out)
         return y, lax.pmean(aux, ep)
+
+
+# ---------------------------------------------------------------------------
+# sigmoid-routed experts (DeepSeek-V3 ``noaux_tc``), held in part
+# ---------------------------------------------------------------------------
+
+#: rows of one grouped product; a step runs only the chunks that hold an
+#: assignment to a held expert
+_EXPERT_ROWS = 4096
+
+
+def sigmoid_routed_experts(p, x, top_k: int, scale: float, held,
+                           valid=None):
+    """``x`` (N, d) -> ``(y (N, d) float32, counts (2,) int32)``.
+
+    Router in float32: ``s = sigmoid(x W_g)`` over ALL ``n_routed``
+    experts, the ``top_k`` highest of ``s + bias`` chosen, gates ``scale *
+    s_e / sum_chosen s`` (the sum runs over all chosen, held or not).
+    Of the chosen, this layer computes the experts it holds, ``held =
+    (first, count)`` with weights stacked ``(count, ...)``: assignments
+    are sorted by expert and go through grouped products in chunks of
+    ``_EXPERT_ROWS`` rows, of which only those that hold an assignment
+    run (a single chunk, a decode step, always runs).  ``lax.ragged_dot``
+    leaves the rows beyond its groups undefined (on the TPU: old memory),
+    so what it returns there is dropped by a select.  No capacity,
+    nothing dropped, nothing stands in for an expert held elsewhere.  The
+    shared expert sees every token.  ``counts``:
+    assignments made by the ``valid`` tokens, and those that met a held
+    expert."""
+    from bigdl_tpu.nn.latent_attention import swiglu
+    n, d = x.shape
+    first, count = held
+    dtype = p["w1"].dtype
+    if valid is None:
+        valid = jnp.ones((n,), bool)
+    with jax.named_scope("router"):
+        logits = jnp.matmul(x.astype(jnp.float32),
+                            p["gate"].astype(jnp.float32),
+                            precision=jax.lax.Precision.HIGHEST)
+        s = jax.nn.sigmoid(logits)
+        _, chosen = jax.lax.top_k(s + p["bias"].astype(jnp.float32), top_k)
+        s_chosen = jnp.take_along_axis(s, chosen, axis=1)
+        gates = scale * s_chosen / jnp.sum(s_chosen, axis=1, keepdims=True)
+    with jax.named_scope("dispatch"):
+        local = chosen - first
+        is_held = (local >= 0) & (local < count) & valid[:, None]
+        rows = n * top_k
+        chunk = min(rows, _EXPERT_ROWS)
+        n_chunks = -(-rows // chunk)
+        pad = n_chunks * chunk - rows
+        key = jnp.pad(jnp.where(is_held, local, count).reshape(-1),
+                      (0, pad), constant_values=count)
+        order = jnp.argsort(key, stable=True)
+        token = jnp.minimum(order // top_k, n - 1).reshape(n_chunks, chunk)
+        gate = jnp.pad(gates.reshape(-1), (0, pad))[order]
+        gate = jnp.where(key[order] < count, gate, 0.0).reshape(
+            n_chunks, chunk)
+        sizes = jnp.sum(key[:, None] == jnp.arange(count)[None, :], axis=0,
+                        dtype=jnp.int32)
+        ends = jnp.cumsum(sizes)
+        n_held = ends[-1]
+        counts = jnp.stack([jnp.sum(valid, dtype=jnp.int32) * top_k, n_held])
+    xin = x.astype(dtype)
+
+    def run(y, c, tok, g):
+        lo = c * chunk
+        here = (jnp.clip(ends, lo, lo + chunk)
+                - jnp.clip(ends - sizes, lo, lo + chunk))
+        xr = xin[tok]
+        hid = (jax.nn.silu(jax.lax.ragged_dot(
+                   xr, p["w1"], here, preferred_element_type=jnp.float32))
+               * jax.lax.ragged_dot(xr, p["w3"], here,
+                                    preferred_element_type=jnp.float32))
+        out = jax.lax.ragged_dot(hid.astype(dtype), p["w2"], here,
+                                 preferred_element_type=jnp.float32)
+        # the rows of no group (gate 0) are whatever the TPU's memory
+        # held, NaN among it: selected away, never multiplied by 0
+        return y.at[tok].add(jnp.where((g != 0)[:, None],
+                                       out * g[:, None], 0.0))
+
+    def body(y, args):
+        c, tok, g = args
+        return jax.lax.cond(c * chunk < n_held, run, lambda y, *_: y,
+                            y, c, tok, g), None
+
+    with jax.named_scope("experts"):
+        y = jnp.zeros((n, d), jnp.float32)
+        if n_chunks == 1:
+            # a decode step: always run, so that a step's time does not
+            # depend on whether any of its few tokens met a held expert
+            y = run(y, 0, token[0], gate[0])
+        else:
+            y, _ = jax.lax.scan(body, y, (jnp.arange(n_chunks), token, gate))
+    with jax.named_scope("shared"):
+        shared = swiglu(p["shared"], x)
+    with jax.named_scope("combine"):
+        y = y + shared
+    return y, counts
+
+
+def held_range(held, n_routed: int):
+    """``held`` as ``(first, count)``, all ``n_routed`` experts for None;
+    refused if it is no range of them."""
+    first, count = (0, n_routed) if held is None else (int(held[0]),
+                                                       int(held[1]))
+    if not (0 <= first and count >= 1 and first + count <= n_routed):
+        raise ValueError(f"held {(first, count)} is no range of the "
+                         f"{n_routed} routed experts")
+    return first, count
+
+
+class SigmoidRoutedExperts(Module):
+    """``n_routed`` sigmoid-scored experts, ``top_k`` a token chosen under
+    a selection bias, plus one shared expert; every expert a SwiGLU of
+    ``width``.  ``held = (first, count)``: the experts whose weights this
+    layer holds.  The router still has ``n_routed`` outputs and chooses
+    among all of them; an assignment to an expert held elsewhere adds
+    nothing here (it is that holder's to add), so the sum over the shares
+    ``held`` of one layer, shared expert counted once, is the whole layer
+    (see :func:`sigmoid_routed_experts`).  ``out_scale`` multiplies the
+    standard deviation every expert's ``w2`` is drawn at."""
+
+    def __init__(self, d_model: int, width: int, n_routed: int, top_k: int,
+                 scale: float = 1.0, held=None, out_scale: float = 1.0,
+                 name=None):
+        super().__init__(name)
+        self.d_model, self.width = int(d_model), int(width)
+        self.n_routed, self.top_k = int(n_routed), int(top_k)
+        self.scale, self.out_scale = float(scale), float(out_scale)
+        self.held = held_range(held, self.n_routed)
+        if not 1 <= self.top_k <= self.n_routed:
+            raise ValueError(f"top_k {top_k} must be in [1, {n_routed}]")
+
+    def _init_params(self, rng):
+        """Every expert's weights come from the expert's own number, so a
+        layer that holds a share has exactly the uncut layer's rows.  The
+        selection bias is seeded small: training moves it until the
+        experts' loads are even, and under scores in (0, 1) of which the
+        top few of hundreds are chosen, a bias of 0.1 makes one expert
+        four times as popular as the next (a share of 16 of 256 experts
+        then met 5 % of the assignments under one seed and 10 % under
+        another, and a decode step's time followed; PERF.md 6)."""
+        from bigdl_tpu.nn.latent_attention import SwiGLU
+        kg, kb, ks, ke = jax.random.split(rng, 4)
+        d = self.d_model
+        one = SwiGLU(d, self.width, self.out_scale)
+        first, count = self.held
+        experts = jax.vmap(
+            lambda e: one._init_params(jax.random.fold_in(ke, e)))(
+                first + jnp.arange(count))
+        return {"gate": jax.random.normal(kg, (d, self.n_routed))
+                / math.sqrt(d),
+                "bias": 0.01 * jax.random.normal(kb, (self.n_routed,)),
+                "w1": experts["w1"], "w3": experts["w3"],
+                "w2": experts["w2"],
+                "shared": one._init_params(ks)}
+
+    def apply(self, params, input, state, training=False, rng=None):
+        flat = input.reshape(-1, input.shape[-1])
+        y, _ = sigmoid_routed_experts(params, flat, self.top_k, self.scale,
+                                      self.held)
+        return y.reshape(input.shape), state

@@ -1,9 +1,10 @@
 """Paged block-table KV cache for autoregressive decode.
 
-vLLM-style paging brought to the tracked-jit world: one fixed
-device-resident pool of key/value blocks shaped ``(layer, block,
-block_size, head, head_dim)``, a HOST-side free-list, and a per-sequence
-block table mapping token positions to pool blocks.  Heterogeneous
+vLLM-style paging brought to the tracked-jit world: fixed
+device-resident pools of blocks shaped ``(layer, block, block_size,
+*row)`` (keys and values of ``(head, head_dim)`` rows by default; a
+latent-attention model names its own kinds), a HOST-side free-list, and a
+per-sequence block table mapping token positions to pool blocks.  Heterogeneous
 sequence lengths share the same device memory with fragmentation bounded
 by the block granularity — a sequence wastes at most ``block_size - 1``
 slots, never a max-context reservation.
@@ -41,7 +42,7 @@ scheduler-thread work, microseconds).
 from __future__ import annotations
 
 import math
-from typing import Dict, Iterable, List, Optional
+from typing import Dict, Iterable, List, Optional, Tuple
 
 import jax.numpy as jnp
 import numpy as np
@@ -60,16 +61,35 @@ DUMP_BLOCK = 0
 _SCRUB_CHUNK = 8
 
 
-def _scrub_fn(k, v, ids):
-    zeros = jnp.zeros((k.shape[0], ids.shape[0]) + k.shape[2:], k.dtype)
-    return k.at[:, ids].set(zeros), v.at[:, ids].set(zeros)
+def _scrub_fn(*pools_and_ids):
+    """``(pool..., ids) -> (pool...)``: the blocks ``ids`` of every pool,
+    all layers, set to zero."""
+    *pools, ids = pools_and_ids
+    return tuple(
+        p.at[:, ids].set(jnp.zeros((p.shape[0], ids.shape[0]) + p.shape[2:],
+                                   p.dtype))
+        for p in pools)
 
 
 class PagedKVCache:
-    """Fixed device pool of (layer, block, block_size, head, head_dim)
-    K/V blocks + host free-list + per-sequence block tables."""
+    """Fixed device pools of ``(layer, block, block_size, *row)`` blocks +
+    ONE host free-list + per-sequence block tables.
 
-    def __init__(self, n_layers: int, n_head: int, head_dim: int,
+    ``kinds`` names the pools, ``{name: (layers, row shape)}``:
+    :meth:`kv` gives the usual two, ``k`` and ``v``, of ``(n_head,
+    head_dim)`` rows for each layer; a latent-attention model has a
+    ``latent`` row for every layer and an ``index`` key for the layers
+    that have an indexer.  :attr:`pools` holds them in that order, and is
+    what the steps take, donate and return."""
+
+    @classmethod
+    def kv(cls, n_layers: int, n_head: int, head_dim: int, n_blocks: int,
+           block_size: int, **kw) -> "PagedKVCache":
+        row = (int(n_head), int(head_dim))
+        return cls({"k": (n_layers, row), "v": (n_layers, row)}, n_blocks,
+                   block_size, **kw)
+
+    def __init__(self, kinds: Dict[str, Tuple[int, Tuple[int, ...]]],
                  n_blocks: int, block_size: int, dtype=jnp.float32,
                  label: str = "lm_kv_cache"):
         if n_blocks < 2:
@@ -78,22 +98,28 @@ class PagedKVCache:
                 f"the reserved dump block), got n_blocks={n_blocks}")
         if block_size < 1:
             raise ValueError(f"block_size must be >= 1, got {block_size}")
-        self.n_layers = int(n_layers)
-        self.n_head = int(n_head)
-        self.head_dim = int(head_dim)
         self.n_blocks = int(n_blocks)
         self.block_size = int(block_size)
         self.dtype = jnp.dtype(dtype)
         self.label = label
-        shape = (self.n_layers, self.n_blocks, self.block_size,
-                 self.n_head, self.head_dim)
-        self.pool_nbytes = 2 * int(np.prod(shape)) * self.dtype.itemsize
+        self.kinds = tuple(kinds)
+        shapes = [(int(layers), self.n_blocks, self.block_size)
+                  + tuple(int(n) for n in row)
+                  for layers, row in kinds.values()]
+        #: bytes of each pool, by kind
+        self.kind_nbytes = {
+            kind: int(np.prod(shape)) * self.dtype.itemsize
+            for kind, shape in zip(self.kinds, shapes)}
+        self.pool_nbytes = sum(self.kind_nbytes.values())
+        #: bytes the pools hold for one token position, all layers
+        self.bytes_per_token = self.pool_nbytes // (
+            self.n_blocks * self.block_size)
         # gate BEFORE the buffers exist: an over-budget pool is a plan
         # error answered while device state is still untouched
         from bigdl_tpu.resources.device import preflight_pool
         preflight_pool(self.pool_nbytes, label)
-        self.k = jnp.zeros(shape, self.dtype)
-        self.v = jnp.zeros(shape, self.dtype)
+        self.pools: Tuple = tuple(jnp.zeros(shape, self.dtype)
+                                  for shape in shapes)
         #: bytes of the pools that the compiled steps alias input to
         #: output, the smallest over every executable seen so far:
         #: ``pool_nbytes`` when donation engaged everywhere, 0 when XLA
@@ -116,7 +142,8 @@ class PagedKVCache:
         from bigdl_tpu.utils.compile_cache import tracked_jit
         self.scrub_step = tracked_jit(_scrub_fn, "lm_kv_scrub",
                                        contract=lm_kv_scrub_contract(),
-                                       donate_argnums=(0, 1))
+                                       donate_argnums=tuple(
+                                           range(len(self.pools))))
         self.scrub_step.on_executable = self.note_executable
         self._free: List[int] = list(range(self.n_blocks - 1, 0, -1))
         self._tables: Dict[int, List[int]] = {}
@@ -125,6 +152,19 @@ class PagedKVCache:
             "LM/block_occupancy",
             help="allocated KV-cache blocks / allocatable blocks")
         self._occupancy.set(0.0)
+
+    # -- the pools by name ------------------------------------------------
+
+    def pool(self, kind: str):
+        return self.pools[self.kinds.index(kind)]
+
+    @property
+    def k(self):
+        return self.pool("k")
+
+    @property
+    def v(self):
+        return self.pool("v")
 
     # -- capacity ---------------------------------------------------------
 
@@ -200,9 +240,9 @@ class PagedKVCache:
         """AOT-compile the scrub step, so the first free after warmup
         pays no compile."""
         import jax
-        pool = jax.ShapeDtypeStruct(self.k.shape, self.k.dtype)
         self.scrub_step.warmup(
-            pool, pool, jax.ShapeDtypeStruct((_SCRUB_CHUNK,), jnp.int32))
+            *(jax.ShapeDtypeStruct(p.shape, p.dtype) for p in self.pools),
+            jax.ShapeDtypeStruct((_SCRUB_CHUNK,), jnp.int32))
 
     # -- donation -------------------------------------------------------
 
@@ -224,8 +264,8 @@ class PagedKVCache:
 
         The steps donate, so a call that raised after dispatch — or a
         watchdog abort that landed before the write-back — leaves
-        :attr:`k` / :attr:`v` deleted, and the next step would fail on
-        them.  Both are then rebuilt as zeros of the same shape, dtype
+        :attr:`pools` deleted, and the next step would fail on
+        them.  All are then rebuilt as zeros of the same shape, dtype
         and placement, counted (``LM/kv_pool_rebuilds``) and recorded as
         an incident.  Zeros are the right content only when no sequence
         holds a block (freed blocks are zero by the second invariant):
@@ -233,7 +273,7 @@ class PagedKVCache:
         caller is about to free — has lost its context, which is a
         :class:`ServingInfraError` and never a silent rebuild.  Returns
         whether it rebuilt."""
-        if not (self.k.is_deleted() or self.v.is_deleted()):
+        if not any(p.is_deleted() for p in self.pools):
             return False
         with self._lock:
             held = sorted(set(self._tables) - set(releasing))
@@ -243,8 +283,8 @@ class PagedKVCache:
                 f"sequence(s) {held} still hold blocks — their context "
                 "is lost; shed them and retry")
         # a deleted array keeps its shape, dtype and sharding
-        self.k, self.v = (jnp.zeros(a.shape, a.dtype, device=a.sharding)
-                          for a in (self.k, self.v))
+        self.pools = tuple(jnp.zeros(a.shape, a.dtype, device=a.sharding)
+                           for a in self.pools)
         self.rebuilds += 1
         self._rebuild_counter.inc()
         incident.record("lm/kv_pool_rebuild", label=self.label,
@@ -253,7 +293,7 @@ class PagedKVCache:
 
     def _scrub(self, blocks: List[int]) -> None:
         """Zero the named blocks across all layers, in place (the step
-        donates both pools).  Ids are padded to ``_SCRUB_CHUNK`` with
+        donates every pool).  Ids are padded to ``_SCRUB_CHUNK`` with
         the dump block (re-zeroing junk is free), so the scrub step
         keeps its one fixed shape for every free."""
         self.ensure_live()
@@ -261,7 +301,7 @@ class PagedKVCache:
             chunk = blocks[at:at + _SCRUB_CHUNK]
             ids = np.full((_SCRUB_CHUNK,), DUMP_BLOCK, np.int32)
             ids[:len(chunk)] = chunk
-            self.k, self.v = self.scrub_step(self.k, self.v, ids)
+            self.pools = tuple(self.scrub_step(*self.pools, ids))
 
     def _publish_occupancy(self) -> None:
         denom = max(1, self.allocatable_blocks)

@@ -75,16 +75,19 @@ logger = logging.getLogger("bigdl_tpu")
 
 
 class UnsupportedModelError(ValueError):
-    """The served model is not the decoder-only transformer shape this
-    engine knows how to dissect (``models.transformer.transformer_lm``).
-    Structured — names the exact structural mismatch — because the
-    silent alternative is a decode path that reads the wrong weights."""
+    """The served model is neither of the decoder-only families this
+    engine knows how to serve (``models.transformer.transformer_lm``,
+    dissected module by module; ``models.transformer.glm_moe_dsa``, served
+    through the family's own forward function), or asks of its family
+    what that family has not.  Structured — names the exact mismatch —
+    because the silent alternative is a decode path that reads the wrong
+    weights."""
 
     def __init__(self, what: str):
         super().__init__(
             f"LMServingEngine serves transformer_lm-shaped models "
             f"(LookupTable, PositionalEncoding, n x decoder block, "
-            f"LayerNorm, Linear, LogSoftMax); {what}")
+            f"LayerNorm, Linear, LogSoftMax) and GlmMoeDsaLM; {what}")
 
 
 class QuantizationGateError(ValueError):
@@ -103,6 +106,10 @@ class _LMGraph:
     modules (weights are read through each module's adopted ``.params``
     view, never positional index math) plus the dims the decode step
     closes over."""
+
+    family = "transformer_lm"
+    #: the int8 decode tier quantizes this family's Linear layers
+    quantizable = True
 
     def __init__(self, model):
         import bigdl_tpu.nn as nn
@@ -184,6 +191,75 @@ class _LMGraph:
         self.head_dim = int(self.layers[0]["attn"].head_dim)
         self.n_layers = len(self.layers)
         self.max_seq_len = int(pos.max_seq_len)
+
+
+    # -- what the engine asks of a family --------------------------------
+
+    def make_cache(self, n_blocks: int, block_size: int) -> PagedKVCache:
+        """Float32 K and V of every layer behind one block table."""
+        return PagedKVCache.kv(self.n_layers, self.n_head, self.head_dim,
+                               n_blocks, block_size)
+
+    def query_blocks(self, bucket: int) -> int:
+        """Query blocks a prefill of ``bucket`` positions attends in."""
+        return 1
+
+    def params(self) -> Dict[str, Any]:
+        return _extract_params(self)
+
+    def steps(self, block_size: int, max_blocks: int):
+        """``(decode, prefill, full)`` in the shape the engine dispatches
+        (:func:`_pooled`)."""
+        return (_pooled(_build_decode_fn(self, block_size, max_blocks)),
+                _pooled(_build_prefill_fn(self, block_size)),
+                _build_full_fn(self))
+
+
+class _GlmGraph:
+    """A ``glm_moe_dsa`` model: the family is one function of ``(params,
+    tokens, positions, cache)``, so there is nothing to dissect; the same
+    five answers as :class:`_LMGraph` gives."""
+
+    family = "glm_moe_dsa"
+    quantizable = False
+
+    def __init__(self, model):
+        self.model, self.spec = model, model.spec
+        self.vocab = int(model.spec.vocab_size)
+        self.d_model = int(model.spec.attention.d_model)
+        self.n_head = int(model.spec.attention.n_head)
+        self.n_layers = int(model.spec.n_layers)
+        self.max_seq_len = int(model.spec.max_position_embeddings)
+
+    def make_cache(self, n_blocks: int, block_size: int) -> PagedKVCache:
+        """A latent row for every layer and an indexer key for the layers
+        that have an indexer, at the parameters' dtype, behind one block
+        table."""
+        a = self.spec.attention
+        dtype = jax.tree_util.tree_leaves(self.model.params)[0].dtype
+        return PagedKVCache(
+            {"latent": (self.n_layers, (a.latent_row_width,)),
+             "index": (len(self.spec.index_layers), (a.index_head_dim,))},
+            n_blocks, block_size, dtype=dtype)
+
+    def query_blocks(self, bucket: int) -> int:
+        from bigdl_tpu.nn.latent_attention import query_blocks
+        return query_blocks(bucket)
+
+    def params(self):
+        return self.model.params
+
+    def steps(self, block_size: int, max_blocks: int):
+        return _build_glm_steps(self, block_size)
+
+
+def _graph_of(model):
+    """The served family's graph; ``UnsupportedModelError`` for a model
+    of neither."""
+    from bigdl_tpu.models.transformer.glm_moe_dsa import GlmMoeDsaLM
+    if isinstance(model, GlmMoeDsaLM):
+        return _GlmGraph(model)
+    return _LMGraph(model)
 
 
 def _linear_entry(weight, bias) -> Dict[str, Any]:
@@ -437,6 +513,60 @@ def _build_full_fn(graph: _LMGraph):
     return full
 
 
+def _pooled(fn):
+    """A ``transformer_lm`` step ``(dp, pool_k, pool_v, ...) -> (lp, k, v)``
+    in the shape the engine dispatches for every family: ``(dp, *pools,
+    ...) -> (lp, pools, aux)``, the cache's pools in its order (each
+    donated), returned as one tuple so that one statement writes them
+    back, and ``aux`` what the step counts on the device (here nothing)."""
+
+    def step(dp, *args):
+        lp, k, v = fn(dp, *args)
+        return lp, (k, v), None
+
+    return step
+
+
+def _build_glm_steps(graph: "_GlmGraph", block_size: int):
+    """The three steps of the ``glm_moe_dsa`` family in the shape the
+    engine dispatches (:func:`_pooled`), each the family's ONE forward
+    function (``models.transformer.glm_moe_dsa.forward``) handed this
+    step's view of the pools ``(latent, index)``.  ``aux`` is three int32:
+    routed assignments of the real tokens, those that met a held expert,
+    and (decode) the context rows the active slots' attention read."""
+    from bigdl_tpu.models.transformer.glm_moe_dsa import forward
+    from bigdl_tpu.nn.latent_attention import LatentCache
+    spec = graph.spec
+
+    def decode(dp, latent, index, tokens, positions, tables, active):
+        b = tokens.shape[0]
+        blk = jnp.where(active, tables[jnp.arange(b),
+                                       positions // block_size], DUMP_BLOCK)
+        cache = LatentCache(latent, index, blk[:, None],
+                            (positions % block_size)[:, None], tables)
+        logp, cache, counts = forward(spec, dp, tokens, positions[:, None],
+                                      cache, valid=active[:, None])
+        return logp[:, 0], (cache.latent, cache.index), counts
+
+    def prefill(dp, latent, index, tokens, length, table):
+        pos = jnp.arange(tokens.shape[1])
+        real = pos < length
+        cache = LatentCache(
+            latent, index,
+            jnp.where(real, table[pos // block_size], DUMP_BLOCK)[None],
+            (pos % block_size)[None])
+        logp, cache, counts = forward(spec, dp, tokens, pos[None], cache,
+                                      valid=real[None],
+                                      rows=(length - 1)[None])
+        return logp[0, 0], (cache.latent, cache.index), counts
+
+    def full(dp, tokens):
+        pos = jnp.arange(tokens.shape[1])[None]
+        return forward(spec, dp, tokens, pos)[0][0]
+
+    return decode, prefill, full
+
+
 # ---------------------------------------------------------------------------
 # streaming handle
 # ---------------------------------------------------------------------------
@@ -599,9 +729,10 @@ class LMServingEngine:
                  deadline_ms: Optional[float] = None,
                  max_queue_depth: Optional[int] = None,
                  quantize: Optional[str] = None,
+                 prefill_buckets=None,
                  start: bool = False):
         from bigdl_tpu.utils import config
-        self.graph = _LMGraph(model)
+        self.graph = _graph_of(model)
         self.max_batch = int(max_batch if max_batch is not None else
                              config.get_int("bigdl.lm.maxBatch", 8))
         self.max_context = int(
@@ -633,14 +764,19 @@ class LMServingEngine:
             raise ValueError(
                 f"bigdl.lm.quantize must be 'off' or 'int8', got "
                 f"{self.quantize!r}")
+        if self.quantize == "int8" and not self.graph.quantizable:
+            raise UnsupportedModelError(
+                f"the int8 decode tier quantizes transformer_lm's Linear "
+                f"layers; the {self.graph.family} family has no quantized "
+                "tier")
         self.quantize_rtol = config.get_float("bigdl.lm.quantizeRtol", 0.05)
         self.quantize_atol = config.get_float("bigdl.lm.quantizeAtol", 0.05)
         if self.max_context > self.graph.max_seq_len:
             raise ValueError(
                 f"bigdl.lm.maxContext {self.max_context} exceeds the "
-                f"model's PositionalEncoding max_len "
-                f"{self.graph.max_seq_len} — build the model with a "
-                "larger max_len or lower maxContext")
+                f"model's longest position (PositionalEncoding max_len, "
+                f"max_position_embeddings) {self.graph.max_seq_len} — "
+                "build the model with a larger one or lower maxContext")
         if self.max_batch < 1 or self.max_new_tokens < 1:
             raise ValueError("maxBatch and maxNewTokens must be >= 1")
 
@@ -651,14 +787,13 @@ class LMServingEngine:
                        config.get_int("bigdl.lm.cacheBlocks", 0))
         if n_blocks <= 0:
             n_blocks = self.max_batch * self._max_blocks + 1
-        self.cache = PagedKVCache(self.graph.n_layers, self.graph.n_head,  # guarded-by: _lock
-                                  self.graph.head_dim, n_blocks,
-                                  self.block_size)
+        self.cache = self.graph.make_cache(n_blocks, self.block_size)  # guarded-by: _lock
         self._buckets = self._bucket_plan(
+            prefill_buckets if prefill_buckets is not None else
             config.get_property("bigdl.lm.prefillBuckets", None))
 
         # -- compiled steps + retrace sentinels ---------------------------
-        self._dp = _extract_params(self.graph)
+        self._dp = self.graph.params()
         self._dp_q = (_quantize_params(self._dp)
                       if self.quantize == "int8" else None)
         self._build_steps()
@@ -715,6 +850,44 @@ class LMServingEngine:
             help="host arrays handed to the prefill and decode steps")
         self._d2h = telemetry.counter(
             "LM/d2h_bytes", help="log-probs pulled from the two steps")
+        # tallies of this engine (stats()) and their process-wide counters
+        self._tally: Dict[str, int] = {}                # guarded-by: _lock
+        self._tally_counters = {
+            name: telemetry.counter(f"LM/{name}", help=text)
+            for name, text in (
+                ("prompt_tokens", "prompt tokens of admitted requests"),
+                ("attn_context_tokens",
+                 "context positions of the active slots, summed over decode "
+                 "steps (pos + 1 each)"),
+                ("attn_selected_tokens",
+                 "of those, the positions attention read: the decode "
+                 "step's own count under a learned selection, else all"),
+                ("moe_assignments",
+                 "routed expert assignments of real tokens, the "
+                 "scheduler's prefill and decode steps"),
+                ("moe_assignments_held",
+                 "of those, the assignments to an expert this model "
+                 "holds"))}
+        telemetry.gauge(
+            "LM/cache_bytes_per_token",
+            help="bytes the pools hold for one token, all layers").set(
+                self.cache.bytes_per_token)
+        for kind in ("latent", "index"):
+            if kind in self.cache.kinds:
+                telemetry.gauge(f"LM/{kind}_pool_bytes").set(
+                    self.cache.kind_nbytes[kind])
+        # what the engine holds on the device while it serves: the
+        # parameter trees its steps read (each array once) and the pools
+        held = {id(x): x.nbytes for tree in (self._dp, self._dp_q)
+                for x in jax.tree_util.tree_leaves(tree)}
+        self.weight_bytes = int(sum(held.values()))
+        telemetry.gauge(
+            "LM/weight_bytes",
+            help="bytes of the parameter arrays the steps read").set(
+                self.weight_bytes)
+        telemetry.gauge(
+            "LM/pool_bytes", help="bytes of the cache's pools, all "
+            "kinds").set(self.cache.pool_nbytes)
 
         self.quantization_report: Optional[Dict[str, Any]] = None
         if self._dp_q is not None:
@@ -725,13 +898,14 @@ class LMServingEngine:
     # -- compile plan -----------------------------------------------------
 
     def _bucket_plan(self, spec) -> List[int]:
-        """Prefill shape ladder: configured ``bigdl.lm.prefillBuckets``
-        or a power-of-two ladder from blockSize up; maxContext is
-        always IN the plan so the longest admissible prompt has a
+        """Prefill shape ladder: the ``prefill_buckets`` argument or
+        ``bigdl.lm.prefillBuckets`` (``"16,32,64"`` or a sequence of
+        ints), else a power-of-two ladder from blockSize up; maxContext
+        is always IN the plan so the longest admissible prompt has a
         warmed signature."""
         if spec:
-            buckets = sorted({int(b) for b in str(spec).split(",") if
-                              str(b).strip()})
+            parts = spec.split(",") if isinstance(spec, str) else spec
+            buckets = sorted({int(b) for b in parts if str(b).strip()})
             if not buckets or buckets[0] < 1:
                 raise ValueError(
                     f"bigdl.lm.prefillBuckets must be positive ints, got "
@@ -761,18 +935,17 @@ class LMServingEngine:
         from bigdl_tpu.analysis.retrace import RetraceSentinel
         from bigdl_tpu.utils.compile_cache import tracked_jit
         B, MB = self.max_batch, self._max_blocks
-        pool = jax.ShapeDtypeStruct(self.cache.k.shape, self.cache.k.dtype)
-        dec_tail = (pool, pool,
+        pools = _tree_spec(self.cache.pools)
+        dec_tail = pools + (
                     jax.ShapeDtypeStruct((B, 1), jnp.int32),
                     jax.ShapeDtypeStruct((B,), jnp.int32),
                     jax.ShapeDtypeStruct((B, MB), jnp.int32),
                     jax.ShapeDtypeStruct((B,), jnp.bool_))
-        decode = _build_decode_fn(self.graph, self.block_size, MB)
-        prefill = _build_prefill_fn(self.graph, self.block_size)
-        full = _build_full_fn(self.graph)
+        decode, prefill, full = self.graph.steps(self.block_size, MB)
 
-        def wire(fn, label, contract, specs_list, donate=(1, 2)):
-            # a step that takes the two pools consumes them: XLA aliases
+        def wire(fn, label, contract, specs_list,
+                 donate=tuple(range(1, 1 + len(pools)))):
+            # a step that takes the pools consumes them: XLA aliases
             # each to its output and the scatters run in place
             cached = tracked_jit(fn, label, contract=contract,
                                  donate_argnums=donate)
@@ -791,7 +964,7 @@ class LMServingEngine:
             decode, "lm_decode", lm_decode_contract(),
             [self._decode_specs])
         self._prefill_specs = {
-            b: (_tree_spec(self._dp), pool, pool,
+            b: (_tree_spec(self._dp),) + pools + (
                 jax.ShapeDtypeStruct((1, b), jnp.int32),
                 jax.ShapeDtypeStruct((), jnp.int32),
                 jax.ShapeDtypeStruct((MB,), jnp.int32))
@@ -911,11 +1084,11 @@ class LMServingEngine:
             tables[0], active[0] = table_row, True
             tail = (tokens, positions, tables, active)
             with self._lock:
-                lp_fp, self.cache.k, self.cache.v = self._decode(
-                    self._dp, self.cache.k, self.cache.v, *tail)
+                lp_fp, self.cache.pools, _ = self._decode(
+                    self._dp, *self.cache.pools, *tail)
             with self._lock:
-                lp_q, self.cache.k, self.cache.v = self._decode_q(
-                    self._dp_q, self.cache.k, self.cache.v, *tail)
+                lp_q, self.cache.pools, _ = self._decode_q(
+                    self._dp_q, *self.cache.pools, *tail)
             a = np.asarray(host_pull(lp_fp, what="lm gate fp logits"))[0]
             b = np.asarray(host_pull(lp_q, what="lm gate int8 logits"))[0]
         finally:
@@ -1152,12 +1325,32 @@ class LMServingEngine:
                                   exemplar=stream.trace_id)
         return True
 
+    def _count_aux(self, aux) -> Optional[int]:
+        """What a step of the scheduler counted on the device, pulled
+        after its log-probs (which are ready by then): the expert layers'
+        assignments into their two tallies; returns the context rows the
+        step's attention read (None: the family counts nothing, it reads
+        every row)."""
+        if aux is None:
+            return None
+        from bigdl_tpu.analysis.hostsync import host_pull
+        made, held, read = np.asarray(host_pull(aux, what="lm step counts"))
+        self._count("moe_assignments", int(made))
+        self._count("moe_assignments_held", int(held))
+        return int(read)
+
+    def _count(self, name: str, n: int) -> None:
+        with self._lock:
+            self._tally[name] = self._tally.get(name, 0) + n
+        self._tally_counters[name].inc(n)
+
     def stats(self) -> Dict[str, Any]:
         """Outcome counters + the accounting identity residual
         (``unaccounted`` includes streams still in flight — quiesce
         first for the exact identity)."""
         with self._lock:
             out: Dict[str, Any] = dict(self._counts)
+            tally = dict(self._tally)
         out["unaccounted"] = out["submitted"] - sum(out[o]
                                                     for o in OUTCOMES)
         out["decode_steps"] = self.decode_steps
@@ -1175,6 +1368,11 @@ class LMServingEngine:
         # dead pools replaced by zeros
         out["kv_aliased_bytes"] = self.cache.aliased_bytes
         out["kv_pool_rebuilds"] = self.cache.rebuilds
+        out["cache_bytes_per_token"] = self.cache.bytes_per_token
+        out["pool_bytes"] = dict(self.cache.kind_nbytes)
+        out["weight_bytes"] = self.weight_bytes
+        for name in self._tally_counters:
+            out[name] = tally.get(name, 0)
         return out
 
     # -- the scheduler thread --------------------------------------------
@@ -1445,10 +1643,12 @@ class LMServingEngine:
                 with telemetry.span(
                         "lm/prefill", prompt_tokens=int(prompt.size),
                         bucket=self._prefill_bucket(int(prompt.size)),
+                        query_blocks=self.graph.query_blocks(
+                            self._prefill_bucket(int(prompt.size))),
                         active=sum(s is not None for s in self._slots),
                         trace_id=stream.trace_id):
-                    tok, table_row = self._prefill_step_raw(stream.seq_id,
-                                                            prompt)
+                    tok, table_row = self._prefill_step_raw(
+                        stream.seq_id, prompt, served=True)
             except Exception as e:  # noqa: BLE001 — fail one stream
                 self.cache.free_seq(stream.seq_id)
                 self._finish_stream(stream, "shed", error=ServingInfraError(
@@ -1466,6 +1666,7 @@ class LMServingEngine:
             admitted += 1
             self._queue_wait.observe((now - stream.submit_ns) / 1e6)
             self._prefill_ms.observe((t_done - t_pf) / 1e6)
+            self._count("prompt_tokens", int(prompt.size))
             stream._emit(tok)
             request_trace.instant(stream.trace_id, "request/emit",
                                   token=int(tok), first=True)
@@ -1485,8 +1686,8 @@ class LMServingEngine:
         telemetry.gauge("LM/queue_depth").set(self.queue_depth())
         return admitted
 
-    def _prefill_step_raw(self, seq_id: int, prompt: np.ndarray
-                          ) -> Tuple[int, np.ndarray]:
+    def _prefill_step_raw(self, seq_id: int, prompt: np.ndarray,
+                          served: bool = False) -> Tuple[int, np.ndarray]:
         """Run the bucketed prefill for an ALLOCATED sequence: scatter
         the prompt's k/v into its blocks, return the first greedy token
         (1-based) and the dump-padded table row the decode step
@@ -1502,11 +1703,14 @@ class LMServingEngine:
         # the step consumes the pools: written back in the statement
         # that dispatches, so no line can see a deleted array
         with self._lock:
-            lp, self.cache.k, self.cache.v = self._prefill(
-                self._dp, self.cache.k, self.cache.v, padded, np.int32(P),
-                table_row)
+            lp, self.cache.pools, aux = self._prefill(
+                self._dp, *self.cache.pools, padded, np.int32(P), table_row)
         with telemetry.span("lm/prefill_wait"):
             lp = np.asarray(host_pull(lp, what="lm prefill logits"))
+        if served:
+            # the tallies are of admitted requests, as the histograms
+            # are: generate()'s offline steps do not count
+            self._count_aux(aux)
         self._h2d.inc(padded.nbytes + 4 + table_row.nbytes)
         self._d2h.inc(lp.nbytes)
         with self._lock:
@@ -1560,16 +1764,24 @@ class LMServingEngine:
         dp, fn = ((self._dp_q, self._decode_q)
                   if self._dp_q is not None else (self._dp, self._decode))
         with telemetry.span("lm/decode_dispatch"), self._lock:
-            lp, self.cache.k, self.cache.v = fn(
-                dp, self.cache.k, self.cache.v, tokens, positions, tables,
-                active)
+            lp, self.cache.pools, aux = fn(
+                dp, *self.cache.pools, tokens, positions, tables, active)
         # the device time still to run plus the transfer of the whole
         # (max_batch, vocab) float32 log-probs
         with telemetry.span("lm/decode_wait", bytes=lp.nbytes):
             lp = np.asarray(host_pull(lp, what="lm decode logits"))
+        read = self._count_aux(aux)
         self._h2d.inc(tokens.nbytes + positions.nbytes + tables.nbytes
                       + active.nbytes)
         self._d2h.inc(lp.nbytes)
+        # every active slot's context, from the host's positions, and the
+        # rows of it attention read: the device's own count where the
+        # family selects, else all of them
+        context = int(positions[active].astype(np.int64).sum()
+                      + active.sum())
+        self._count("attn_context_tokens", context)
+        self._count("attn_selected_tokens",
+                    context if read is None else read)
         now = telemetry.clock_ns()
         with telemetry.span("lm/emit", tokens=int(active.sum())):
             self._emit_tokens(lp, t0, now, step)
@@ -1681,9 +1893,9 @@ class LMServingEngine:
                 tokens[0, 0], positions[0] = out_tokens[-1], position
                 tables[0], active[0] = table_row, True
                 with self._lock:
-                    lp, self.cache.k, self.cache.v = fn(
-                        dp, self.cache.k, self.cache.v, tokens, positions,
-                        tables, active)
+                    lp, self.cache.pools, aux = fn(
+                        dp, *self.cache.pools, tokens, positions, tables,
+                        active)
                 self._h2d.inc(tokens.nbytes + positions.nbytes
                               + tables.nbytes + active.nbytes)
                 self._d2h.inc(lp.nbytes)

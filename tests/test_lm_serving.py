@@ -117,7 +117,7 @@ def _zero_retraces(eng):
 
 class TestPagedKVCache:
     def test_exhaustion_is_structured_overloaded_never_oom(self):
-        cache = PagedKVCache(2, 2, 8, n_blocks=4, block_size=4)
+        cache = PagedKVCache.kv(2, 2, 8, n_blocks=4, block_size=4)
         cache.allocate(1, 12)                     # 3 blocks = the pool
         with pytest.raises(Overloaded) as ei:
             cache.allocate(2, 8)                  # needs 2, 0 free
@@ -127,17 +127,17 @@ class TestPagedKVCache:
         assert cache.can_allocate(8)              # retriable for real
 
     def test_dump_block_never_allocated(self):
-        cache = PagedKVCache(2, 2, 8, n_blocks=5, block_size=4)
+        cache = PagedKVCache.kv(2, 2, 8, n_blocks=5, block_size=4)
         blocks = cache.allocate(1, 16)            # the whole free-list
         assert DUMP_BLOCK not in blocks
         assert sorted(blocks) == [1, 2, 3, 4]
 
     def test_block_reuse_is_zero_initialized_bitwise(self):
-        cache = PagedKVCache(2, 2, 8, n_blocks=5, block_size=4)
+        cache = PagedKVCache.kv(2, 2, 8, n_blocks=5, block_size=4)
         blocks = cache.allocate(7, 10)
         # simulate a decode having written k/v into the blocks
-        cache.k = cache.k.at[:, np.array(blocks)].set(1.5)
-        cache.v = cache.v.at[:, np.array(blocks)].set(-2.25)
+        cache.pools = (cache.k.at[:, np.array(blocks)].set(1.5),
+                       cache.v.at[:, np.array(blocks)].set(-2.25))
         assert float(np.abs(np.asarray(cache.k[:, blocks])).max()) > 0
         cache.free_seq(7)
         # the scrub is the no-cross-request-leakage proof: bit-exact zero
@@ -147,7 +147,7 @@ class TestPagedKVCache:
         assert sorted(again) == sorted(blocks)    # same ids, clean bits
 
     def test_double_allocate_and_idempotent_free(self):
-        cache = PagedKVCache(1, 1, 4, n_blocks=3, block_size=2)
+        cache = PagedKVCache.kv(1, 1, 4, n_blocks=3, block_size=2)
         cache.allocate(1, 2)
         with pytest.raises(ValueError, match="already holds"):
             cache.allocate(1, 2)
@@ -156,7 +156,7 @@ class TestPagedKVCache:
 
     def test_pool_needs_room_beyond_the_dump_block(self):
         with pytest.raises(ValueError, match="dump block"):
-            PagedKVCache(1, 1, 4, n_blocks=1, block_size=2)
+            PagedKVCache.kv(1, 1, 4, n_blocks=1, block_size=2)
 
 
 # ---------------------------------------------------------------------------
@@ -680,7 +680,7 @@ class TestDonatedPools:
         eng.close()
 
     def test_dead_pool_under_a_held_table_is_an_error_not_a_rebuild(self):
-        cache = PagedKVCache(2, 2, 8, n_blocks=5, block_size=4)
+        cache = PagedKVCache.kv(2, 2, 8, n_blocks=5, block_size=4)
         cache.allocate(1, 4)
         cache.allocate(2, 4)
         assert cache.ensure_live() is False          # live: nothing to do
@@ -703,7 +703,7 @@ class TestDonatedPools:
         from bigdl_tpu.utils.compile_cache import tracked_jit
         config.set_property("bigdl.compile.cacheDir", str(tmp_path / "cc"))
         try:
-            cache = PagedKVCache(2, 2, 8, n_blocks=5, block_size=4)
+            cache = PagedKVCache.kv(2, 2, 8, n_blocks=5, block_size=4)
             pool = jax.ShapeDtypeStruct(cache.k.shape, cache.k.dtype)
             ids = jax.ShapeDtypeStruct((kv_cache._SCRUB_CHUNK,), np.int32)
             old = tracked_jit(kv_cache._scrub_fn, "lm_kv_scrub",
@@ -714,7 +714,7 @@ class TestDonatedPools:
             assert cache.scrub_step.cache_hits == 0
             assert cache.scrub_step.compiles == 1
             assert cache.aliased_bytes == cache.pool_nbytes
-            again = PagedKVCache(2, 2, 8, n_blocks=5, block_size=4)
+            again = PagedKVCache.kv(2, 2, 8, n_blocks=5, block_size=4)
             again.warmup()
             assert again.scrub_step.cache_hits == 1
             assert again.scrub_step.compiles == 0
