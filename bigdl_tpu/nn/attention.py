@@ -55,23 +55,56 @@ def scaled_dot_product_attention(q: jnp.ndarray, k: jnp.ndarray,
     return jnp.einsum("bhqk,bkhd->bqhd", p, v)
 
 
-def paged_attention(q: jnp.ndarray, k_ctx: jnp.ndarray, v_ctx: jnp.ndarray,
-                    valid: jnp.ndarray) -> jnp.ndarray:
-    """Decode-step attention over a gathered paged-cache context.
+def paged_attention(q: jnp.ndarray, fetch, positions: jnp.ndarray,
+                    active: jnp.ndarray, n_chunks, chunk: int
+                    ) -> jnp.ndarray:
+    """Decode-step attention over a paged-cache context, read a chunk at
+    a time and only as far as the caller says.
 
     ``q`` is the current step's query, (B, T, H, Dh) with T=1 on the
-    decode path; ``k_ctx``/``v_ctx`` are the (B, S, H, Dh) context rows
-    gathered from the KV pool via each sequence's block table (S = the
-    table capacity in tokens, mostly padding for short sequences);
-    ``valid`` is the (B, S) mask of real context positions.  Numerics are
-    exactly :func:`scaled_dot_product_attention` with an explicit mask:
-    the finite mask value makes an invalid key's probability underflow to
-    0.0, so a padded context attends identically to the unpadded one —
-    the decode-vs-full-forward parity proof leans on this.  A fully
-    masked row (an inactive decode slot) softmaxes to uniform junk
-    rather than NaN; its output is discarded on the host."""
-    return scaled_dot_product_attention(q, k_ctx, v_ctx, causal=False,
-                                        mask=valid[:, None, None, :])
+    decode path; ``fetch(c)`` returns the (B, chunk, H, Dh) key and value
+    rows of context positions ``c * chunk ... (c + 1) * chunk - 1``,
+    gathered from the KV pool via each sequence's block table;
+    ``positions`` (B,) is each sequence's last real position and
+    ``active`` (B,) its slot's liveness, so the mask is made here and no
+    (B, S) array over the table's whole capacity exists.  ``n_chunks`` is
+    a traced scalar: a ``lax.fori_loop`` makes that many trips with a
+    running softmax (maximum, normaliser, weighted sum), so the rows read
+    follow the longest live context and not the table's capacity, in one
+    compiled program.  The caller passes at least ``ceil((max live
+    position + 1) / chunk)``; rows past a sequence's own position inside
+    those chunks are masked as before: the finite mask value makes an
+    invalid key's probability underflow to exactly 0.0, so the result is
+    :func:`scaled_dot_product_attention` over the unpadded context up to
+    the order of the sums: the decode-vs-full-forward parity proof leans
+    on this.  A fully masked row (an inactive decode slot) softmaxes to
+    uniform junk rather than NaN; its output is discarded on the host."""
+    bsz, t, h, dh = q.shape
+    neg_big = jnp.asarray(jnp.finfo(q.dtype).min, q.dtype)
+
+    def body(c, carry):
+        m, l, acc = carry
+        k, v = fetch(c)
+        with jax.named_scope("attn"):
+            k_pos = c * chunk + jnp.arange(chunk)
+            valid = (k_pos[None, :] <= positions[:, None]) & active[:, None]
+            s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / math.sqrt(dh)
+            s = jnp.where(valid[:, None, None, :], s, neg_big)
+            m_new = jnp.maximum(m, jnp.max(s, axis=-1))
+            alpha = jnp.exp(m - m_new)
+            p = jnp.exp(s - m_new[..., None])
+            l = alpha * l + jnp.sum(p, axis=-1)
+            acc = (alpha[..., None] * acc
+                   + jnp.einsum("bhqk,bkhd->bhqd", p, v))
+        return m_new, l, acc
+
+    with jax.named_scope("attn"):
+        init = (jnp.full((bsz, h, t), neg_big),
+                jnp.zeros((bsz, h, t), q.dtype),
+                jnp.zeros((bsz, h, t, dh), q.dtype))
+    _, l, acc = lax.fori_loop(0, n_chunks, body, init)
+    with jax.named_scope("attn"):
+        return jnp.moveaxis(acc / l[..., None], 1, 2)
 
 
 def chunked_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
@@ -279,32 +312,6 @@ class MultiHeadAttention(Module):
         # -1 heads: under the explicit Megatron split params hold only
         # the LOCAL heads' columns (head_dim never splits)
         return y.reshape(bsz, t, -1, self.head_dim)
-
-    # -- decode-cache apply path (serving/lm.py) --------------------------
-
-    def project_step(self, params, x):
-        """Project one decode/prefill span into per-head q/k/v, each
-        (B, T, H, Dh) — the serving path's entry into this module's
-        weights: the caller scatters k/v into the paged pool between
-        projection and attention (the current token must be IN the cache
-        before the gather so it attends itself)."""
-        q = self._project(params, x, "wq", "bq")
-        k = self._project(params, x, "wk", "bk")
-        v = self._project(params, x, "wv", "bv")
-        return q, k, v
-
-    def attend_cached(self, params, q, k_ctx, v_ctx, valid):
-        """Single-step attention over the gathered paged context plus
-        this module's output projection: (B, T, H, Dh) q against
-        (B, S, H, Dh) context under the (B, S) validity mask ->
-        (B, T, D).  Same numerics as :meth:`apply`'s standard path —
-        masked keys underflow to exact zero probability."""
-        out = paged_attention(q, k_ctx, v_ctx, valid)
-        bsz, t = out.shape[0], out.shape[1]
-        out = out.reshape(bsz, t, -1) @ params["wo"]
-        if self.with_bias:
-            out = out + params["bo"]
-        return out
 
     def apply(self, params, input, state, training=False, rng=None):
         if isinstance(input, (list, tuple)):

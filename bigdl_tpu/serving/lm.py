@@ -207,6 +207,15 @@ class _LMGraph:
     def params(self) -> Dict[str, Any]:
         return _extract_params(self)
 
+    def rows_fetched(self, positions, active, block_size: int,
+                     max_blocks: int) -> int:
+        """Rows of one pool and layer the decode step's attention fetches
+        for these (host) arguments: every slot's table in whole chunks,
+        as many as the step's own loop makes trips."""
+        chunk = _chunk_blocks(block_size, max_blocks) * block_size
+        return len(positions) * chunk * int(
+            _live_chunks(positions, active, chunk, np))
+
     def steps(self, block_size: int, max_blocks: int):
         """``(decode, prefill, full)`` in the shape the engine dispatches
         (:func:`_pooled`)."""
@@ -218,7 +227,7 @@ class _LMGraph:
 class _GlmGraph:
     """A ``glm_moe_dsa`` model: the family is one function of ``(params,
     tokens, positions, cache)``, so there is nothing to dissect; the same
-    five answers as :class:`_LMGraph` gives."""
+    six answers as :class:`_LMGraph` gives."""
 
     family = "glm_moe_dsa"
     quantizable = False
@@ -248,6 +257,12 @@ class _GlmGraph:
 
     def params(self):
         return self.model.params
+
+    def rows_fetched(self, positions, active, block_size: int,
+                     max_blocks: int) -> int:
+        """The selected rows of every slot, live or not."""
+        return len(positions) * min(int(self.spec.attention.index_topk),
+                                    max_blocks * block_size)
 
     def steps(self, block_size: int, max_blocks: int):
         return _build_glm_steps(self, block_size)
@@ -374,14 +389,43 @@ def _head(x, dp, eps_f):
 # ---------------------------------------------------------------------------
 
 
+#: context positions one trip of the decode step's attention loop reads.
+#: On the v5e at 16 slots x 32 heads of 128 a trip's two gathers (32 MB
+#: each) stay in the fast memory the scores read them from; 64 measured
+#: the same step time, 256 a fifth to a third slower (PERF.md 6, PR 27)
+_KV_CHUNK = 128
+
+
+def _chunk_blocks(block_size: int, max_blocks: int) -> int:
+    """Blocks of a table one trip of the decode step's attention reads."""
+    return max(1, min(max_blocks, _KV_CHUNK // block_size))
+
+
+def _live_chunks(positions, active, chunk: int, xp=jnp):
+    """Trips of the decode step's attention loop: whole chunks of
+    ``chunk`` positions up to the longest live context (``pos + 1`` of an
+    active slot), at least one.  The step calls it on its arguments
+    (``xp=jnp``) and the scheduler on the host copies of the same
+    (``xp=np``), which is how ``LM/kv_rows_fetched`` counts what the
+    device read without pulling anything."""
+    longest = xp.max(xp.where(active, positions + 1, 0))
+    return xp.maximum((longest + chunk - 1) // chunk, 1)
+
+
 def _build_decode_fn(graph: _LMGraph, block_size: int, max_blocks: int):
     """One fused decode iteration at the FIXED ``(maxBatch, 1)`` shape:
     embed + positional row, per layer scatter this step's k/v into the
     paged pool (BEFORE the gather, so the current token attends itself),
-    gather each sequence's table context, masked paged attention, FFN;
-    returns next-token log-probs and the updated pools.  Inactive slots
-    compute junk that scatters into the dump block and is discarded on
-    the host — occupancy can never mint a new signature."""
+    then attention over each sequence's table context, FFN; returns
+    next-token log-probs and the updated pools.  The context is read
+    through the block tables a chunk of :data:`_KV_CHUNK` positions at a
+    time, straight out of the 5-D pools (layer and block in one gather,
+    no copy of a layer), and only as far as the longest live context
+    (:func:`_live_chunks` of ``positions`` and ``active``): a loop on the
+    device with a running softmax, so one compiled program reads 128 rows
+    a slot or the tables' whole capacity as the traffic asks.  Inactive
+    slots compute junk that scatters into the dump block and is discarded
+    on the host — occupancy can never mint a new signature."""
     from bigdl_tpu.nn.attention import paged_attention
     pe = graph.pos.pe
     vocab_in = int(graph.embed.n_index)
@@ -389,7 +433,8 @@ def _build_decode_fn(graph: _LMGraph, block_size: int, max_blocks: int):
     eps1 = [l["ln1"].eps for l in graph.layers]
     eps2 = [l["ln2"].eps for l in graph.layers]
     eps_f = graph.lnf.eps
-    S = max_blocks * block_size
+    cb = _chunk_blocks(block_size, max_blocks)
+    chunk = cb * block_size
 
     def decode(dp, pool_k, pool_v, tokens, positions, tables, active):
         B = tokens.shape[0]
@@ -402,8 +447,7 @@ def _build_decode_fn(graph: _LMGraph, block_size: int, max_blocks: int):
                                            positions // block_size],
                             DUMP_BLOCK)
             slot = positions % block_size
-            valid = ((jnp.arange(S)[None, :] <= positions[:, None]) &
-                     active[:, None])
+            n_chunks = _live_chunks(positions, active, chunk)
         for li, lyr in enumerate(dp["layers"]):
             with jax.named_scope(f"layer{li}"):
                 with jax.named_scope("ln1"):
@@ -416,10 +460,20 @@ def _build_decode_fn(graph: _LMGraph, block_size: int, max_blocks: int):
                     pool_k = pool_k.at[li, blk, slot].set(k[:, 0])
                     pool_v = pool_v.at[li, blk, slot].set(v[:, 0])
                 with jax.named_scope("kv_gather"):
-                    k_ctx = pool_k[li][tables].reshape(B, S, H, Dh)
-                    v_ctx = pool_v[li][tables].reshape(B, S, H, Dh)
-                with jax.named_scope("attn"):
-                    att = paged_attention(q, k_ctx, v_ctx, valid)
+                    # the tables in whole chunks (the dump block pads
+                    # the last one: every position of it is masked)
+                    padded = jnp.pad(tables, ((0, 0), (0, -max_blocks % cb)),
+                                     constant_values=DUMP_BLOCK)
+
+                def fetch(c):
+                    with jax.named_scope("kv_gather"):
+                        part = jax.lax.dynamic_slice_in_dim(
+                            padded, c * cb, cb, axis=1)
+                        return (pool_k[li, part].reshape(B, chunk, H, Dh),
+                                pool_v[li, part].reshape(B, chunk, H, Dh))
+
+                att = paged_attention(q, fetch, positions, active, n_chunks,
+                                      chunk)
                 with jax.named_scope("out"):
                     x = x + _apply_linear(att.reshape(B, 1, H * Dh),
                                           lyr["attn"]["wo"])
@@ -862,6 +916,11 @@ class LMServingEngine:
                 ("attn_selected_tokens",
                  "of those, the positions attention read: the decode "
                  "step's own count under a learned selection, else all"),
+                ("kv_rows_fetched",
+                 "rows of one pool and layer the decode steps' attention "
+                 "fetched through the block tables: max_batch x what the "
+                 "bound let through (whole chunks up to the longest live "
+                 "context), from the host's copy of the step's arguments"),
                 ("moe_assignments",
                  "routed expert assignments of real tokens, the "
                  "scheduler's prefill and decode steps"),
@@ -1782,6 +1841,8 @@ class LMServingEngine:
         self._count("attn_context_tokens", context)
         self._count("attn_selected_tokens",
                     context if read is None else read)
+        self._count("kv_rows_fetched", self.graph.rows_fetched(
+            positions, active, self.block_size, MB))
         now = telemetry.clock_ns()
         with telemetry.span("lm/emit", tokens=int(active.sum())):
             self._emit_tokens(lp, t0, now, step)

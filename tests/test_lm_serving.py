@@ -199,19 +199,113 @@ class TestEngineValidation:
 # decode-vs-full-forward parity (the paged-path correctness proof)
 # ---------------------------------------------------------------------------
 
+#: a table of four chunks of the decode step's attention loop (512
+#: positions in blocks of 16: chunks end at 128, 256, 384, 512)
+_LONG = dict(max_context=512, block_size=16)
+
+
+def _long_engine(**kw):
+    return _engine(_model(max_len=512), **dict(_LONG, **kw))
+
+
+def _one_decode_step(eng, fn, dp, prompt, slot):
+    """Prefill ``prompt``, then ONE decode step with only ``slot`` live:
+    the prefill's token and that slot's row of the step's log-probs."""
+    B, MB = eng.max_batch, eng._max_blocks
+    seq = eng._offline_seq_id()
+    eng.cache.allocate(seq, len(prompt) + 1)
+    try:
+        tok, row = eng._prefill_step_raw(seq, prompt)
+        tokens = np.ones((B, 1), np.int32)
+        positions = np.zeros((B,), np.int32)
+        tables = np.full((B, MB), DUMP_BLOCK, np.int32)
+        active = np.zeros((B,), bool)
+        tokens[slot, 0], positions[slot] = tok, len(prompt)
+        tables[slot], active[slot] = row, True
+        lp, eng.cache.pools, _ = fn(dp, *eng.cache.pools, tokens, positions,
+                                    tables, active)
+        return tok, np.asarray(lp)[slot]
+    finally:
+        eng.cache.free_seq(seq)
+
+
 class TestDecodeParity:
-    def test_greedy_tokens_bit_identical_logps_allclose(self):
-        eng = _engine()
+    # prompt, new tokens: decode steps read contexts of prompt + 1 ...
+    # prompt + new - 1 rows, across the loop's chunk edges
+    @pytest.mark.parametrize("engine,n,new", [
+        (_engine, 9, 12),            # inside the first (only) chunk
+        (_long_engine, 125, 5),      # 126, 127, 128, 129: over the first edge
+        (_long_engine, 253, 5),      # 254 ... 257: over the second
+        (_long_engine, 500, 12),     # the last chunk, up to 511 of 512 rows
+    ], ids=["one_chunk", "first_edge", "second_edge", "last_chunk"])
+    def test_greedy_tokens_bit_identical_logps_allclose(self, engine, n,
+                                                        new):
+        eng = engine()
         toks_paged, lp_paged = eng.generate(
-            _prompt(9, seed=5), max_new_tokens=12, return_logps=True)
+            _prompt(n, seed=5), max_new_tokens=new, return_logps=True)
         toks_full, lp_full = eng.generate_sequential(
-            _prompt(9, seed=5), max_new_tokens=12, return_logps=True)
+            _prompt(n, seed=5), max_new_tokens=new, return_logps=True)
         assert toks_paged == toks_full          # greedy: bit-identical
         # paged logps cover generated tokens 2..N (the prefill's first
         # token has no decode row); sequential covers 1..N
         assert len(lp_paged) == len(lp_full) - 1
         for a, b in zip(lp_paged, lp_full[1:]):
             np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-5)
+        eng.close()
+
+    @pytest.mark.parametrize("n,slot", [(127, 0), (128, 3), (129, 1),
+                                        (383, 2), (511, 3)])
+    def test_one_live_slot_among_inactive_matches_full_forward(self, n,
+                                                               slot):
+        """The longest context one short of a chunk's edge, at it, past
+        it, and at ``max_context``; the live slot is not always the
+        first."""
+        eng = _long_engine()
+        prompt = _prompt(n, seed=n)
+        tok, got = _one_decode_step(eng, eng._decode, eng._dp, prompt, slot)
+        padded = np.ones((1, 512), np.int32)
+        padded[0, :n], padded[0, n] = prompt, tok
+        want = np.asarray(eng._full(eng._dp, padded))[n]
+        np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+        assert int(np.argmax(got)) == int(np.argmax(want))
+        eng.close()
+
+    @pytest.mark.parametrize("lengths", [(3, 130, 260, 480), (200,),
+                                         (127, 128, 129)],
+                             ids=["spread", "alone", "at_an_edge"])
+    def test_slots_of_different_lengths_share_a_step(self, lengths):
+        """The scheduler's steps hold contexts of very different lengths
+        at once (the loop runs to the longest; every shorter slot's later
+        chunks are masked whole): each stream is the sequential
+        baseline's."""
+        config.set_property("bigdl.analysis.retrace", "strict")
+        with _long_engine() as eng:
+            eng.start()
+            streams = [eng.submit(_prompt(n, seed=n), max_new_tokens=6)
+                       for n in lengths]
+            outs = [s.result(timeout=60) for s in streams]
+            stats = eng.stats()
+            _zero_retraces(eng)
+        _assert_identity(stats)
+        if len(lengths) > 1:            # the slots did share iterations
+            assert stats["decode_steps"] < (stats["tokens_out"]
+                                            - stats["prefills"]), stats
+        ref = _long_engine()
+        for n, o in zip(lengths, outs):
+            assert o == ref.generate_sequential(_prompt(n, seed=n),
+                                                max_new_tokens=6)
+        ref.close()
+
+    def test_generate_crosses_every_bound_in_one_program(self):
+        """100 -> 400 rows of context: the loop makes 1, 2, 3 and 4 trips
+        in ONE ``lm_decode`` program at one signature, no retrace."""
+        config.set_property("bigdl.analysis.retrace", "strict")
+        eng = _long_engine()
+        toks = eng.generate(_prompt(100, seed=2), max_new_tokens=300)
+        assert toks == eng.generate_sequential(_prompt(100, seed=2),
+                                               max_new_tokens=300)
+        _zero_retraces(eng)
+        assert len(eng.timings["lm_decode"]) == 1
         eng.close()
 
     def test_mixed_prompt_lengths_share_one_decode_shape(self):
@@ -228,6 +322,151 @@ class TestDecodeParity:
             eng.start()
             with pytest.raises(ServingInfraError, match="offline"):
                 eng.generate(_prompt(4))
+
+
+# ---------------------------------------------------------------------------
+# the decode step reads the tables only as far as the longest live context
+# ---------------------------------------------------------------------------
+
+def _chunks_of(eng):
+    from bigdl_tpu.serving.lm import _chunk_blocks
+    cb = _chunk_blocks(eng.block_size, eng._max_blocks)
+    return cb, cb * eng.block_size
+
+
+class TestBoundedFetch:
+    def test_host_and_device_agree_on_the_trip_count(self):
+        """``LM/kv_rows_fetched`` is counted on the host by the function
+        the step itself calls on the device: the two agree on every
+        input, no live slot included."""
+        import jax.numpy as jnp
+
+        from bigdl_tpu.serving.lm import _live_chunks
+        on_device = jax.jit(lambda p, a: _live_chunks(p, a, 128))
+        rng = np.random.default_rng(0)
+        for _ in range(40):
+            positions = rng.integers(0, 2048, size=16).astype(np.int32)
+            active = rng.random(16) < rng.random()
+            want = max(1, -(-int((positions[active] + 1).max(initial=0))
+                            // 128))
+            assert int(_live_chunks(positions, active, 128, np)) == want
+            assert int(on_device(jnp.asarray(positions),
+                                 jnp.asarray(active))) == want
+
+    @pytest.mark.parametrize("n,trips", [(100, 1), (128, 2), (300, 3),
+                                         (511, 4)])
+    def test_step_reads_the_chunks_the_host_counts_and_no_more(self, n,
+                                                               trips):
+        """The step's own trip count, seen from outside: a table entry in
+        a chunk past the bound may point at a block of NaN and nothing
+        changes (never read); one in the last chunk inside the bound,
+        past the slot's own position and so masked, turns the row NaN
+        (read: 0 x NaN).  ``rows_fetched`` counts exactly those chunks."""
+        eng = _long_engine()
+        cb, chunk = _chunks_of(eng)
+        B, MB = eng.max_batch, eng._max_blocks
+        positions = np.zeros((B,), np.int32)
+        active = np.zeros((B,), bool)
+        positions[1], active[1] = n, True
+        assert eng.graph.rows_fetched(positions, active, eng.block_size,
+                                      MB) == B * chunk * trips
+        poison = eng.cache.n_blocks - 1
+        eng.cache.pools = tuple(p.at[:, poison].set(np.nan)
+                                for p in eng.cache.pools)
+
+        def step(tables):
+            tokens = np.ones((B, 1), np.int32)
+            lp, eng.cache.pools, _ = eng._decode(
+                eng._dp, *eng.cache.pools, tokens, positions, tables, active)
+            return np.asarray(lp)[1]
+
+        tables = np.full((B, MB), DUMP_BLOCK, np.int32)
+        tables[1, :n // eng.block_size + 1] = \
+            1 + np.arange(n // eng.block_size + 1)
+        clean = step(tables)
+        assert np.isfinite(clean).all()
+        beyond = tables.copy()
+        beyond[:, trips * cb:] = poison           # every slot's later chunks
+        np.testing.assert_array_equal(step(beyond), clean)
+        if trips * chunk > n + 1 + eng.block_size:
+            inside = tables.copy()
+            inside[1, trips * cb - 1] = poison    # masked, but fetched
+            assert np.isnan(step(inside)).all()
+        eng.close()
+
+    def test_counter_is_the_sum_of_the_steps_own_counts(self):
+        from bigdl_tpu import telemetry
+        with _long_engine() as eng:
+            _, chunk = _chunks_of(eng)
+            real, seen = eng._decode, []
+
+            def spy(dp, k, v, tokens, positions, tables, active):
+                seen.append((positions.copy(), active.copy()))
+                return real(dp, k, v, tokens, positions, tables, active)
+
+            eng._decode = spy
+            before = telemetry.counter("LM/kv_rows_fetched").value
+            eng.start()
+            streams = [eng.submit(_prompt(n, seed=n), max_new_tokens=8)
+                       for n in (5, 124, 250)]
+            for s in streams:
+                s.result(timeout=60)
+            stats = eng.stats()
+        want = sum(eng.max_batch * chunk
+                   * max(1, -(-int((p[a] + 1).max()) // chunk))
+                   for p, a in seen)
+        assert len(seen) == stats["decode_steps"] and want > 0
+        assert stats["kv_rows_fetched"] == want
+        assert telemetry.counter("LM/kv_rows_fetched").value - before == want
+        # contexts crossed 128 and 256: the steps did not all read alike,
+        # and never fewer rows than the live contexts hold
+        assert len({int((p[a] + 1).max()) // chunk for p, a in seen}) > 1
+        assert stats["kv_rows_fetched"] >= stats["attn_context_tokens"] > 0
+        # offline generate() is not the scheduler's: it counts nothing
+        eng2 = _long_engine()
+        eng2.generate(_prompt(9), max_new_tokens=4)
+        assert eng2.stats()["kv_rows_fetched"] == 0
+        eng2.close()
+
+    @pytest.mark.parametrize("quantize", ["off", "int8"])
+    def test_lowered_decode_holds_no_whole_layer_of_a_pool(self, quantize):
+        """No slice, copy or gather in the lowered step has a whole layer
+        of a pool, or every slot's whole table, as its result: the only
+        arrays of a pool's extent are the 5-D pools themselves."""
+        eng = _long_engine(quantize=quantize)
+        cached, specs = ((eng._decode_q_cached, eng._decode_q_specs)
+                         if quantize == "int8"
+                         else (eng._decode_cached, eng._decode_specs))
+        text = cached.lower(*specs).as_text()
+        L, NB, bs, H, Dh = eng.cache.k.shape
+        B, MB = eng.max_batch, eng._max_blocks
+        cb, chunk = _chunks_of(eng)
+        assert f"tensor<{L}x{NB}x{bs}x{H}x{Dh}xf32>" in text
+        for shape in ((NB, bs, H, Dh), (1, NB, bs, H, Dh),
+                      (B, MB, bs, H, Dh), (B, MB * bs, H, Dh)):
+            assert "tensor<" + "x".join(map(str, shape)) + "xf32>" \
+                not in text, shape
+        # what the loop's body does gather: one chunk of every table
+        assert f"tensor<{B}x{cb}x{bs}x{H}x{Dh}xf32>" in text
+        assert "stablehlo.while" in text
+        eng.close()
+
+    @pytest.mark.parametrize("n,slot", [(127, 2), (129, 0), (400, 1)])
+    def test_int8_tier_reads_the_same_rows(self, n, slot):
+        """The int8 tier is the same function over another pytree: on
+        identical pools its row stays within the gate's tolerance of the
+        fp step's, chunk edges or not."""
+        eng = _long_engine(quantize="int8")
+        prompt = _prompt(n, seed=n)
+        _, fp = _one_decode_step(eng, eng._decode, eng._dp, prompt, slot)
+        _, q = _one_decode_step(eng, eng._decode_q, eng._dp_q, prompt, slot)
+        np.testing.assert_allclose(q, fp, rtol=eng.quantize_rtol,
+                                   atol=eng.quantize_atol)
+        toks = eng.generate(prompt, max_new_tokens=4)
+        assert len(toks) == 4
+        _zero_retraces(eng)
+        assert len(eng.timings["lm_decode_int8"]) == 1
+        eng.close()
 
 
 # ---------------------------------------------------------------------------

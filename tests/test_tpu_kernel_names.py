@@ -83,3 +83,40 @@ def test_flash_kernels_keep_the_names_the_benchmark_reads(one_chip,
     assert all(pattern.search(k) for k in kernels), kernels
     assert sorted(k.split(".")[0][:17] for k in kernels) == [
         "flash_mha_bwd_dkv", "flash_mha_bwd_dq_", "flash_mha_fwd"]
+
+
+def test_lm_decode_reads_no_whole_layer_of_a_pool_on_the_chip(one_chip):
+    """The LM server's decode step as the chip's compiler leaves it, pools
+    donated: both pools aliased to their outputs (the scatters run in
+    place, the attention loop carries the pools without a copy), no
+    operation whose result is a whole layer of a pool or every slot's
+    whole table, and temporaries smaller than one layer of one pool."""
+    from bigdl_tpu.serving import lm as _lm
+    B, bs, MB = 4, 16, 32
+    model = transformer_lm(512, d_model=256, n_head=2, n_layers=2,
+                           max_len=MB * bs)
+    graph = _lm._LMGraph(model)
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    dp = jax.tree_util.tree_map(lambda x: spec(x.shape, x.dtype),
+                                _lm._extract_params(graph))
+    L, NB, H, Dh = graph.n_layers, B * MB + 1, graph.n_head, graph.head_dim
+    pool = spec((L, NB, bs, H, Dh), jnp.float32)
+    step = jax.jit(_lm._build_decode_fn(graph, bs, MB),
+                   donate_argnums=(1, 2))
+    compiled = step.lower(dp, pool, pool, spec((B, 1), jnp.int32),
+                          spec((B,), jnp.int32), spec((B, MB), jnp.int32),
+                          spec((B,), jnp.bool_)).compile()
+    layer_bytes = NB * bs * H * Dh * 4
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == 2 * L * layer_bytes
+    assert mem.temp_size_in_bytes < layer_bytes, mem
+    text = compiled.as_text()
+    for shape in ((NB, bs, H, Dh), (1, NB, bs, H, Dh), (B, MB, bs, H, Dh),
+                  (B * MB, bs, H, Dh), (B, MB * bs, H, Dh)):
+        made = [ln.strip()[:200] for ln in text.splitlines()
+                if re.search(r"= f32\[" + ",".join(map(str, shape)) + r"\]",
+                             ln)]
+        assert not made, made
+    assert " while(" in text
