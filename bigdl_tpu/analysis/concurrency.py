@@ -1,8 +1,9 @@
 """Whole-package concurrency soundness pass (the static half).
 
 Run as ``python -m bigdl_tpu.analysis.concurrency <package> [...]``.
-Imports nothing heavy (no jax) — safe as a CI / bench preflight, and
-wired into ``bench.py --lint-only`` next to the AST linter.
+Imports nothing heavy (no jax) — safe as a CI preflight next to the AST
+linter; tier-1 runs it over the whole package
+(``tests/test_concurrency.py::TestPackageGate``).
 
 The runtime half is :mod:`bigdl_tpu.analysis.lockwitness`; the two share
 one vocabulary: a lock's NAME is the string given to

@@ -29,9 +29,10 @@ directory (``python -m bigdl_tpu.analysis.hlo_audit <cacheDir>``):
    estimate from ``compiled.memory_analysis()`` plus a transpose
    census (generalizing PR 1's one-off ResNet HLO assertion): rank-4
    transposes beyond the contract's ``max_rank4_transposes`` are a
-   violation; the census and peak bytes are always exported so the
-   bench trajectory (``bench.py --audit-only`` → ``bench_audit.json``
-   vs the committed ``audit_baselines.json``) catches regressions
+   violation; the census and peak bytes are always exported, so the
+   offline audit of a persisted cache against the committed
+   ``audit_baselines.json`` (``python -m bigdl_tpu.analysis.hlo_audit
+   <cache dir> --baselines audit_baselines.json``) catches regressions
    rather than absolutes.
 
 Modes mirror ``bigdl.analysis.*``: ``strict`` raises

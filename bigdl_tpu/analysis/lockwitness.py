@@ -27,8 +27,6 @@ Armed STRICT for every tier-1 test by the conftest autouse fixture
 (``bigdl.analysis.lockWitness`` + :func:`arm`), exactly like the
 host-sync guard; disarmed (the default) every wrapper method is one
 module-bool check and a delegate, so production paths pay nanoseconds.
-``bench.py --concurrency-only`` asserts the armed per-acquire overhead
-stays under 1% of the serving p50.
 
 The chaos injector ``bigdl.chaos.lockDelayAt="<lockname>:k[:seconds]"``
 hooks this acquire path: the k-th acquisition of the named lock stalls

@@ -1,8 +1,7 @@
 """Decoded-epoch cache: pay JPEG decode once across repeated epochs.
 
-On the real-data path the decode pool is the measured bottleneck
-(bench_ingest.json: ~1.3k img/s per core against a multi-k img/s
-assemble ceiling), and epochs 2..N decode the SAME compressed records
+On the real-data path JPEG decode is the costliest stage a record
+passes, and epochs 2..N decode the SAME compressed records
 epoch 1 already decoded.  :class:`DecodedEpochCache` interposes at the
 decode stage (``StreamingIngest``'s ``timed_decode``): a hit returns
 the decoded uint8 HWC frame without touching libjpeg, so second-epoch

@@ -18,8 +18,8 @@ Every stage is decoupled by a bounded ring (backpressure, never unbounded
 memory) and instrumented: items, busy seconds, stall seconds split into
 *starve* (waiting for the upstream stage) and *backpressure* (blocked on a
 full downstream ring), plus mean ring occupancy.  ``stats()`` snapshots
-feed ``bench.py --ingest-only`` (``bench_ingest.json``) and the training
-summary layer — the stage with high busy and low stall is the bottleneck.
+feed the training summary layer (``Ingest/<engine>/<stage>/*``) — the
+stage with high busy and low stall is the bottleneck.
 
 Determinism contract (the part that makes this usable for training, not
 just benchmarks): crop offsets / flips draw from a CLONE of the caller's

@@ -149,8 +149,8 @@ class BatchPrefetcher:
         self._fetch = fetch
         self._on_batch = on_batch
         # transfer-stage counters: how long the pipeline spent blocking
-        # uploads device-resident vs fetching — surfaced by bench.py and
-        # the driver's end-of-run metrics.  Written from the fetch AND
+        # uploads device-resident vs fetching — surfaced by the driver's
+        # end-of-run metrics.  Written from the fetch AND
         # transfer producers AND the passthrough (depth 0) caller, so
         # they share a stats lock
         self._stats_lock = analysis.make_lock("engine.prefetch")

@@ -9,18 +9,15 @@ Run::
 
     python -m bigdl_tpu.models.perf -m alexnet -b 64 -i 20
     python -m bigdl_tpu.models.perf -m resnet50 --partitions 8   # mesh DP
-    python -m bigdl_tpu.models.perf -m resnet50 --per-layer      # attribution
     python -m bigdl_tpu.models.perf -m resnet50 --layout nchw    # layout A/B
 
-``--per-layer`` prints the layer-by-layer forward time / FLOPs / MFU
-attribution (:func:`per_layer_report`) instead of the training loop — the
-tool that makes a layout or fusion change attributable layer by layer.
+The throughput it prints is the host's: what the chip reaches is measured
+by ``benchmark/run.py`` (``PERF.md``).
 """
 
 from __future__ import annotations
 
 import argparse
-import sys
 import time
 
 import numpy as np
@@ -72,107 +69,6 @@ def _transformer():
                           max_len=128)
 
 
-# ---------------------------------------------------------------------------
-# per-layer time / FLOPs / MFU attribution
-# ---------------------------------------------------------------------------
-
-def _layer_flops(m, in_shape, out_shape) -> float:
-    """Matmul FLOPs of one leaf's forward (0 for memory-bound layers)."""
-    if isinstance(m, nn.SpatialFullConvolution):
-        # every input pixel scatters a kh*kw patch into every output plane
-        in_pix = int(np.prod(in_shape)) // m.n_input_plane
-        return 2.0 * m.kh * m.kw * m.n_input_plane * m.n_output_plane * in_pix
-    if isinstance(m, nn.SpatialConvolution):
-        if m.format == "NHWC":
-            out_pix = int(np.prod(out_shape[:-1]))
-        else:
-            out_pix = int(np.prod(out_shape)) // m.n_output_plane
-        taps = m.kernel_h * m.kernel_w * (m.n_input_plane // m.n_group)
-        return 2.0 * taps * m.n_output_plane * out_pix
-    if isinstance(m, nn.SpatialDilatedConvolution):
-        out_pix = (int(np.prod(out_shape[:-1])) if m.format == "NHWC"
-                   else int(np.prod(out_shape)) // m.n_output_plane)
-        return 2.0 * m.kh * m.kw * m.n_input_plane * m.n_output_plane * out_pix
-    if isinstance(m, nn.Linear):
-        rows = int(np.prod(out_shape)) // m.output_size
-        return 2.0 * m.input_size * m.output_size * rows
-    return 0.0
-
-
-def _walk_forward(m, x, rows):
-    """Execute ``m`` child by child (each leaf's own jitted, device-synced
-    forward) collecting (module, input shape, output) rows.  Containers the
-    walk understands are expanded; anything else times as one leaf."""
-    import jax.numpy as jnp
-    from bigdl_tpu.nn.structural import _axis
-
-    if isinstance(m, (nn.Sequential, nn.Remat)):
-        for c in m.children:
-            x = _walk_forward(c, x, rows)
-        return x
-    if isinstance(m, nn.Concat):
-        outs = [_walk_forward(c, x, rows) for c in m.children]
-        return jnp.concatenate(outs, axis=_axis(m.dimension, outs[0].ndim))
-    if isinstance(m, nn.ConcatTable):
-        return [_walk_forward(c, x, rows) for c in m.children]
-    in_shape = getattr(x, "shape", None)
-    m.forward_time = 0
-    out = m.forward(x)
-    rows.append((m, in_shape, out))
-    return out
-
-
-def per_layer_report(model, input, peak_tflops=None, file=None):
-    """Layer-by-layer forward attribution: wall time, share of total, FLOPs
-    and achieved TFLOP/s (plus MFU when ``peak_tflops`` names the chip's
-    peak) for every leaf module, in execution order.
-
-    Per-layer dispatch defeats cross-layer XLA fusion, so the TOTAL here
-    exceeds the fused step the trainers run — read the numbers as relative
-    attribution (which layers move when a layout/fusion change lands), not
-    absolute throughput.  Returns the list of per-layer record dicts.
-    """
-    file = file or sys.stderr
-    model._ensure_init()
-    # two passes: the first absorbs each leaf's jit compile
-    _walk_forward(model, input, [])
-    rows = []
-    _walk_forward(model, input, rows)
-    total_ns = sum(m.forward_time for m, _, _ in rows) or 1
-    records = []
-    print(f"{'layer':<34}{'type':<28}{'out_shape':<20}"
-          f"{'ms':>8}{'%time':>7}{'GFLOP':>9}{'TFLOP/s':>9}"
-          + (f"{'MFU%':>7}" if peak_tflops else ""), file=file)
-    for m, in_shape, out in rows:
-        out_shape = (out[0].shape if isinstance(out, (list, tuple))
-                     else out.shape)
-        ms = m.forward_time / 1e6
-        flops = _layer_flops(m, in_shape, out_shape)
-        tflops = flops / (m.forward_time or 1) / 1e3
-        rec = {"name": m.name, "type": type(m).__name__,
-               "out_shape": tuple(out_shape), "ms": round(ms, 3),
-               "time_share": round(m.forward_time / total_ns, 4),
-               "gflop": round(flops / 1e9, 3),
-               "tflops": round(tflops, 3)}
-        line = (f"{m.name:<34}{type(m).__name__:<28}"
-                f"{str(tuple(out_shape)):<20}{ms:>8.2f}"
-                f"{100 * m.forward_time / total_ns:>6.1f}%"
-                f"{flops / 1e9:>9.2f}{tflops:>9.2f}")
-        if peak_tflops:
-            rec["mfu"] = round(tflops / peak_tflops, 4)
-            line += f"{100 * tflops / peak_tflops:>6.1f}%"
-        print(line, file=file)
-        records.append(rec)
-    tot_gflop = sum(r["gflop"] for r in records)
-    tot_tflops = tot_gflop * 1e6 / total_ns     # GFLOP / (ns -> ms) = TFLOP/s
-    line = (f"{'TOTAL':<34}{'':<28}{'':<20}{total_ns / 1e6:>8.2f}"
-            f"{100.0:>6.1f}%{tot_gflop:>9.2f}{tot_tflops:>9.2f}")
-    if peak_tflops:
-        line += f"{100 * tot_tflops / peak_tflops:>6.1f}%"
-    print(line, file=file)
-    return records
-
-
 def main(argv=None):
     p = argparse.ArgumentParser(description="zoo throughput harness")
     p.add_argument("-m", "--model", choices=sorted(_MODELS), default="lenet5")
@@ -183,17 +79,11 @@ def main(argv=None):
     p.add_argument("--precision", choices=["fp32", "bf16"], default="fp32",
                    help="fused-step compute precision (fp32 matches the "
                         "reference harness; bf16 is the TPU-first mode "
-                        "the headline bench uses)")
+                        "the benchmark's training cell runs)")
     p.add_argument("--layout", choices=["nhwc", "nchw"], default="nhwc",
                    help="convnet compute layout: nhwc = channels-last "
                         "trunk (TPU-native default), nchw = the classic "
                         "Torch layout, for before/after A-B runs")
-    p.add_argument("--per-layer", action="store_true",
-                   help="print the layer-by-layer forward time/FLOPs/MFU "
-                        "attribution instead of running the training loop")
-    p.add_argument("--peak-tflops", type=float, default=None,
-                   help="chip peak for the per-layer MFU column (e.g. 197 "
-                        "for one v5e chip at bf16)")
     args = p.parse_args(argv)
     driver_utils.init_logging()
 
@@ -201,19 +91,6 @@ def main(argv=None):
     model = build(args.layout.upper())
     rng = np.random.RandomState(0)
 
-    if args.per_layer:
-        import jax.numpy as jnp
-        if args.model == "transformer":   # 1-based token ids, not pixels
-            x = jnp.asarray(rng.randint(1, classes + 1,
-                                        (args.batch_size,) + shape)
-                            .astype(np.float32))
-        else:
-            x = jnp.asarray(rng.uniform(-1, 1, (args.batch_size,) + shape)
-                            .astype(np.float32))
-        print(f"[{args.model}] per-layer forward attribution "
-              f"(batch {args.batch_size}, layout {args.layout})",
-              file=sys.stderr)
-        return per_layer_report(model, x, peak_tflops=args.peak_tflops)
     n_records = max(args.batch_size * 2, args.partitions * 2)
     if args.model == "transformer":
         records = [Sample(rng.randint(1, classes + 1, shape)

@@ -193,8 +193,10 @@ class MultiHeadAttention(Module):
     on the current machine.  Default (False) stays the standard path —
     it is bit-exact against the other paths, composes with the GSPMD
     head split (pallas kernels do not partition), and has no shape
-    constraints; perf-critical dense training opts in (bench.py's LM
-    legs and ``chip_smoke.py`` do).  flash=True raises when the backend/shape constraints
+    constraints; perf-critical dense training opts in (the benchmark's
+    ``transformer_lm`` builder and ``chip_smoke.py`` do; on the chip the
+    kernels read ``flash_roofline.train``, ``PERF.md``).  flash=True
+    raises when the backend/shape constraints
     aren't met (TPU only, T % 128 == 0, head_dim % 128 == 0,
     self-attention with Tq == Tkv — the kernel's causal mask is
     top-left aligned, which diverges from the reference's

@@ -31,8 +31,9 @@ instead of falling over:
   the HLO auditor's precision pass + an fp-vs-int8 logits allclose
   (``docs/optimization.md`` "LM serving").
 - :mod:`~bigdl_tpu.serving.loadgen` — the Poisson open-loop load
-  generator the bench legs (``bench.py --serving-only`` /
-  ``--lm-serving-only``) and the chaos proofs drive the engines with,
+  generator the accounting-identity and chaos proofs of
+  ``tests/test_serving.py`` and ``tests/test_lm_serving.py`` drive the
+  engines with (the benchmark has its own: ``benchmark/traffic/``),
   including the ``bigdl.chaos.burstArrivals`` thundering-herd injector;
   :func:`~bigdl_tpu.serving.loadgen.run_lm_open_loop` adds client-side
   TTFT / inter-token-latency percentiles over streamed tokens.

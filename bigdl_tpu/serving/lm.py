@@ -1096,13 +1096,6 @@ class LMServingEngine:
                 "them on every call", self.cache.aliased_bytes,
                 self.cache.pool_nbytes)
 
-    def warmup_sequential(self) -> None:
-        """AOT-compile the ``lm_full`` ladder of the offline baseline
-        (:meth:`generate_sequential`), which serving never dispatches —
-        without this each bucket compiles at its first use."""
-        for specs in self._full_specs.values():
-            self._full_cached.warmup(*specs)
-
     # -- int8 gate --------------------------------------------------------
 
     def _quantization_gate(self) -> None:

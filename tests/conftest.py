@@ -81,6 +81,19 @@ def _lock_witness_armed():
     config.clear_property("bigdl.analysis.lockWitness")
 
 
+@pytest.fixture(scope="session")
+def cold_probe(tmp_path_factory):
+    """The compile probe's cold start (``tests/compile_probe.py``): a
+    real child process trains and evaluates against an empty executable
+    cache.  Returns ``(cache dir, what the child printed)``; run once a
+    worker, for whichever of its tests ask (the warm-start test and the
+    offline-audit tests share it where xdist puts their files on one
+    worker)."""
+    import compile_probe
+    cache_dir = str(tmp_path_factory.mktemp("probe_ccache"))
+    return cache_dir, compile_probe.run_child(cache_dir)
+
+
 @pytest.fixture(autouse=True, scope="session")
 def _no_thread_leaks():
     """End-of-suite leak check: every framework thread spawned during the

@@ -5,7 +5,8 @@ The contract under test (utils/compile_cache.py):
 
 - a second trainer over the same model+topology reaches its first device
   step with ZERO fresh compiles (cache hit per fused step) and
-  bit-identical step results;
+  bit-identical step results: in this process, and in a second OS
+  process over the first one's directory (``tests/compile_probe.py``);
 - torn / uncommitted / corrupt / version-skewed / foreign-topology
   entries are a logged MISS and a recompile — never a crash — with
   exact numerical parity after the fallback;
@@ -35,6 +36,8 @@ from bigdl_tpu.utils.compile_cache import (CachedStep, CompileCache,
                                            pad_batch, slice_rows,
                                            tracked_jit)
 from bigdl_tpu import telemetry
+
+import compile_probe
 
 
 @pytest.fixture
@@ -137,6 +140,28 @@ class TestStoreLifecycle:
         assert cached.compiles == 0 and cached.cache_misses == 0
         assert np.array_equal(_flat(m1.params), _flat(m2.params)), \
             "warm-start step results must be bit-identical to cold"
+
+    def test_second_process_starts_warm(self, cold_probe):
+        """The same contract across REAL processes: a second child over
+        the cold child's directory (``tests/compile_probe.py``: trainer
+        plus ragged bucketed validation) compiles nothing, loads every
+        signature the cold one compiled, trains to the same weights,
+        and neither retraces its eval forward."""
+        cache_dir, cold = cold_probe
+        warm = compile_probe.run_child(cache_dir)
+
+        def total(rec, key):
+            return sum(step[key] for step in rec["steps"].values())
+
+        assert total(cold, "hits") == 0 and total(cold, "misses") >= 3
+        assert total(warm, "misses") == 0 and total(warm, "compiles") == 0, \
+            "warm start must skip compilation entirely"
+        assert total(warm, "hits") == total(cold, "misses"), \
+            "every cold-compiled fused-step signature must warm-load"
+        assert warm["weights_crc"] == cold["weights_crc"], \
+            "warm-start step results must be bit-identical"
+        assert cold["eval_retraces"] == 0 and warm["eval_retraces"] == 0, \
+            "bucketed ragged validation must stay retrace-free"
 
     def test_loaded_executable_failing_first_dispatch_recompiles(
             self, cache_dir, monkeypatch):

@@ -342,6 +342,32 @@ class TestOffline:
         assert rc == 0
         assert "[local]" in out and "0 problem(s)" in out
 
+    def test_auditor_accepts_what_a_real_child_persisted(self, cold_probe):
+        """The offline auditor over a cache that a real trainer process
+        wrote (``tests/compile_probe.py``, audit passes at their default
+        of warn): no problem, and every signature the child compiled is
+        there with its census, under the label of its fused step."""
+        cache_dir, cold = cold_probe
+        lines, problems = hlo_audit.audit_cache_dir(cache_dir)
+        assert problems == []
+        audited = [ln for ln in lines if "collective(s)" in ln]
+        for step, label in (("train/local", "local"), ("eval", "eval")):
+            assert cold["steps"][step]["misses"] >= 1
+            assert sum(f"[{label}]" in ln for ln in audited) == \
+                cold["steps"][step]["misses"], lines
+
+    @pytest.mark.slow
+    def test_probe_census_matches_committed_baselines(self, cold_probe):
+        """The probe's persisted census against the repository's
+        ``audit_baselines.json``: collective bytes within 1.25x + 1 KiB,
+        no more rank-4 transposes, no new collective kind."""
+        baselines = hlo_audit.load_baselines(os.path.join(
+            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+            "audit_baselines.json"))
+        assert {"local", "eval"} <= set(baselines)
+        _, problems = hlo_audit.audit_cache_dir(cold_probe[0], baselines)
+        assert problems == []
+
     def test_cli_flags_undeclared_kind_in_persisted_entry(self, tmp_path,
                                                           capsys):
         """A hand-written entry whose census carries a collective its

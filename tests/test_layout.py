@@ -353,19 +353,3 @@ class TestFoldConvBN:
         np.testing.assert_allclose(folded, plain, rtol=0, atol=1e-5)
         # the served model was a clone: the original still has its BNs
         assert m.find_modules(nn.SpatialBatchNormalization)
-
-
-def test_per_layer_report_smoke(capsys):
-    from bigdl_tpu.models.perf import per_layer_report
-    from bigdl_tpu.models.lenet import lenet5
-    import io
-    m = lenet5(10).evaluate()
-    buf = io.StringIO()
-    recs = per_layer_report(m, _x(4, 1, 28, 28).reshape(4, 28, 28),
-                            peak_tflops=197.0, file=buf)
-    txt = buf.getvalue()
-    assert "SpatialConvolution" in txt and "TOTAL" in txt
-    conv = [r for r in recs if r["type"] == "SpatialConvolution"]
-    assert conv and all(r["gflop"] > 0 for r in conv)
-    # shares are rounded to 4 decimals per row before summing
-    assert abs(sum(r["time_share"] for r in recs) - 1.0) < 0.01

@@ -9,8 +9,6 @@ import os
 import subprocess
 import sys
 
-import pytest
-
 from bigdl_tpu.analysis.lint import (DEFAULT_ALLOWLIST, KNOWN_RULES,
                                      lint_paths, load_allowlist,
                                      main as lint_main)
@@ -57,28 +55,3 @@ def test_known_rule_filter_exits_zero(capsys):
     rc = lint_main(["bigdl_tpu/analysis/lint.py",
                     "--rule", "undeclared-collective"])
     assert rc == 0, capsys.readouterr()
-
-
-def test_bench_lint_only_preflight():
-    """bench.py --lint-only runs the linter + native.check_build + the
-    offline HLO audit over a freshly-populated probe compile cache."""
-    proc = subprocess.run(
-        [sys.executable, "bench.py", "--lint-only"],
-        cwd=REPO, capture_output=True, text=True, timeout=300,
-        env={**os.environ, "JAX_PLATFORMS": "cpu"})
-    assert proc.returncode == 0, proc.stdout + proc.stderr
-    assert "preflight" in (proc.stdout + proc.stderr)
-    assert "HLO audit OK" in (proc.stdout + proc.stderr)
-
-
-@pytest.mark.slow
-def test_bench_audit_only_matches_baselines():
-    """The acceptance criterion: --audit-only's census matches the
-    committed audit_baselines.json within tolerance (nonzero exit on a
-    contract or baseline regression)."""
-    proc = subprocess.run(
-        [sys.executable, "bench.py", "--audit-only"],
-        cwd=REPO, capture_output=True, text=True, timeout=600,
-        env={**os.environ, "JAX_PLATFORMS": "cpu"})
-    assert proc.returncode == 0, proc.stdout + proc.stderr
-    assert "audit_collective_bytes" in proc.stdout

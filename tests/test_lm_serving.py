@@ -516,6 +516,25 @@ class TestStreamingScheduler:
             assert o == ref.generate(_prompt(5, seed=i), max_new_tokens=8)
         ref.close()
 
+    @pytest.mark.parametrize("quantize", [None, "int8"])
+    def test_clean_open_loop_completes_every_stream(self, quantize):
+        """A Poisson open loop of mixed prompt and answer lengths with
+        no fault planned: every stream completes with all its tokens,
+        the identity is exact in the client's record and in the engine's
+        stats, and no step retraces: on the fp tier and the int8 tier."""
+        reqs = sample_lm_workload(12, VOCAB, seed=7,
+                                  prompt_lens=(4, 8, 12),
+                                  output_lens=(4, 6, 8))
+        with _engine(quantize=quantize) as eng:
+            eng.start()
+            rec = run_lm_open_loop(eng, reqs, rate_hz=200.0, seed=11)
+            stats = eng.stats()
+        _assert_identity(rec)
+        _assert_identity(stats)
+        assert rec["completed"] == len(reqs), rec
+        assert rec["tokens_total"] == sum(n for _, n in reqs)
+        _zero_retraces(eng)
+
     def test_blocks_free_after_drain(self):
         with _engine() as eng:
             eng.start()

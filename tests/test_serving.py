@@ -517,26 +517,6 @@ class TestCombinedChaosPlan:
 
 
 # ---------------------------------------------------------------------------
-# Bench leg (fast leg inline; soak is slow-marked)
-# ---------------------------------------------------------------------------
-
-@pytest.mark.slow
-def test_serving_bench_soak():
-    """The ``bench.py --serving-only`` soak variant: calibrated Poisson
-    leg long enough to exercise steady-state percentiles, plus the
-    overload burst — all asserts live in bench_serving itself."""
-    import os
-    import sys
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    if repo not in sys.path:
-        sys.path.insert(0, repo)
-    import bench
-    rec = bench.bench_serving(soak=True, write=False)
-    assert rec["calibrated"]["p99_ms"] <= rec["deadline_ms"]
-    assert rec["overload"]["rejected"] > 0
-
-
-# ---------------------------------------------------------------------------
 # Restart/reuse contract + supervisor abandon (ISSUE 17 satellites)
 # ---------------------------------------------------------------------------
 
