@@ -569,10 +569,11 @@ def _build_full_fn(graph: _LMGraph):
 
 def _pooled(fn):
     """A ``transformer_lm`` step ``(dp, pool_k, pool_v, ...) -> (lp, k, v)``
-    in the shape the engine dispatches for every family: ``(dp, *pools,
-    ...) -> (lp, pools, aux)``, the cache's pools in its order (each
-    donated), returned as one tuple so that one statement writes them
-    back, and ``aux`` what the step counts on the device (here nothing)."""
+    in the shape every family's builder returns: ``(dp, *pools, ...) ->
+    (lp, pools, aux)``, the cache's pools in its order (each donated),
+    returned as one tuple so that one statement writes them back, and
+    ``aux`` what the step counts on the device (here nothing).  The engine
+    dispatches it with its token chosen (:func:`_choosing`)."""
 
     def step(dp, *args):
         lp, k, v = fn(dp, *args)
@@ -582,12 +583,13 @@ def _pooled(fn):
 
 
 def _build_glm_steps(graph: "_GlmGraph", block_size: int):
-    """The three steps of the ``glm_moe_dsa`` family in the shape the
-    engine dispatches (:func:`_pooled`), each the family's ONE forward
-    function (``models.transformer.glm_moe_dsa.forward``) handed this
-    step's view of the pools ``(latent, index)``.  ``aux`` is three int32:
-    routed assignments of the real tokens, those that met a held expert,
-    and (decode) the context rows the active slots' attention read."""
+    """The three steps of the ``glm_moe_dsa`` family in the shape
+    :func:`_pooled` gives the other one (the engine adds the token:
+    :func:`_choosing`), each the family's ONE forward function
+    (``models.transformer.glm_moe_dsa.forward``) handed this step's view
+    of the pools ``(latent, index)``.  ``aux`` is three int32: routed
+    assignments of the real tokens, those that met a held expert, and
+    (decode) the context rows the active slots' attention read."""
     from bigdl_tpu.models.transformer.glm_moe_dsa import forward
     from bigdl_tpu.nn.latent_attention import LatentCache
     spec = graph.spec
@@ -619,6 +621,27 @@ def _build_glm_steps(graph: "_GlmGraph", block_size: int):
         return forward(spec, dp, tokens, pos)[0][0]
 
     return decode, prefill, full
+
+
+def _choosing(step):
+    """A family's step ``(dp, *pools, ...) -> (lp, pools, aux)`` as the
+    engine dispatches it: ``-> (tok, lp, pools, aux)``, where ``tok`` is the
+    greedy token of every row of ``lp`` (1-based, the first index on a tie,
+    as ``np.argmax``), chosen on the device from the same float32
+    log-probs.  The scheduler pulls ``tok`` (and ``aux``) and nothing
+    else; ``lp`` stays an output of the same program for who asks for
+    rows (``generate(return_logps=True)``, the int8 gate), and costs one
+    write on the device when nobody does."""
+
+    def chosen(dp, *args):
+        lp, pools, aux = step(dp, *args)
+        with jax.named_scope("greedy"):
+            tok = jnp.argmax(lp, axis=-1).astype(jnp.int32) + 1
+        return tok, lp, pools, aux
+
+    # the device's module keeps the name a trace knows the step by
+    chosen.__name__ = step.__name__
+    return chosen
 
 
 # ---------------------------------------------------------------------------
@@ -761,6 +784,11 @@ class _Slot:
 # ---------------------------------------------------------------------------
 
 
+def _pulled_nbytes(tree) -> int:
+    """Bytes of the arrays of ``tree``: what one pull of it brings across."""
+    return sum(x.nbytes for x in jax.tree_util.tree_leaves(tree))
+
+
 def _tree_spec(tree):
     return jax.tree_util.tree_map(
         lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), tree)
@@ -897,13 +925,16 @@ class LMServingEngine:
             help="submit to the scheduler's pop, per admitted request")
         self._prefill_ms = telemetry.histogram(
             "LM/prefill_ms", window=window,
-            help="prefill dispatch to its log-probs on the host, per "
+            help="prefill dispatch to its token on the host, per "
                  "admitted request")
         self._h2d = telemetry.counter(
             "LM/h2d_bytes",
             help="host arrays handed to the prefill and decode steps")
         self._d2h = telemetry.counter(
-            "LM/d2h_bytes", help="log-probs pulled from the two steps")
+            "LM/d2h_bytes",
+            help="what was pulled from the prefill and decode steps: their "
+                 "tokens and counts, and a row of log-probs where "
+                 "generate() was asked for them")
         # tallies of this engine (stats()) and their process-wide counters
         self._tally: Dict[str, int] = {}                # guarded-by: _lock
         self._tally_counters = {
@@ -926,7 +957,11 @@ class LMServingEngine:
                  "scheduler's prefill and decode steps"),
                 ("moe_assignments_held",
                  "of those, the assignments to an expert this model "
-                 "holds"))}
+                 "holds"),
+                ("decode_pulled_bytes",
+                 "bytes the scheduler pulled from its decode steps: "
+                 "max_batch int32 tokens a step, and the step's three "
+                 "counts in the family that counts"))}
         telemetry.gauge(
             "LM/cache_bytes_per_token",
             help="bytes the pools hold for one token, all layers").set(
@@ -1001,6 +1036,8 @@ class LMServingEngine:
                     jax.ShapeDtypeStruct((B, MB), jnp.int32),
                     jax.ShapeDtypeStruct((B,), jnp.bool_))
         decode, prefill, full = self.graph.steps(self.block_size, MB)
+        # both families, and the int8 tier over the same decode
+        decode, prefill = _choosing(decode), _choosing(prefill)
 
         def wire(fn, label, contract, specs_list,
                  donate=tuple(range(1, 1 + len(pools)))):
@@ -1136,10 +1173,10 @@ class LMServingEngine:
             tables[0], active[0] = table_row, True
             tail = (tokens, positions, tables, active)
             with self._lock:
-                lp_fp, self.cache.pools, _ = self._decode(
+                _, lp_fp, self.cache.pools, _ = self._decode(
                     self._dp, *self.cache.pools, *tail)
             with self._lock:
-                lp_q, self.cache.pools, _ = self._decode_q(
+                _, lp_q, self.cache.pools, _ = self._decode_q(
                     self._dp_q, *self.cache.pools, *tail)
             a = np.asarray(host_pull(lp_fp, what="lm gate fp logits"))[0]
             b = np.asarray(host_pull(lp_q, what="lm gate int8 logits"))[0]
@@ -1378,15 +1415,14 @@ class LMServingEngine:
         return True
 
     def _count_aux(self, aux) -> Optional[int]:
-        """What a step of the scheduler counted on the device, pulled
-        after its log-probs (which are ready by then): the expert layers'
-        assignments into their two tallies; returns the context rows the
-        step's attention read (None: the family counts nothing, it reads
-        every row)."""
+        """What a step of the scheduler counted on the device, on the host
+        already (it crossed in the one pull that brought the step's
+        token): the expert layers' assignments into their two tallies;
+        returns the context rows the step's attention read (None: the
+        family counts nothing, it reads every row)."""
         if aux is None:
             return None
-        from bigdl_tpu.analysis.hostsync import host_pull
-        made, held, read = np.asarray(host_pull(aux, what="lm step counts"))
+        made, held, read = aux
         self._count("moe_assignments", int(made))
         self._count("moe_assignments_held", int(held))
         return int(read)
@@ -1742,8 +1778,10 @@ class LMServingEngine:
                           served: bool = False) -> Tuple[int, np.ndarray]:
         """Run the bucketed prefill for an ALLOCATED sequence: scatter
         the prompt's k/v into its blocks, return the first greedy token
-        (1-based) and the dump-padded table row the decode step
-        gathers through."""
+        (1-based, the step's own choice: one int32 crosses, with the
+        step's counts in the same pull when ``served``; the row of
+        log-probs stays on the device) and the dump-padded table row the
+        decode step gathers through."""
         from bigdl_tpu.analysis.hostsync import host_pull
         P = int(prompt.size)
         bucket = self._prefill_bucket(P)
@@ -1755,25 +1793,29 @@ class LMServingEngine:
         # the step consumes the pools: written back in the statement
         # that dispatches, so no line can see a deleted array
         with self._lock:
-            lp, self.cache.pools, aux = self._prefill(
+            tok, _, self.cache.pools, aux = self._prefill(
                 self._dp, *self.cache.pools, padded, np.int32(P), table_row)
+        # the tallies are of admitted requests, as the histograms are:
+        # generate()'s offline steps neither pull nor count theirs
+        pulled = (tok, aux if served else None)
         with telemetry.span("lm/prefill_wait"):
-            lp = np.asarray(host_pull(lp, what="lm prefill logits"))
-        if served:
-            # the tallies are of admitted requests, as the histograms
-            # are: generate()'s offline steps do not count
-            self._count_aux(aux)
+            tok, aux = host_pull(pulled, what="lm prefill token")
+        self._count_aux(aux)
         self._h2d.inc(padded.nbytes + 4 + table_row.nbytes)
-        self._d2h.inc(lp.nbytes)
+        self._d2h.inc(_pulled_nbytes(pulled))
         with self._lock:
             self.prefills += 1
         telemetry.counter("LM/prefills").inc()
-        return int(np.argmax(lp)) + 1, table_row
+        return int(tok), table_row
 
     def _decode_iteration(self, wd) -> None:
         """ONE fused decode step over every occupied slot — the
-        continuous-batching heartbeat.  Finished sequences vacate their
-        slot and free their blocks before the next admission pass."""
+        continuous-batching heartbeat.  The step chooses every slot's
+        token on the device; ONE pull brings the ``max_batch`` int32 (and
+        the step's counts, where the family counts) to the host, and the
+        ``(max_batch, vocab)`` log-probs never cross.  Finished sequences
+        vacate their slot and free their blocks before the next admission
+        pass."""
         from bigdl_tpu.analysis.hostsync import host_pull
         from bigdl_tpu.utils import chaos
         self.decode_steps += 1
@@ -1816,16 +1858,18 @@ class LMServingEngine:
         dp, fn = ((self._dp_q, self._decode_q)
                   if self._dp_q is not None else (self._dp, self._decode))
         with telemetry.span("lm/decode_dispatch"), self._lock:
-            lp, self.cache.pools, aux = fn(
+            toks, _, self.cache.pools, aux = fn(
                 dp, *self.cache.pools, tokens, positions, tables, active)
-        # the device time still to run plus the transfer of the whole
-        # (max_batch, vocab) float32 log-probs
-        with telemetry.span("lm/decode_wait", bytes=lp.nbytes):
-            lp = np.asarray(host_pull(lp, what="lm decode logits"))
+        # the device time still to run plus one round trip for what
+        # crosses: max_batch int32, and three more where the step counts
+        nbytes = _pulled_nbytes((toks, aux))
+        with telemetry.span("lm/decode_wait", bytes=nbytes):
+            toks, aux = host_pull((toks, aux), what="lm decode tokens")
         read = self._count_aux(aux)
         self._h2d.inc(tokens.nbytes + positions.nbytes + tables.nbytes
                       + active.nbytes)
-        self._d2h.inc(lp.nbytes)
+        self._d2h.inc(nbytes)
+        self._count("decode_pulled_bytes", nbytes)
         # every active slot's context, from the host's positions, and the
         # rows of it attention read: the device's own count where the
         # family selects, else all of them
@@ -1838,7 +1882,7 @@ class LMServingEngine:
             positions, active, self.block_size, MB))
         now = telemetry.clock_ns()
         with telemetry.span("lm/emit", tokens=int(active.sum())):
-            self._emit_tokens(lp, t0, now, step)
+            self._emit_tokens(toks, t0, now, step)
         ms = (telemetry.clock_ns() - t0) / 1e6
         self._ema.observe(ms)
         if wd is not None:
@@ -1850,15 +1894,16 @@ class LMServingEngine:
         telemetry.gauge("LM/slot_occupancy").set(
             sum(s is not None for s in self._slots) / max(1, B))
 
-    def _emit_tokens(self, lp: np.ndarray, t0: int, now: int,
+    def _emit_tokens(self, toks: np.ndarray, t0: int, now: int,
                      step: int) -> None:
-        """The per-slot half of a decode iteration: arg-max, emit, and
-        for a sequence that ends here finish, vacate and free."""
+        """The per-slot half of a decode iteration: the slot's entry of
+        the step's ``(max_batch,)`` tokens, emit, and for a sequence that
+        ends here finish, vacate and free."""
         for i, slot in enumerate(self._slots):
             if slot is None:
                 continue
             stream = slot.stream
-            tok = int(np.argmax(lp[i])) + 1
+            tok = int(toks[i])
             slot.position += 1
             slot.generated += 1
             slot.last_token = tok
@@ -1906,7 +1951,11 @@ class LMServingEngine:
                  return_logps: bool = False):
         """Offline greedy generation through the PAGED path (prefill +
         single-token decode over the block table) — the exact compiled
-        steps the scheduler dispatches, minus the scheduler.  Refused
+        steps the scheduler dispatches, minus the scheduler, and the
+        tokens taken as the scheduler takes them: the step's own choice,
+        one int32 pulled a step.  ``return_logps=True`` also pulls the
+        row of float32 log-probs each decode step chose from (the parity
+        proofs and the benchmark's precision gate ask for it).  Refused
         while the scheduler runs (it owns the slots and pools)."""
         from bigdl_tpu.analysis.hostsync import host_pull
         if self._started:
@@ -1947,16 +1996,17 @@ class LMServingEngine:
                 tokens[0, 0], positions[0] = out_tokens[-1], position
                 tables[0], active[0] = table_row, True
                 with self._lock:
-                    lp, self.cache.pools, aux = fn(
+                    tok, lp, self.cache.pools, _ = fn(
                         dp, *self.cache.pools, tokens, positions, tables,
                         active)
                 self._h2d.inc(tokens.nbytes + positions.nbytes
                               + tables.nbytes + active.nbytes)
-                self._d2h.inc(lp.nbytes)
-                row = np.asarray(host_pull(
-                    lp, what="lm offline decode logits"))[0]
-                out_tokens.append(int(np.argmax(row)) + 1)
-                logps.append(row)
+                pulled = (tok, lp if return_logps else None)
+                self._d2h.inc(_pulled_nbytes(pulled))
+                tok, lp = host_pull(pulled, what="lm offline decode token")
+                out_tokens.append(int(tok[0]))
+                if return_logps:
+                    logps.append(lp[0])
                 position += 1
         finally:
             self.cache.free_seq(seq_id)

@@ -284,10 +284,12 @@ def test_prefill_then_paged_decode_matches_the_reference(model, reference):
         tokens = np.array([[t[-1]] for t in toks], np.int32)
         positions = np.array([p.size + step for p in prompts], np.int32)
         with eng._lock:
-            lp, eng.cache.pools, aux = eng._decode(
+            tok, lp, eng.cache.pools, aux = eng._decode(
                 eng._dp, *eng.cache.pools, tokens, positions, np.stack(rows),
                 np.ones((B,), bool))
         lp = np.asarray(lp)
+        np.testing.assert_array_equal(np.asarray(tok),
+                                      np.argmax(lp, axis=-1) + 1)
         made, _, read = np.asarray(aux).tolist()
         assert made == 2 * 2 * 4          # 2 tokens, top-2, 4 layers
         # the device's own count of the rows attention read, a layer
@@ -303,6 +305,84 @@ def test_prefill_then_paged_decode_matches_the_reference(model, reference):
             np.testing.assert_allclose(row, want[p.size + step], atol=2e-5)
         eng.cache.free_seq(-10 - i)
     assert _pools_clean(eng.cache)
+
+
+def test_step_token_is_the_argmax_of_its_own_row(model):
+    """The second family's steps pass through the same one place: over a
+    run of decode steps with a live and an idle slot, ``tok`` is
+    ``argmax(lp) + 1`` of the ``lp`` the same call returns, bit for bit,
+    in the prefill's scalar too."""
+    eng = engine(model)
+    eng.warmup()
+    prompt = tokens_of(41, 14)
+    eng.cache.allocate(-3, prompt.size + 8)
+    padded = np.ones((1, eng._prefill_bucket(prompt.size)), np.int32)
+    padded[0, :prompt.size] = prompt
+    row = np.full((eng._max_blocks,), 0, np.int32)
+    blocks = eng.cache.table(-3)
+    row[:len(blocks)] = blocks
+    tok, lp, eng.cache.pools, aux = eng._prefill(
+        eng._dp, *eng.cache.pools, padded, np.int32(prompt.size), row)
+    assert np.asarray(tok).shape == () and np.asarray(aux).shape == (3,)
+    assert int(tok) == int(np.argmax(np.asarray(lp))) + 1
+    B = eng.max_batch
+    tokens = np.ones((B, 1), np.int32)
+    positions = np.zeros((B,), np.int32)
+    tables = np.zeros((B, eng._max_blocks), np.int32)
+    active = np.zeros((B,), bool)
+    tokens[1, 0], positions[1], tables[1], active[1] = (
+        int(tok), prompt.size, row, True)
+    for _ in range(7):
+        tok, lp, eng.cache.pools, aux = eng._decode(
+            eng._dp, *eng.cache.pools, tokens, positions, tables, active)
+        tok, lp = np.asarray(tok), np.asarray(lp)
+        assert tok.shape == (B,) and tok.dtype == np.int32
+        assert lp.shape == (B, VOCAB)
+        np.testing.assert_array_equal(tok, np.argmax(lp, axis=-1) + 1)
+        tokens[1, 0] = tok[1]
+        positions[1] += 1
+    eng.cache.free_seq(-3)
+    assert _pools_clean(eng.cache)
+
+
+def test_scheduler_pulls_once_a_step_tokens_and_counts_together(model):
+    """A decode step of the family that counts brings ``4 x max_batch +
+    12`` bytes across in ONE pull (its counts had a round trip of their
+    own before), a prefill one int32 and the same 12."""
+    from bigdl_tpu import telemetry
+    from bigdl_tpu.analysis import hostsync
+    eng = engine(model, deadline_ms=60000)
+    eng.warmup()
+    before = telemetry.counter("LM/decode_pulled_bytes").value
+    d2h = telemetry.counter("LM/d2h_bytes").value
+    pulls = hostsync.STATS.explicit_pulls
+    eng.start()
+    try:
+        streams = [eng.submit(tokens_of(51, 9), max_new_tokens=6),
+                   eng.submit(tokens_of(52, 15), max_new_tokens=4)]
+        for s in streams:
+            s.result(timeout=120)
+    finally:
+        eng.stop(grace=0.0)
+    st = eng.stats()
+    per_step = 4 * eng.max_batch + 12
+    assert st["decode_steps"] > 0 and st["prefills"] == 2
+    assert st["decode_pulled_bytes"] == st["decode_steps"] * per_step
+    assert telemetry.counter("LM/decode_pulled_bytes").value - before == \
+        st["decode_pulled_bytes"]
+    assert hostsync.STATS.explicit_pulls - pulls == st["decode_steps"] + 2
+    assert telemetry.counter("LM/d2h_bytes").value - d2h == \
+        st["decode_pulled_bytes"] + 2 * (4 + 12)
+    assert st["moe_assignments"] > 0 and st["attn_selected_tokens"] > 0
+    # offline generate() pulls its tokens and leaves the counts where
+    # they are
+    d2h = telemetry.counter("LM/d2h_bytes").value
+    ref = engine(model)
+    assert ref.generate(tokens_of(51, 9), max_new_tokens=6) == \
+        streams[0].result()
+    assert telemetry.counter("LM/d2h_bytes").value - d2h == \
+        4 + 5 * 4 * ref.max_batch
+    assert ref.stats()["decode_pulled_bytes"] == 0
 
 
 def test_paged_selection_is_the_references(model):

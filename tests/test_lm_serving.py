@@ -222,9 +222,12 @@ def _one_decode_step(eng, fn, dp, prompt, slot):
         active = np.zeros((B,), bool)
         tokens[slot, 0], positions[slot] = tok, len(prompt)
         tables[slot], active[slot] = row, True
-        lp, eng.cache.pools, _ = fn(dp, *eng.cache.pools, tokens, positions,
-                                    tables, active)
-        return tok, np.asarray(lp)[slot]
+        chosen, lp, eng.cache.pools, _ = fn(dp, *eng.cache.pools, tokens,
+                                            positions, tables, active)
+        lp = np.asarray(lp)
+        # the step's own choice is the arg-max of the row it returns
+        assert int(np.asarray(chosen)[slot]) == int(np.argmax(lp[slot])) + 1
+        return tok, lp[slot]
     finally:
         eng.cache.free_seq(seq)
 
@@ -376,7 +379,7 @@ class TestBoundedFetch:
 
         def step(tables):
             tokens = np.ones((B, 1), np.int32)
-            lp, eng.cache.pools, _ = eng._decode(
+            _, lp, eng.cache.pools, _ = eng._decode(
                 eng._dp, *eng.cache.pools, tokens, positions, tables, active)
             return np.asarray(lp)[1]
 
@@ -467,6 +470,173 @@ class TestBoundedFetch:
         _zero_retraces(eng)
         assert len(eng.timings["lm_decode_int8"]) == 1
         eng.close()
+
+
+# ---------------------------------------------------------------------------
+# the steps choose their token on the device; the scheduler pulls only that
+# ---------------------------------------------------------------------------
+
+def _tier(eng):
+    return ((eng._decode_q, eng._dp_q) if eng._dp_q is not None
+            else (eng._decode, eng._dp))
+
+
+class TestDeviceChoosesToken:
+    @pytest.mark.parametrize("quantize", ["off", "int8"])
+    def test_step_token_is_the_argmax_of_its_own_row(self, quantize):
+        """A run of decode steps with live and idle slots side by side,
+        each fed the step's own tokens: ``tok`` is ``argmax(lp) + 1`` of
+        the ``lp`` the same call returns, bit for bit, in every row (an
+        idle slot's junk row included), on the fp and the int8 tier."""
+        eng = _engine(quantize=quantize)
+        fn, dp = _tier(eng)
+        B, MB = eng.max_batch, eng._max_blocks
+        live = {0: _prompt(5, seed=1), 2: _prompt(11, seed=2)}
+        tokens = np.ones((B, 1), np.int32)
+        positions = np.zeros((B,), np.int32)
+        tables = np.full((B, MB), DUMP_BLOCK, np.int32)
+        active = np.zeros((B,), bool)
+        for slot, prompt in live.items():
+            eng.cache.allocate(-100 - slot, len(prompt) + 8)
+            tok, row = eng._prefill_step_raw(-100 - slot, prompt)
+            tokens[slot, 0], positions[slot] = tok, len(prompt)
+            tables[slot], active[slot] = row, True
+        seen = set()
+        for step in range(7):
+            if step == 4:
+                active[2] = False         # a slot falls idle mid-run
+            tok, lp, eng.cache.pools, aux = fn(
+                dp, *eng.cache.pools, tokens, positions, tables, active)
+            assert aux is None
+            tok, lp = np.asarray(tok), np.asarray(lp)
+            assert tok.shape == (B,) and tok.dtype == np.int32
+            assert lp.shape == (B, VOCAB) and lp.dtype == np.float32
+            np.testing.assert_array_equal(tok, np.argmax(lp, axis=-1) + 1)
+            tokens[active, 0] = tok[active]
+            positions[active] += 1
+            seen.update(tok[active].tolist())
+        assert len(seen) > 1                  # not one token all along
+        for slot in live:
+            eng.cache.free_seq(-100 - slot)
+        _zero_retraces(eng)
+        eng.close()
+
+    def test_prefill_token_is_the_argmax_of_its_own_row(self):
+        eng = _engine()
+        prompt = _prompt(9, seed=4)
+        eng.cache.allocate(-7, 10)
+        padded = np.ones((1, eng._prefill_bucket(9)), np.int32)
+        padded[0, :9] = prompt
+        row = np.full((eng._max_blocks,), DUMP_BLOCK, np.int32)
+        blocks = eng.cache.table(-7)
+        row[:len(blocks)] = blocks
+        tok, lp, eng.cache.pools, _ = eng._prefill(
+            eng._dp, *eng.cache.pools, padded, np.int32(9), row)
+        assert np.asarray(tok).shape == () and np.asarray(lp).shape == (VOCAB,)
+        assert int(tok) == int(np.argmax(np.asarray(lp))) + 1
+        eng.cache.free_seq(-7)
+        eng.cache.allocate(-8, 10)
+        assert eng._prefill_step_raw(-8, prompt)[0] == int(tok)
+        eng.cache.free_seq(-8)
+        eng.close()
+
+    @pytest.mark.parametrize("rows", [
+        [[0.0, -1.0, 0.5, 0.5, -2.0, 0.5]],               # three-way tie
+        [[-3.0] * 6],                                     # all equal
+        [[-np.inf, -np.inf, -1.0, -np.inf, -1.0, -np.inf],
+         [-np.inf] * 6],                                  # ties at -inf
+    ], ids=["tie", "flat", "minus_inf"])
+    def test_first_index_wins_an_exact_tie(self, rows):
+        """The one place every family's step gets its token, over a stub
+        step: first index on a tie, as ``np.argmax`` on the host had it."""
+        from bigdl_tpu.serving.lm import _choosing
+        lp = np.asarray(rows, np.float32)
+
+        def stub(dp, pool, x):
+            return x, (pool,), None
+
+        tok, out, pools, aux = jax.jit(_choosing(stub))(None, np.zeros(2),
+                                                        lp)
+        np.testing.assert_array_equal(np.asarray(tok),
+                                      np.argmax(lp, axis=-1) + 1)
+        assert np.asarray(tok).dtype == np.int32 and aux is None
+        np.testing.assert_array_equal(np.asarray(out), lp)
+        assert len(pools) == 1
+
+    def test_scheduler_pulls_max_batch_integers_a_step(self):
+        """One explicit pull a decode step and one a prefill, and what
+        crosses in a decode step is ``4 x max_batch`` bytes: the counter,
+        ``stats()`` and the span's ``bytes`` agree.  ``generate()`` is
+        not the scheduler's and counts nothing."""
+        from bigdl_tpu import telemetry
+        from bigdl_tpu.analysis import hostsync
+        with _engine() as eng:
+            before = telemetry.counter("LM/decode_pulled_bytes").value
+            d2h = telemetry.counter("LM/d2h_bytes").value
+            eng.generate(_prompt(6), max_new_tokens=5)
+            assert eng.stats()["decode_pulled_bytes"] == 0
+            assert telemetry.counter("LM/decode_pulled_bytes").value == before
+            # offline: a scalar from the prefill, max_batch int32 a step
+            assert telemetry.counter("LM/d2h_bytes").value - d2h == \
+                4 + 4 * 4 * eng.max_batch
+            d2h = telemetry.counter("LM/d2h_bytes").value
+            pulls = hostsync.STATS.explicit_pulls
+            eng.start()
+            streams = [eng.submit(_prompt(n, seed=n), max_new_tokens=7)
+                       for n in (4, 9, 13)]
+            for s in streams:
+                s.result(timeout=60)
+            eng.stop()
+            stats = eng.stats()
+            pulls = hostsync.STATS.explicit_pulls - pulls
+        per_step = 4 * eng.max_batch
+        assert stats["decode_steps"] > 0 and stats["prefills"] == 4
+        assert stats["decode_pulled_bytes"] == stats["decode_steps"] * per_step
+        assert telemetry.counter("LM/decode_pulled_bytes").value - before == \
+            stats["decode_pulled_bytes"]
+        assert pulls == stats["decode_steps"] + 3
+        assert telemetry.counter("LM/d2h_bytes").value - d2h == \
+            stats["decode_pulled_bytes"] + 3 * 4
+        waits = [e for e in telemetry.events()
+                 if e.get("name") == "lm/decode_wait"]
+        assert len(waits) == stats["decode_steps"]
+        assert {e["args"]["bytes"] for e in waits} == {per_step}
+
+    @pytest.mark.parametrize("quantize", ["off", "int8"])
+    def test_generate_returns_the_rows_only_when_asked(self, quantize):
+        """(c), (d): the same tokens with and without ``return_logps``,
+        from the scheduler too; each row is bit-equal to the whole
+        ``(max_batch, vocab)`` pull of the same step made by hand (what
+        ``generate()`` pulled on every step before), and the token is its
+        arg-max."""
+        eng = _engine(quantize=quantize)
+        fn, dp = _tier(eng)
+        prompt = _prompt(7, seed=9)
+        toks = eng.generate(prompt, max_new_tokens=9)
+        again, rows = eng.generate(prompt, max_new_tokens=9,
+                                   return_logps=True)
+        assert toks == again and len(rows) == 8
+        assert [int(np.argmax(r)) + 1 for r in rows] == toks[1:]
+        B, MB = eng.max_batch, eng._max_blocks
+        seq = eng._offline_seq_id()
+        eng.cache.allocate(seq, len(prompt) + 9)
+        tok, row = eng._prefill_step_raw(seq, prompt)
+        assert tok == toks[0]
+        for i, want in enumerate(rows):
+            tokens = np.ones((B, 1), np.int32)
+            positions = np.zeros((B,), np.int32)
+            tables = np.full((B, MB), DUMP_BLOCK, np.int32)
+            active = np.zeros((B,), bool)
+            tokens[0, 0], positions[0] = toks[i], len(prompt) + i
+            tables[0], active[0] = row, True
+            _, lp, eng.cache.pools, _ = fn(dp, *eng.cache.pools, tokens,
+                                           positions, tables, active)
+            np.testing.assert_array_equal(np.asarray(lp)[0], want)
+        eng.cache.free_seq(seq)
+        eng.start()
+        assert eng.submit(prompt, max_new_tokens=9).result(timeout=60) == toks
+        eng.close()
+        _zero_retraces(eng)
 
 
 # ---------------------------------------------------------------------------

@@ -206,7 +206,7 @@ def test_lm_scheduler_leaves_iteration_trees_under_a_session(tmp_path):
             assert inside(e, admits), e
     assert sum(e["args"]["admitted"] for e in admits) == 6
     wait = next(e for e in lane if e["name"] == "lm/decode_wait")
-    assert wait["args"]["bytes"] == 4 * VOCAB * 4     # (max_batch, vocab) f32
+    assert wait["args"]["bytes"] == 4 * 4             # (max_batch,) int32
 
     # the self times account for the lane's slice
     own = telemetry.self_times(lane)
@@ -217,7 +217,7 @@ def test_lm_scheduler_leaves_iteration_trees_under_a_session(tmp_path):
     # ... and the same spans are on the profiler's host plane
     host = _host_events(tmp_path)
     assert len(host["lm/decode_wait"]) == by_name["lm/decode_wait"]
-    assert host["lm/decode_wait"][0]["bytes"] == 4 * VOCAB * 4
+    assert host["lm/decode_wait"][0]["bytes"] == 4 * 4
     assert len(host["lm/prefill"]) == 6
 
 
@@ -230,8 +230,9 @@ def test_lm_histograms_count_admissions_and_not_generate():
     assert q.count == 0 and p.count == 0
     h2d = telemetry.counter("LM/h2d_bytes").value
     d2h = telemetry.counter("LM/d2h_bytes").value
-    # generate(): one prefill and three decode steps crossed the boundary
-    assert d2h == (VOCAB + 3 * 4 * VOCAB) * 4
+    # generate(): one prefill's token and three decode steps' tokens
+    # crossed the boundary
+    assert d2h == (1 + 3 * 4) * 4
     assert h2d > 0
     eng.start()
     try:
