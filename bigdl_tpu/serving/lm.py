@@ -10,8 +10,15 @@ else queues.  This module serves tokens the Orca/vLLM way instead:
   ``maxBatch`` decode slots.  Every iteration dispatches ONE fused
   decode step over all occupied slots; sequences that finish (EOS,
   token budget, deadline) vacate their slot and free their KV blocks
-  *that same iteration*, and waiting prompts prefill into the vacancy —
-  no head-of-line blocking behind the longest generation.
+  *the iteration their last token is emitted*, and waiting prompts
+  prefill into the vacancy — no head-of-line blocking behind the
+  longest generation.
+- **One decode step in flight.**  A step chooses its tokens on the
+  device, and they are the next step's input there: the scheduler
+  dispatches step n+1 before it pulls and emits step n, so the host's
+  part of an iteration runs while the device executes a step.  An end
+  the host cannot foresee (EOS, deadline) costs one wasted row of the
+  step already in flight, never a wrong token.
 - **Paged KV cache** (:class:`~bigdl_tpu.serving.kv_cache.PagedKVCache`):
   one fixed device pool of ``(layer, block, block_size, head,
   head_dim)`` K/V blocks sized once at construction (gated by the HBM
@@ -53,7 +60,7 @@ import threading
 import time
 from collections import deque
 from contextlib import nullcontext
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Dict, List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -628,15 +635,22 @@ def _choosing(step):
     engine dispatches it: ``-> (tok, lp, pools, aux)``, where ``tok`` is the
     greedy token of every row of ``lp`` (1-based, the first index on a tie,
     as ``np.argmax``), chosen on the device from the same float32
-    log-probs.  The scheduler pulls ``tok`` (and ``aux``) and nothing
-    else; ``lp`` stays an output of the same program for who asks for
-    rows (``generate(return_logps=True)``, the int8 gate), and costs one
-    write on the device when nobody does."""
+    log-probs.  A decode step's ``(max_batch, vocab)`` rows give a
+    ``(max_batch, 1)`` column: the shape, dtype and place of the step's own
+    ``tokens`` argument, so the scheduler hands step n's ``tok`` to step
+    n+1 as it is, still on the device, before it has pulled it (an
+    inactive row's entry is the arg-max of that row's junk: in the
+    vocabulary, and written to the dump block).  A prefill's one row gives
+    a scalar.  The scheduler pulls ``tok`` (and ``aux``) and nothing else;
+    ``lp`` stays an output of the same program for who asks for rows
+    (``generate(return_logps=True)``, the int8 gate), and costs one write
+    on the device when nobody does."""
 
     def chosen(dp, *args):
         lp, pools, aux = step(dp, *args)
         with jax.named_scope("greedy"):
-            tok = jnp.argmax(lp, axis=-1).astype(jnp.int32) + 1
+            tok = jnp.argmax(lp, axis=-1, keepdims=lp.ndim > 1)
+            tok = tok.astype(jnp.int32) + 1
         return tok, lp, pools, aux
 
     # the device's module keeps the name a trace knows the step by
@@ -763,20 +777,49 @@ class TokenStream:
 
 
 class _Slot:
-    """One occupied decode slot: the stream plus its device-side cursor
-    (``position`` = the pool position the NEXT fed token writes)."""
+    """One occupied decode slot: the stream plus its cursor, in two halves.
+    What has been DISPATCHED: ``position`` (the pool position the next
+    dispatched token writes) and ``dispatched`` (tokens counted towards
+    ``max_new_tokens``, the prefill's and those of steps still in flight).
+    What has been EMITTED: ``generated`` and ``last_token``.  They differ
+    by the one step the scheduler keeps in flight."""
 
-    __slots__ = ("stream", "position", "generated", "last_token",
-                 "table_row", "last_emit_ns")
+    __slots__ = ("stream", "position", "dispatched", "generated",
+                 "last_token", "table_row", "last_emit_ns")
 
     def __init__(self, stream: TokenStream, position: int, last_token: int,
                  table_row: np.ndarray):
         self.stream = stream
         self.position = position
-        self.generated = 1          # prefill emitted the first token
+        self.dispatched = 1         # prefill emitted the first token
+        self.generated = 1
         self.last_token = last_token
         self.table_row = table_row
         self.last_emit_ns = telemetry.clock_ns()
+
+    def goes_on(self) -> bool:
+        """Whether another step is to be dispatched for the sequence: an
+        end by length is known before the step that reaches it runs."""
+        return self.dispatched < self.stream.max_new_tokens
+
+    def token_on_host(self) -> bool:
+        return self.dispatched == self.generated
+
+
+class _Flight(NamedTuple):
+    """A decode step that was dispatched and whose tokens have not been
+    pulled: the step's outputs still on the device, the host arrays it was
+    built from, and WHICH STREAM each active row was dispatched for (the
+    slot may be vacant, or another stream's, by the time the row is
+    emitted)."""
+
+    step: int
+    t0: int
+    toks: Any
+    aux: Any
+    rows: List[Tuple[int, TokenStream]]
+    positions: np.ndarray
+    active: np.ndarray
 
 
 # ---------------------------------------------------------------------------
@@ -891,6 +934,10 @@ class LMServingEngine:
         # scheduler thread, so a stream popped from the queue must never
         # live only in a local — _shed_active covers this field
         self._admitting: Optional[TokenStream] = None
+        # the decode step dispatched and not yet pulled (the scheduler
+        # thread's alone), and when the last one was pulled
+        self._inflight: Optional[_Flight] = None
+        self._pulled_ns = 0
         self._lock = analysis.make_lock("lm.engine")
         self._payload_acct = _resource_governor.account("lm_admission")
         self._counts: Dict[str, int] = dict.fromkeys(OUTCOMES, 0)  # guarded-by: _lock
@@ -961,7 +1008,15 @@ class LMServingEngine:
                 ("decode_pulled_bytes",
                  "bytes the scheduler pulled from its decode steps: "
                  "max_batch int32 tokens a step, and the step's three "
-                 "counts in the family that counts"))}
+                 "counts in the family that counts"),
+                ("decode_steps_ahead",
+                 "decode steps dispatched while the previous step's tokens "
+                 "were not yet on the host (their tokens argument is that "
+                 "step's output, still on the device)"),
+                ("decode_rows_wasted",
+                 "rows a decode step computed for a sequence that had "
+                 "ended (eos, deadline, eviction) after the step was "
+                 "dispatched; their tokens are dropped"))}
         telemetry.gauge(
             "LM/cache_bytes_per_token",
             help="bytes the pools hold for one token, all layers").set(
@@ -1466,9 +1521,20 @@ class LMServingEngine:
     # -- the scheduler thread --------------------------------------------
 
     def _any_active(self) -> bool:
-        return any(s is not None for s in self._slots)
+        """A slot is live or a step is in flight: a decode pass has work."""
+        return (self._inflight is not None or
+                any(s is not None for s in self._slots))
 
     def _scheduler_loop(self) -> None:
+        """The scheduler thread.  A pass with work is an admission pass
+        (when something waits) and one :meth:`_decode_iteration`, which
+        keeps ONE decode step in flight: in a run of decode steps the host
+        holds the tokens of step n-1 and the device runs step n while the
+        pass dispatches step n+1.  The host holds every live slot's token
+        only at the first step, after an idle wait, and after an admission
+        pass that prefilled.  The loop ends when nothing waits, no slot is
+        live and no step is in flight; every failure sheds the slots and
+        the step in flight together (:meth:`_shed_active`)."""
         telemetry.name_thread("lm-scheduler")
         wd = None
         if self.stall_factor > 0:
@@ -1519,7 +1585,8 @@ class LMServingEngine:
                     with (telemetry.span("lm/iteration",
                                          step=self.decode_steps + 1,
                                          active=n_active)
-                          if n_active or waiting else nullcontext()):
+                          if waiting or self._any_active()
+                          else nullcontext()):
                         if waiting:
                             self._admit_waiting(wd)
                         active = self._any_active()
@@ -1612,11 +1679,13 @@ class LMServingEngine:
     def _shed_active(self, error: Exception, reason: str,
                      cool: bool = False) -> None:
         """Fail every in-flight sequence with the diagnosis and free
-        its blocks.  Each victim gets its OWN exception instance —
-        concurrent ``result()`` raises on a shared object would
-        interleave tracebacks across client threads."""
+        its blocks; the decode step in flight goes with them, unpulled
+        (its tokens belong to the streams failed here).  Each victim gets
+        its OWN exception instance — concurrent ``result()`` raises on a
+        shared object would interleave tracebacks across client threads."""
         failed = 0
         first_trace: Optional[str] = None
+        self._inflight = None
         # the failure may be a dispatch that consumed the pools and never
         # wrote them back; everything holding a block is freed below
         doomed = [s.stream.seq_id for s in self._slots if s is not None]
@@ -1809,15 +1878,47 @@ class LMServingEngine:
         return int(tok), table_row
 
     def _decode_iteration(self, wd) -> None:
-        """ONE fused decode step over every occupied slot — the
-        continuous-batching heartbeat.  The step chooses every slot's
-        token on the device; ONE pull brings the ``max_batch`` int32 (and
-        the step's counts, where the family counts) to the host, and the
-        ``(max_batch, vocab)`` log-probs never cross.  Finished sequences
-        vacate their slot and free their blocks before the next admission
-        pass."""
-        from bigdl_tpu.analysis.hostsync import host_pull
+        """One pass of the continuous-batching heartbeat, with ONE decode
+        step kept in flight.  In a run of decode steps the pass (1)
+        dispatches step n+1, whose ``tokens`` argument is step n's ``tok``
+        output as it lies on the device, (2) pulls step n's tokens (and
+        counts), which waits only for what is left of step n, and (3)
+        emits them and finishes, vacates and frees what ended: building,
+        dispatching, emitting and the loop's own work all run while the
+        device executes a step.
+
+        Nothing is ahead when the host holds the token of a slot that goes
+        on: at the first step, after an idle wait, and after an admission
+        pass that prefilled (the prefill was pulled, so the step in flight
+        has finished too and is retired first).  That step is built from
+        the host's tokens and left in flight; the next pass dispatches
+        ahead of it.  So a step dispatched ahead only ever carries
+        sequences that continue, and host and device tokens never mix."""
+        prev = self._inflight
+        if prev is not None and any(s.token_on_host()
+                                    for _, s in self._going()):
+            self._inflight = None
+            self._retire(prev, wd)
+            prev = None
+        self._inflight = self._dispatch_decode(prev)
+        if prev is not None:
+            self._retire(prev, wd)
+
+    def _going(self) -> List[Tuple[int, _Slot]]:
+        """The live slots whose sequence goes on, with their index."""
+        return [(i, s) for i, s in enumerate(self._slots)
+                if s is not None and s.goes_on()]
+
+    def _dispatch_decode(self, prev: Optional[_Flight]) -> Optional[_Flight]:
+        """Dispatch ONE fused decode step over every slot whose sequence
+        goes on, and advance what those slots have dispatched; None when
+        no sequence goes on.  ``prev`` is the step still in flight, whose
+        ``tok`` output is this step's ``tokens`` (every slot that goes on
+        has its row there), or None: the tokens are the host's."""
         from bigdl_tpu.utils import chaos
+        going = self._going()
+        if not going:
+            return None
         self.decode_steps += 1
         step = self.decode_steps
         telemetry.counter("LM/decode_steps").inc()
@@ -1830,7 +1931,8 @@ class LMServingEngine:
                 # and _admitting only — a stream finished before its
                 # slot clears is a guarded no-op for the sweep, but a
                 # slot cleared before the finish would strand the stream
-                # unaccounted forever
+                # unaccounted forever.  (Its row of the step in flight is
+                # dropped at emission; the scrub runs after that step.)
                 slot = self._slots[victim]
                 self._finish_stream(slot.stream, "shed",
                                     error=ServingInfraError(
@@ -1839,19 +1941,19 @@ class LMServingEngine:
                                     reason="evicted")
                 self._slots[victim] = None
                 self.cache.free_seq(slot.stream.seq_id)
-            if not self._any_active():
-                return
+                going = self._going()
+                if not going:
+                    return None
         t0 = telemetry.clock_ns()
         B, MB = self.max_batch, self._max_blocks
         with telemetry.span("lm/decode_build"):
-            tokens = np.ones((B, 1), np.int32)
+            tokens = np.ones((B, 1), np.int32) if prev is None else prev.toks
             positions = np.zeros((B,), np.int32)
             tables = np.full((B, MB), DUMP_BLOCK, np.int32)
             active = np.zeros((B,), bool)
-            for i, slot in enumerate(self._slots):
-                if slot is None:
-                    continue
-                tokens[i, 0] = slot.last_token
+            for i, slot in going:
+                if prev is None:
+                    tokens[i, 0] = slot.last_token
                 positions[i] = slot.position
                 tables[i] = slot.table_row
                 active[i] = True
@@ -1860,30 +1962,53 @@ class LMServingEngine:
         with telemetry.span("lm/decode_dispatch"), self._lock:
             toks, _, self.cache.pools, aux = fn(
                 dp, *self.cache.pools, tokens, positions, tables, active)
-        # the device time still to run plus one round trip for what
-        # crosses: max_batch int32, and three more where the step counts
-        nbytes = _pulled_nbytes((toks, aux))
+        for _, slot in going:
+            slot.position += 1
+            slot.dispatched += 1
+        self._h2d.inc((tokens.nbytes if prev is None else 0)
+                      + positions.nbytes + tables.nbytes + active.nbytes)
+        if prev is not None:
+            self._count("decode_steps_ahead", 1)
+        return _Flight(step, t0, toks, aux,
+                       [(i, slot.stream) for i, slot in going],
+                       positions, active)
+
+    def _retire(self, flight: _Flight, wd) -> None:
+        """Pull a dispatched step's tokens, count it, emit it.  The step
+        chose every slot's token on the device; ONE pull brings the
+        ``max_batch`` int32 (and the step's counts, where the family
+        counts) to the host, and the ``(max_batch, vocab)`` log-probs never
+        cross.  The wait is the device time still to run (what is left of
+        the step when the next one was dispatched ahead of it) plus one
+        round trip."""
+        from bigdl_tpu.analysis.hostsync import host_pull
+        nbytes = _pulled_nbytes((flight.toks, flight.aux))
         with telemetry.span("lm/decode_wait", bytes=nbytes):
-            toks, aux = host_pull((toks, aux), what="lm decode tokens")
+            toks, aux = host_pull((flight.toks, flight.aux),
+                                  what="lm decode tokens")
         read = self._count_aux(aux)
-        self._h2d.inc(tokens.nbytes + positions.nbytes + tables.nbytes
-                      + active.nbytes)
         self._d2h.inc(nbytes)
         self._count("decode_pulled_bytes", nbytes)
         # every active slot's context, from the host's positions, and the
         # rows of it attention read: the device's own count where the
         # family selects, else all of them
+        positions, active = flight.positions, flight.active
         context = int(positions[active].astype(np.int64).sum()
                       + active.sum())
         self._count("attn_context_tokens", context)
         self._count("attn_selected_tokens",
                     context if read is None else read)
         self._count("kv_rows_fetched", self.graph.rows_fetched(
-            positions, active, self.block_size, MB))
+            positions, active, self.block_size, self._max_blocks))
         now = telemetry.clock_ns()
-        with telemetry.span("lm/emit", tokens=int(active.sum())):
-            self._emit_tokens(toks, t0, now, step)
-        ms = (telemetry.clock_ns() - t0) / 1e6
+        with telemetry.span("lm/emit", tokens=len(flight.rows)):
+            wasted = self._emit_tokens(flight, toks, now)
+        if wasted:
+            self._count("decode_rows_wasted", wasted)
+        # one iteration, pull to pull; from its own build where the step
+        # was not dispatched ahead (an idle wait or a prefill lay between)
+        ms = (now - max(flight.t0, self._pulled_ns)) / 1e6
+        self._pulled_ns = now
         self._ema.observe(ms)
         if wd is not None:
             wd.heartbeat()
@@ -1892,27 +2017,34 @@ class LMServingEngine:
                 self._cooldown -= 1
         telemetry.gauge("LM/decode_ms").set(ms)
         telemetry.gauge("LM/slot_occupancy").set(
-            sum(s is not None for s in self._slots) / max(1, B))
+            sum(s is not None for s in self._slots) / max(1, self.max_batch))
 
-    def _emit_tokens(self, toks: np.ndarray, t0: int, now: int,
-                     step: int) -> None:
-        """The per-slot half of a decode iteration: the slot's entry of
-        the step's ``(max_batch,)`` tokens, emit, and for a sequence that
-        ends here finish, vacate and free."""
-        for i, slot in enumerate(self._slots):
-            if slot is None:
+    def _emit_tokens(self, flight: _Flight, toks: np.ndarray,
+                     now: int) -> int:
+        """The per-row half of retiring a step: each row goes to the
+        stream it was dispatched for, if that stream still holds the slot;
+        emit, and for a sequence that ends here finish, vacate and free.
+        A row whose sequence ended after the dispatch (on its ``eos_id``,
+        on its deadline, evicted) is dropped, whoever holds the slot now;
+        returns how many were.  The blocks an end frees are scrubbed after
+        the step already in flight, which may still write one position of
+        them: freed blocks stay zero."""
+        wasted = 0
+        col = toks[:, 0]
+        for i, stream in flight.rows:
+            slot = self._slots[i]
+            if slot is None or slot.stream is not stream:
+                wasted += 1
                 continue
-            stream = slot.stream
-            tok = int(toks[i])
-            slot.position += 1
+            tok = int(col[i])
             slot.generated += 1
             slot.last_token = tok
             self._itl.observe((now - slot.last_emit_ns) / 1e6)
             slot.last_emit_ns = now
             stream._emit(tok)
             request_trace.record_span(stream.trace_id,
-                                      "request/decode_step", t0, now,
-                                      step=step, token=tok)
+                                      "request/decode_step", flight.t0, now,
+                                      step=flight.step, token=tok)
             telemetry.counter("LM/tokens").inc()
             self.tokens_out += 1
             if ((stream.eos_id is not None and tok == stream.eos_id) or
@@ -1937,6 +2069,7 @@ class LMServingEngine:
                     reason="expired")
                 self._slots[i] = None
                 self.cache.free_seq(stream.seq_id)
+        return wasted
 
     # -- offline generation (parity + baseline) ---------------------------
 
@@ -2004,7 +2137,7 @@ class LMServingEngine:
                 pulled = (tok, lp if return_logps else None)
                 self._d2h.inc(_pulled_nbytes(pulled))
                 tok, lp = host_pull(pulled, what="lm offline decode token")
-                out_tokens.append(int(tok[0]))
+                out_tokens.append(int(tok[0, 0]))
                 if return_logps:
                     logps.append(lp[0])
                 position += 1

@@ -288,7 +288,7 @@ def test_prefill_then_paged_decode_matches_the_reference(model, reference):
                 eng._dp, *eng.cache.pools, tokens, positions, np.stack(rows),
                 np.ones((B,), bool))
         lp = np.asarray(lp)
-        np.testing.assert_array_equal(np.asarray(tok),
+        np.testing.assert_array_equal(np.asarray(tok)[:, 0],
                                       np.argmax(lp, axis=-1) + 1)
         made, _, read = np.asarray(aux).tolist()
         assert made == 2 * 2 * 4          # 2 tokens, top-2, 4 layers
@@ -336,10 +336,10 @@ def test_step_token_is_the_argmax_of_its_own_row(model):
         tok, lp, eng.cache.pools, aux = eng._decode(
             eng._dp, *eng.cache.pools, tokens, positions, tables, active)
         tok, lp = np.asarray(tok), np.asarray(lp)
-        assert tok.shape == (B,) and tok.dtype == np.int32
+        assert tok.shape == (B, 1) and tok.dtype == np.int32
         assert lp.shape == (B, VOCAB)
-        np.testing.assert_array_equal(tok, np.argmax(lp, axis=-1) + 1)
-        tokens[1, 0] = tok[1]
+        np.testing.assert_array_equal(tok[:, 0], np.argmax(lp, axis=-1) + 1)
+        tokens[1] = tok[1]
         positions[1] += 1
     eng.cache.free_seq(-3)
     assert _pools_clean(eng.cache)
@@ -464,6 +464,67 @@ def test_generate_and_submit_agree_with_the_reference(model, reference):
 
 def _pools_clean(cache):
     return all(bool((np.asarray(p)[:, 1:] == 0).all()) for p in cache.pools)
+
+
+def _serve_by_hand(eng, arrivals, limit=300):
+    """The scheduler's passes made by hand on an engine that was not
+    started (so the schedule is the test's): ``arrivals[pass number]`` are
+    submitted before that pass; the streams in order of arrival."""
+    streams = []
+    for n in range(limit):
+        for kw in arrivals.get(n, ()):
+            streams.append(eng.submit(**kw))
+        if (n > max(arrivals) and eng._q.empty() and not eng._pending
+                and not eng._any_active()):
+            return streams
+        if eng._pending or not eng._q.empty():
+            eng._admit_waiting(None)
+        if eng._any_active():
+            eng._decode_iteration(None)
+    raise AssertionError(f"still busy after {limit} passes")
+
+
+@pytest.mark.parametrize("slots", [2, 1], ids=["two_slots", "one_slot"])
+def test_streams_served_a_step_ahead_are_generates(model, slots):
+    """The family's decode step takes the previous step's ``tok`` from
+    the device as the other family's does: over admissions in mid-run,
+    ends by length and an end on ``eos_id`` (whose row in the step in
+    flight is dropped, and with one slot the slot's next stream never
+    sees it) every stream is ``generate()``'s, the counts are still those
+    of the live rows' steps, and both pools are scrubbed."""
+    ref = engine(model, max_batch=slots)
+    plan = [(0, 13, 9, None), (0, 7, 8, 2), (5, 10, 6, None),
+            (12, 5, 2, None), (14, 9, 1, None)]
+    arrivals, want = {}, []
+    for at, n, new, eos_from in plan:
+        prompt = tokens_of(60 + n, n)
+        toks = ref.generate(prompt, max_new_tokens=new)
+        eos = None
+        if eos_from is not None:
+            cut = next(i for i in range(eos_from, new)
+                       if toks[i] not in toks[:i])
+            assert cut < new - 1
+            eos = toks[cut]
+        want.append(ref.generate(prompt, max_new_tokens=new, eos_id=eos))
+        arrivals.setdefault(at, []).append(
+            dict(prompt=prompt, max_new_tokens=new, eos_id=eos))
+    eng = engine(model, max_batch=slots, deadline_ms=60000)
+    eng.warmup()
+    streams = _serve_by_hand(eng, arrivals)
+    assert [s.result(timeout=1) for s in streams] == want
+    st = eng.stats()
+    assert st["completed"] == len(plan) and st["unaccounted"] == 0
+    assert st["decode_steps_ahead"] > st["decode_steps"] // 2
+    assert st["decode_rows_wasted"] == 1
+    assert st["decode_pulled_bytes"] == st["decode_steps"] * (
+        4 * eng.max_batch + 12)
+    # the step's own count of the rows read covers the wasted row too
+    # (it was live on the device); the host's context count as well
+    assert st["attn_selected_tokens"] >= TOPK * (
+        sum(len(w) - 1 for w in want))
+    assert all(s.retraces == 0 for s in eng.sentinels.values())
+    assert eng._inflight is None and eng.cache.used_blocks == 0
+    assert _pools_clean(eng.cache)
 
 
 def test_both_pools_are_scrubbed_and_fully_aliased(model):

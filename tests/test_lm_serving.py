@@ -226,7 +226,7 @@ def _one_decode_step(eng, fn, dp, prompt, slot):
                                             positions, tables, active)
         lp = np.asarray(lp)
         # the step's own choice is the arg-max of the row it returns
-        assert int(np.asarray(chosen)[slot]) == int(np.argmax(lp[slot])) + 1
+        assert int(np.asarray(chosen)[slot, 0]) == int(np.argmax(lp[slot])) + 1
         return tok, lp[slot]
     finally:
         eng.cache.free_seq(seq)
@@ -509,12 +509,14 @@ class TestDeviceChoosesToken:
                 dp, *eng.cache.pools, tokens, positions, tables, active)
             assert aux is None
             tok, lp = np.asarray(tok), np.asarray(lp)
-            assert tok.shape == (B,) and tok.dtype == np.int32
+            # a column: the shape of the step's own ``tokens`` argument
+            assert tok.shape == (B, 1) and tok.dtype == np.int32
             assert lp.shape == (B, VOCAB) and lp.dtype == np.float32
-            np.testing.assert_array_equal(tok, np.argmax(lp, axis=-1) + 1)
-            tokens[active, 0] = tok[active]
+            np.testing.assert_array_equal(tok[:, 0],
+                                          np.argmax(lp, axis=-1) + 1)
+            tokens[active] = tok[active]
             positions[active] += 1
-            seen.update(tok[active].tolist())
+            seen.update(tok[active, 0].tolist())
         assert len(seen) > 1                  # not one token all along
         for slot in live:
             eng.cache.free_seq(-100 - slot)
@@ -557,7 +559,7 @@ class TestDeviceChoosesToken:
 
         tok, out, pools, aux = jax.jit(_choosing(stub))(None, np.zeros(2),
                                                         lp)
-        np.testing.assert_array_equal(np.asarray(tok),
+        np.testing.assert_array_equal(np.asarray(tok)[:, 0],
                                       np.argmax(lp, axis=-1) + 1)
         assert np.asarray(tok).dtype == np.int32 and aux is None
         np.testing.assert_array_equal(np.asarray(out), lp)
@@ -1154,6 +1156,271 @@ class TestDonatedPools:
             assert (np.asarray(again.k) == 0).all()
         finally:
             config.clear_property("bigdl.compile.cacheDir")
+
+
+# ---------------------------------------------------------------------------
+# one decode step in flight (ISSUE 32): step n+1 is dispatched with step
+# n's tokens still on the device, and emitted one pass later
+# ---------------------------------------------------------------------------
+
+def _pass(eng):
+    """One pass of ``_scheduler_loop`` that has work, made by hand on an
+    engine that was not started: the schedule is then the test's, not the
+    thread's."""
+    if eng._pending or not eng._q.empty():
+        eng._admit_waiting(None)
+    if eng._any_active():
+        eng._decode_iteration(None)
+
+
+def _serve_by_hand(eng, arrivals, limit=400):
+    """Submit ``arrivals[pass number]`` (lists of ``submit()`` keywords)
+    before that pass and run passes until nothing is left; the streams in
+    order of arrival."""
+    streams = []
+    for n in range(limit):
+        for kw in arrivals.get(n, ()):
+            streams.append(eng.submit(**kw))
+        if (n > max(arrivals) and eng._q.empty() and not eng._pending
+                and not eng._any_active()):
+            return streams
+        _pass(eng)
+    raise AssertionError(f"still busy after {limit} passes")
+
+
+def _fresh_at(toks, lo):
+    """First index >= ``lo`` whose token the sequence has not held before:
+    as an ``eos_id`` it ends the sequence exactly there."""
+    return next(i for i in range(lo, len(toks)) if toks[i] not in toks[:i])
+
+
+class TestStepInFlight:
+    def test_streams_served_ahead_are_generates(self):
+        """(a): admissions in mid-run, ends by length (one at its prefill),
+        ends on ``eos_id``: every stream is ``generate()``'s, token for
+        token, no step retraces and the freed blocks are zero."""
+        config.set_property("bigdl.analysis.retrace", "strict")
+        ref = _engine()
+        plan = [(0, 5, 12, None), (0, 9, 3, None), (4, 7, 10, 3),
+                (5, 6, 1, None), (9, 4, 2, None), (9, 11, 9, 5),
+                (30, 8, 6, None)]
+        arrivals, want = {}, []
+        for at, n, new, eos_from in plan:
+            prompt = _prompt(n, seed=100 + n)
+            toks = ref.generate(prompt, max_new_tokens=new)
+            eos = (toks[_fresh_at(toks, eos_from)]
+                   if eos_from is not None else None)
+            want.append(ref.generate(prompt, max_new_tokens=new,
+                                     eos_id=eos))
+            arrivals.setdefault(at, []).append(
+                dict(prompt=prompt, max_new_tokens=new, eos_id=eos))
+        assert len(want[2]) < 10 and len(want[5]) < 9     # eos did end them
+        eng = _engine()
+        streams = _serve_by_hand(eng, arrivals)
+        assert [s.result(timeout=1) for s in streams] == want
+        assert all(s.outcome == "completed" for s in streams)
+        stats = eng.stats()
+        _assert_identity(stats)
+        _zero_retraces(eng)
+        assert eng._inflight is None and eng.cache.used_blocks == 0
+        assert _pools_zero_outside_dump(eng.cache)
+        # most steps ran ahead; each eos end left its row in the step in
+        # flight, and that row reached nobody
+        assert stats["decode_steps_ahead"] > stats["decode_steps"] // 2
+        assert stats["decode_rows_wasted"] == 2
+        assert stats["tokens_out"] == sum(len(w) for w in want)
+        ref.close()
+        eng.close()
+
+    def test_threaded_streams_with_late_arrivals_are_generates(self):
+        """(a) through the scheduler thread: a second wave is submitted
+        while the first decodes, and one stream ends on its ``eos_id``."""
+        config.set_property("bigdl.analysis.retrace", "strict")
+        ref = _engine()
+        probe = ref.generate(_prompt(6, seed=2), max_new_tokens=14)
+        eos = probe[_fresh_at(probe, 4)]
+        with _engine() as eng:
+            eng.start()
+            first = [eng.submit(_prompt(6, seed=2), max_new_tokens=14,
+                                eos_id=eos),
+                     eng.submit(_prompt(10, seed=3), max_new_tokens=16)]
+            it = iter(first[1])
+            head = [next(it) for _ in range(3)]        # it is decoding now
+            late = [eng.submit(_prompt(n, seed=n), max_new_tokens=new)
+                    for n, new in ((4, 9), (12, 2), (7, 12))]
+            outs = [s.result(timeout=60) for s in first + late]
+            stats = eng.stats()
+            _zero_retraces(eng)
+        assert outs[1][:3] == head
+        assert outs[0] == ref.generate(_prompt(6, seed=2), max_new_tokens=14,
+                                       eos_id=eos)
+        assert outs[1] == ref.generate(_prompt(10, seed=3), max_new_tokens=16)
+        for (n, new), o in zip(((4, 9), (12, 2), (7, 12)), outs[2:]):
+            assert o == ref.generate(_prompt(n, seed=n), max_new_tokens=new)
+        _assert_identity(stats)
+        assert stats["decode_steps_ahead"] > 0
+        ref.close()
+
+    def test_row_of_an_ended_sequence_never_reaches_the_slots_next_stream(
+            self):
+        """(b): one slot.  A ends on its ``eos_id`` at the emission of step
+        n-1, with step n (A's row live) in flight; B takes the slot at the
+        next pass.  Step n's row is dropped, not handed to B; the counter
+        says one row was wasted; A's blocks, written once more by step n
+        and scrubbed after it, read zero."""
+        ref = _engine(max_batch=1)
+        pa, pb = _prompt(5, seed=41), _prompt(8, seed=42)
+        toks = ref.generate(pa, max_new_tokens=12)
+        at = _fresh_at(toks, 3)
+        eng = _engine(max_batch=1)
+        a = eng.submit(pa, max_new_tokens=12, eos_id=toks[at])
+        b = eng.submit(pb, max_new_tokens=6)
+        for _ in range(40):
+            _pass(eng)
+            if a.done():
+                break
+        assert a.result(timeout=1) == toks[:at + 1]
+        # A is gone, B still waits, and the step dispatched before A's end
+        # was found is in flight with A's row
+        flight = eng._inflight
+        assert flight is not None and flight.rows == [(0, a)]
+        assert eng._slots == [None] and eng.cache.used_blocks == 0
+        assert _pools_zero_outside_dump(eng.cache)    # scrubbed AFTER step n
+        assert not b.tokens()
+        _pass(eng)                    # admits B into the slot, retires step n
+        assert eng._slots[0].stream is b and len(b.tokens()) == 1
+        assert eng.stats()["decode_rows_wasted"] == 1
+        for _ in range(40):
+            if b.done():
+                break
+            _pass(eng)
+        assert b.result(timeout=1) == ref.generate(pb, max_new_tokens=6)
+        stats = eng.stats()
+        assert stats["decode_rows_wasted"] == 1
+        assert stats["tokens_out"] == at + 1 + 6
+        _assert_identity(stats)
+        assert eng._inflight is None and eng.cache.used_blocks == 0
+        assert _pools_zero_outside_dump(eng.cache)
+        ref.close()
+        eng.close()
+
+    @pytest.mark.parametrize("fail_at", [2, 3])
+    def test_failed_step_dispatched_ahead_sheds_every_stream_once(self,
+                                                                  fail_at):
+        """(c): a dispatch that consumed the pools and raised, with the
+        previous step still in flight: every live stream is shed once,
+        nothing stays in flight, the dead pool is rebuilt, the identity
+        holds and the next request completes."""
+        eng = _engine()
+        real, calls = eng._decode, []
+
+        def consume_then_raise(*args):
+            out = real(*args)
+            calls.append(eng._inflight is not None)
+            if len(calls) == fail_at:
+                raise RuntimeError("injected after dispatch")
+            return out
+
+        eng._decode = consume_then_raise
+        eng.start()
+        streams = [eng.submit(_prompt(4, seed=i), max_new_tokens=8)
+                   for i in range(2)]
+        for s in streams:
+            with pytest.raises(ServingInfraError, match="decode failed"):
+                s.result(timeout=30)
+            assert s.outcome == "shed"
+        assert calls[fail_at - 1] is True      # it was dispatched ahead
+        fresh = eng.submit(_prompt(5, seed=3), max_new_tokens=5)
+        assert len(fresh.result(timeout=30)) == 5
+        eng.stop()
+        stats = eng.stats()
+        assert stats["shed"] == 2 and stats["completed"] == 1
+        assert stats["kv_pool_rebuilds"] == 1 and eng._inflight is None
+        assert eng.cache._tables == {} and eng.cache.used_blocks == 0
+        assert _pools_zero_outside_dump(eng.cache)
+        _assert_identity(stats)
+        ref = _engine()
+        assert fresh.result() == ref.generate(_prompt(5, seed=3),
+                                              max_new_tokens=5)
+        ref.close()
+
+    def test_counters_say_which_steps_ran_ahead(self):
+        """(d): a lone two-token request has one decode step and nothing
+        ahead of it.  In a long run every step runs ahead but the first
+        after an idle engine and the first after an admission pass that
+        left a sequence to decode (one that ended at its prefill changes
+        nothing); ``stats()`` and the registry agree."""
+        from bigdl_tpu import telemetry
+        config.set_property("bigdl.analysis.retrace", "strict")
+        eng = _engine()
+        ahead = telemetry.counter("LM/decode_steps_ahead").value
+        wasted = telemetry.counter("LM/decode_rows_wasted").value
+        _serve_by_hand(eng, {0: [dict(prompt=_prompt(5),
+                                      max_new_tokens=2)]})
+        stats = eng.stats()
+        assert stats["decode_steps"] == 1 and stats["decode_steps_ahead"] == 0
+        # idle, then: two arrive together (one restart), one mid-run that
+        # decodes (one more), one mid-run that ends at its prefill (none),
+        # and after the engine ran empty a last one (one more)
+        streams = _serve_by_hand(eng, {
+            0: [dict(prompt=_prompt(5, seed=1), max_new_tokens=20),
+                dict(prompt=_prompt(7, seed=2), max_new_tokens=9)],
+            6: [dict(prompt=_prompt(4, seed=3), max_new_tokens=7)],
+            11: [dict(prompt=_prompt(6, seed=4), max_new_tokens=1)],
+            40: [dict(prompt=_prompt(9, seed=5), max_new_tokens=5)]})
+        assert [len(s.result(timeout=1)) for s in streams] == [20, 9, 7, 1, 5]
+        stats = eng.stats()
+        steps = stats["decode_steps"] - 1
+        assert steps == 19 + 4          # the longest stream, then the last
+        assert stats["decode_steps_ahead"] == steps - 3
+        assert stats["decode_rows_wasted"] == 0
+        assert telemetry.counter("LM/decode_steps_ahead").value - ahead == \
+            stats["decode_steps_ahead"]
+        assert telemetry.counter("LM/decode_rows_wasted").value == wasted
+        _zero_retraces(eng)
+        _assert_identity(stats)
+        eng.close()
+
+    def test_stop_with_a_step_in_flight_drains(self):
+        """(d): ``stop()`` while steps run ahead lets the sequences finish
+        inside the grace period and leaves nothing in flight."""
+        eng = _engine()
+        eng.start()
+        s = eng.submit(_prompt(6, seed=8), max_new_tokens=24)
+        it = iter(s)
+        head = [next(it) for _ in range(3)]
+        eng.stop(grace=20.0)
+        assert s.outcome == "completed" and len(s.result(timeout=1)) == 24
+        assert s.result()[:3] == head
+        stats = eng.stats()
+        assert eng._inflight is None and not eng.scheduler_alive()
+        assert stats["decode_steps_ahead"] >= 20
+        assert eng.cache.used_blocks == 0
+        assert _pools_zero_outside_dump(eng.cache)
+        _assert_identity(stats)
+
+    def test_evicted_row_in_flight_is_dropped(self):
+        """``chaos.evict_block`` sheds a stream whose row is in the step
+        in flight: the row is wasted, every other stream is generate()'s."""
+        config.set_property("bigdl.chaos.evictBlockAt", "4")
+        chaos.install()
+        ref = _engine()
+        eng = _engine()
+        streams = _serve_by_hand(eng, {0: [
+            dict(prompt=_prompt(5, seed=i), max_new_tokens=10)
+            for i in range(3)]})
+        with pytest.raises(ServingInfraError, match="evicted"):
+            streams[0].result(timeout=1)
+        assert len(streams[0].tokens()) == 3     # prefill + steps 1 and 2
+        for i in (1, 2):
+            assert streams[i].result(timeout=1) == ref.generate(
+                _prompt(5, seed=i), max_new_tokens=10)
+        stats = eng.stats()
+        assert stats["decode_rows_wasted"] == 1
+        _assert_identity(stats)
+        assert _pools_zero_outside_dump(eng.cache)
+        ref.close()
+        eng.close()
 
 
 # ---------------------------------------------------------------------------
