@@ -4,15 +4,13 @@ names."""
 
 from __future__ import annotations
 
-from typing import Any, Dict
+from typing import Any, Dict, Optional
 
+from benchmark import ops
 from benchmark.builders.common import install_on_device, n_parameters
 
 
-def build(config: Dict[str, Any], key, on_tpu: bool = True):
-    """``transformer_lm`` at the configuration's sizes, flash attention on
-    where the configuration says so and a TPU runs it (the kernel refuses
-    every other backend, so the CPU rehearsal runs the standard path)."""
+def _model(config: Dict[str, Any], flash: bool):
     import bigdl_tpu.nn as nn
     from bigdl_tpu.models.transformer import transformer_lm
     d = int(config["hidden_size"])
@@ -25,11 +23,44 @@ def build(config: Dict[str, Any], key, on_tpu: bool = True):
                            n_head=int(config["num_attention_heads"]),
                            n_layers=int(config["num_hidden_layers"]),
                            max_len=int(config["max_position_embeddings"]))
-    if config.get("program", {}).get("flash") and on_tpu:
+    if flash and config.get("program", {}).get("flash"):
         for m in model.modules():
             if isinstance(m, nn.MultiHeadAttention):
                 m.flash = True
-    return install_on_device(model, key)
+    return model
+
+
+def build(config: Dict[str, Any], key, on_tpu: bool = True):
+    """``transformer_lm`` at the configuration's sizes, flash attention on
+    where the configuration says so and a TPU runs it (the kernel refuses
+    every other backend, so the CPU rehearsal runs the standard path)."""
+    return install_on_device(_model(config, flash=on_tpu), key)
+
+
+def shape_model(config: Dict[str, Any]):
+    """The model ``build`` makes for a TPU, with no array made: what
+    ``sizing.py`` compiles from shapes."""
+    return _model(config, flash=True)
+
+
+def train_flops_per_token(config: Dict[str, Any], seq_len: int) -> float:
+    """Required operations of forward and backward for one trained token."""
+    return ops.decoder_train_flops_per_token(
+        int(config["hidden_size"]), int(config["ffn_dim"]),
+        int(config["num_hidden_layers"]), int(config["vocab_size"]),
+        int(seq_len))
+
+
+def attention_kernel(config: Dict[str, Any],
+                     mix: Dict[str, Any]) -> Optional[Dict[str, int]]:
+    """The shapes of the flash kernels' calls in a training step, as
+    ``readers/flash_roofline.py`` takes them (it reports nothing where the
+    trace holds no such kernel)."""
+    heads = int(config["num_attention_heads"])
+    return {"batch": int(mix["batch"]), "heads": heads,
+            "seq_len": int(mix["seq_len"]),
+            "head_dim": int(config["hidden_size"]) // heads,
+            "layers": int(config["num_hidden_layers"])}
 
 
 def reference_weights(model) -> Dict[str, Any]:

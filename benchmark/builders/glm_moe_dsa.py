@@ -10,10 +10,14 @@ from __future__ import annotations
 
 from typing import Any, Dict
 
+from benchmark import ops
 from benchmark.builders.common import n_parameters
 
 
-def build(config: Dict[str, Any], key, on_tpu: bool = True):
+def shape_model(config: Dict[str, Any]):
+    """The program's model at the configuration's sizes with no array
+    made (``reset`` makes them): what ``build`` resets and what
+    ``sizing.py`` compiles from shapes."""
     import jax.numpy as jnp
 
     from bigdl_tpu.models.transformer.glm_moe_dsa import glm_moe_dsa_lm
@@ -25,7 +29,7 @@ def build(config: Dict[str, Any], key, on_tpu: bool = True):
                          "and no group limit")
     if int(prog["experts_held"][1]) != int(config["n_routed_experts"]):
         raise ValueError("n_routed_experts is the number of experts held")
-    model = glm_moe_dsa_lm(
+    return glm_moe_dsa_lm(
         vocab_size=config["vocab_size"], hidden_size=config["hidden_size"],
         num_attention_heads=config["num_attention_heads"],
         q_lora_rank=config["q_lora_rank"],
@@ -49,13 +53,50 @@ def build(config: Dict[str, Any], key, on_tpu: bool = True):
         experts_held=tuple(prog["experts_held"]),
         param_dtype=jnp.dtype(prog["param_dtype"]),
         init_depth=prog.get("init_depth"))
+
+
+def build(config: Dict[str, Any], key, on_tpu: bool = True):
+    model = shape_model(config)
     model.reset(key)
-    if "served_logprob_gate" in prog:
+    if "served_logprob_gate" in config["program"]:
         # the one limit of the cell that a narrower precision fails; the
         # role's own check sees only the tokens generate() chose
         from benchmark import served_logprobs
         served_logprobs.gate(model, config, key)
     return model
+
+
+def train_flops_per_token(config: Dict[str, Any], seq_len: int) -> float:
+    """Required operations of forward and backward for one trained token
+    (``ops.latent_moe_train_flops_per_token``; the routed experts' term is
+    the expectation for the experts held here under an even router)."""
+    kinds = list(config["mlp_layer_types"])
+    return ops.latent_moe_train_flops_per_token(
+        int(config["hidden_size"]), int(config["num_attention_heads"]),
+        int(config["kv_lora_rank"]), int(config["qk_nope_head_dim"]),
+        int(config["qk_rope_head_dim"]), int(config["v_head_dim"]),
+        int(seq_len), int(config["vocab_size"]),
+        dense_layers=kinds.count("dense"),
+        dense_ffn=int(config["intermediate_size"]),
+        expert_layers=kinds.count("sparse"),
+        expert_ffn=int(config["moe_intermediate_size"]),
+        experts_per_tok=int(config["num_experts_per_tok"]),
+        experts_held=int(config["program"]["experts_held"][1]),
+        router_width=int(config["program"]["router_width"]),
+        shared_experts=int(config["n_shared_experts"]),
+        q_lora_rank=int(config["q_lora_rank"]),
+        index_topk=int(config["index_topk"]),
+        indexer_layers=list(config["indexer_types"]).count("full"),
+        index_heads=int(config["index_n_heads"]),
+        index_head_dim=int(config["index_head_dim"]))
+
+
+def attention_kernel(config: Dict[str, Any], mix: Dict[str, Any]) -> None:
+    """Latent attention runs no flash kernel (heads of ``qk_nope +
+    qk_rope`` for the scores and ``v_head_dim`` for the values, which the
+    program's kernel does not take): the cell is not listed under
+    ``flash_roofline.train`` (``where_builder_gives`` in its file)."""
+    return None
 
 
 def reference_weights(model) -> Dict[str, Any]:

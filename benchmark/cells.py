@@ -4,7 +4,9 @@ A cell is ``workloads/<cell>.json``; it names its configuration
 (``configs/<config>.json``), its traffic mix (``traffic/<traffic>.json``)
 and its role (``roles/<role>.py``).  A metric is ``metrics/<metric>.json``
 and names its reader (``readers/<reader>.py``) and the roles or cells it
-applies to.  A later PR adds files; nothing here lists them.
+applies to (and, where only some families have what it reads, the
+builder's function that says which).  A later PR adds files; nothing here
+lists them.
 """
 
 from __future__ import annotations
@@ -86,9 +88,21 @@ def load_metrics(cell: Dict[str, Any], end_to_end: bool
 
 
 def metric_applies(metric: Dict[str, Any], cell: Dict[str, Any]) -> bool:
+    """By the cell's role or name; and, for a metric of something only
+    some families have (a kernel), where the configuration's builder says
+    this cell has it: ``where_builder_gives`` names a function of the
+    builder, ``(config, mix) -> shapes or None``.  A cell is listed under
+    a metric only where its reader will find something to read."""
     roles = metric.get("roles", [])
-    return (roles == "*" or cell["role"] in roles
-            or cell["name"] in metric.get("workloads", []))
+    if not (roles == "*" or cell["role"] in roles
+            or cell["name"] in metric.get("workloads", [])):
+        return False
+    ask = metric.get("where_builder_gives")
+    if ask is None:
+        return True
+    config = load_config(cell["config"])
+    return getattr(builder(config["builder"]), ask)(
+        config, cell["mix"]) is not None
 
 
 def module(kind: str, name: str):

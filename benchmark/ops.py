@@ -14,7 +14,7 @@ from __future__ import annotations
 
 import json
 import os
-from typing import Dict, List, Tuple
+from typing import Dict, List, Optional, Tuple
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
 
@@ -74,6 +74,108 @@ def decoder_matmul_params(d: int, ffn: int, n_layers: int,
     """Parameters that sit in a matrix product (no table, no bias, no
     norm): what '6 N' arithmetic should use for N."""
     return n_layers * (4 * d * d + 2 * d * ffn) + d * vocab
+
+
+# ---------------------------------------------------------------------------
+# decoder with latent attention and routed experts (the DeepSeek-V3 block)
+# ---------------------------------------------------------------------------
+
+def causal_keys_per_token(seq_len: int, topk: Optional[int] = None) -> float:
+    """Keys a token attends, averaged over the positions of a sequence:
+    position p (from 0) sees p + 1 keys under the causal mask, (T+1)/2 on
+    average; under a selection of ``topk`` keys it sees min(p + 1, topk)."""
+    if topk is None or seq_len <= topk:
+        return (seq_len + 1) / 2
+    return (topk * (topk + 1) / 2 + (seq_len - topk) * topk) / seq_len
+
+
+def latent_attention_macs_per_token(d: int, heads: int, kv_lora_rank: int,
+                                    qk_nope: int, qk_rope: int, v_head: int,
+                                    keys: float,
+                                    q_lora_rank: Optional[int] = None
+                                    ) -> float:
+    """Forward multiply-adds one token costs in one layer of latent
+    attention, keys and values expanded from the latent once (the cheaper
+    form at training lengths; the absorbed form of a decode step is not
+    counted here).  Queries: ``d x heads (nope + rope)``, or through a
+    low-rank of ``q_lora_rank``.  Keys and values: down to ``kv_lora_rank
+    + rope`` (the rotary key is shared by the heads), up to ``heads (nope
+    + v)``.  Core: ``nope + rope`` a key and head for the scores, ``v`` for
+    the values, over ``keys`` keys.  Output: ``heads v x d``."""
+    qk = qk_nope + qk_rope
+    if q_lora_rank is None:
+        q = d * heads * qk
+    else:
+        q = d * q_lora_rank + q_lora_rank * heads * qk
+    kv = d * (kv_lora_rank + qk_rope) + kv_lora_rank * heads * (qk_nope
+                                                                + v_head)
+    core = keys * heads * (qk + v_head)
+    return q + kv + core + heads * v_head * d
+
+
+def indexer_macs_per_token(d: int, index_heads: int, index_head_dim: int,
+                           query_width: int, seq_len: int) -> float:
+    """Forward multiply-adds of one layer's indexer for one token: its
+    queries from the query latent (``query_width``; the hidden size in a
+    family without a query low-rank), one key and one weight a head from
+    the hidden state, and a score a head for every causal key."""
+    proj = (query_width * index_heads * index_head_dim
+            + d * index_head_dim + d * index_heads)
+    return proj + (seq_len + 1) / 2 * index_heads * index_head_dim
+
+
+def latent_moe_train_flops_per_token(
+        d: int, heads: int, kv_lora_rank: int, qk_nope: int, qk_rope: int,
+        v_head: int, seq_len: int, vocab: int, dense_layers: int,
+        dense_ffn: int, expert_layers: int, expert_ffn: int,
+        experts_per_tok: int, experts_held: int, router_width: int,
+        shared_experts: int = 1, q_lora_rank: Optional[int] = None,
+        index_topk: Optional[int] = None, indexer_layers: int = 0,
+        index_heads: int = 0, index_head_dim: int = 0) -> float:
+    """Operations the forward and backward passes require per trained
+    token of a decoder whose every layer has latent attention and whose
+    FFN is a SwiGLU (three products) in ``dense_layers`` layers and
+    routed experts in ``expert_layers``: three products per forward
+    product, two operations a multiply-add, no recomputation, as
+    :func:`decoder_train_flops_per_token`.  What a family lacks it leaves
+    at the default: ``q_lora_rank`` None (queries straight from the hidden
+    state), ``index_topk`` None (every causal key attended),
+    ``indexer_layers`` 0.
+
+    An expert layer costs the router (``d x router_width``),
+    ``shared_experts`` experts of ``expert_ffn`` for every token, and the
+    routed experts held here: ``experts_per_tok x experts_held /
+    router_width`` a token.  **That term is an expectation** under a
+    router that spreads its choices evenly over ``router_width`` experts,
+    of which this chip holds ``experts_held``; a cell whose router is far
+    from even should read its held share from the program's counters
+    (``experts_held_share``) and say so beside its ``train_mfu``.
+
+    ``vocab`` is the rows of the head held here; the table is a lookup.
+    A selection (``index_topk``) binds only where ``seq_len`` exceeds it;
+    then attention's core runs over the selected keys and each of
+    ``indexer_layers`` layers pays its indexer **forward only**: a hard
+    top-k passes no gradient to the scores that chose it, so the language
+    model's loss requires no backward product of the indexer (a family
+    that trains its indexer by a loss of its own counts that loss's
+    products in a file of its own).  Where the selection does not bind,
+    nothing of the indexer is required work."""
+    binds = index_topk is not None and seq_len > index_topk
+    keys = causal_keys_per_token(seq_len, index_topk if binds else None)
+    attn = latent_attention_macs_per_token(
+        d, heads, kv_lora_rank, qk_nope, qk_rope, v_head, keys, q_lora_rank)
+    expert = 3 * d * expert_ffn
+    moe = (d * router_width + shared_experts * expert
+           + experts_per_tok * experts_held / router_width * expert)
+    macs = ((dense_layers + expert_layers) * attn
+            + dense_layers * 3 * d * dense_ffn + expert_layers * moe
+            + decoder_head_macs_per_token(d, vocab))
+    forward_only = 0.0
+    if binds:
+        forward_only = indexer_layers * indexer_macs_per_token(
+            d, index_heads, index_head_dim,
+            d if q_lora_rank is None else q_lora_rank, seq_len)
+    return 3 * 2 * macs + 2 * forward_only
 
 
 # ---------------------------------------------------------------------------

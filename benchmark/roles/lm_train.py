@@ -1,5 +1,11 @@
-"""Role: train the decoder LM on one chip through
-``Optimizer.create(...).optimize()``.  Set up, check, measure, report."""
+"""Role: train a decoder LM on one chip through
+``Optimizer.create(...).optimize()``.  Set up, check, measure, report.
+
+The role is a kind of run, not a family: the configuration's builder
+answers what belongs to the model (``build``, ``describe``,
+``train_flops_per_token``, ``attention_kernel``), its reference what the
+model should say, and the job names the optimizer
+(``training.optim_method``)."""
 
 from __future__ import annotations
 
@@ -7,7 +13,7 @@ from typing import Any, Dict
 
 import numpy as np
 
-from benchmark import cells, checks, ops, training
+from benchmark import cells, checks, training
 from benchmark.builders.common import seed_key
 from benchmark.traffic import generate
 
@@ -45,12 +51,10 @@ def predict_first_batch(ctx, n_samples: int) -> np.ndarray:
 
 
 def flops_per_step(ctx, global_batch: int) -> float:
-    c, mix = ctx.config, ctx.cell["mix"]
-    per_token = ops.decoder_train_flops_per_token(
-        int(c["hidden_size"]), int(c["ffn_dim"]),
-        int(c["num_hidden_layers"]), int(c["vocab_size"]),
-        int(mix["seq_len"]))
-    return per_token * global_batch * int(mix["seq_len"])
+    seq_len = int(ctx.cell["mix"]["seq_len"])
+    per_token = cells.builder(ctx.config["builder"]).train_flops_per_token(
+        ctx.config, seq_len)
+    return per_token * global_batch * seq_len
 
 
 def run(ctx, make_opt=make_optimizer, first_batch=first_batch,
@@ -94,10 +98,7 @@ def run(ctx, make_opt=make_optimizer, first_batch=first_batch,
         global_batch * int(mix["seq_len"]), "tokens", chips)
     record["checks"]["first_batch_as_predicted"] = (
         "seen" in first and sorted(first["seen"]) == sorted(predicted))
-    record["flash"] = {"batch": int(mix["batch"]),
-                       "heads": int(cfg["num_attention_heads"]),
-                       "seq_len": int(mix["seq_len"]),
-                       "head_dim": int(cfg["hidden_size"])
-                       // int(cfg["num_attention_heads"]),
-                       "layers": int(cfg["num_hidden_layers"])}
+    kernel = builder.attention_kernel(cfg, mix)
+    if kernel is not None:
+        record["flash"] = kernel
     return record

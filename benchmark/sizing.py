@@ -100,19 +100,10 @@ def _load(cell_name: str, overrides):
     return cell, cells.load_config(cell["config"])
 
 
-def _lm_model(config):
-    import bigdl_tpu.nn as nn
-    from bigdl_tpu.models.transformer import transformer_lm
-    model = transformer_lm(int(config["vocab_size"]),
-                           d_model=int(config["hidden_size"]),
-                           n_head=int(config["num_attention_heads"]),
-                           n_layers=int(config["num_hidden_layers"]),
-                           max_len=int(config["max_position_embeddings"]))
-    if config.get("program", {}).get("flash"):
-        for m in model.modules():
-            if isinstance(m, nn.MultiHeadAttention):
-                m.flash = True
-    return model
+def _shape_model(config):
+    """The configuration's model with no array made, from its builder."""
+    from benchmark import cells
+    return cells.builder(config["builder"]).shape_model(config)
 
 
 def _train_step_specs(model, optim_method, inputs, targets, sharding):
@@ -136,9 +127,10 @@ def size_local_train(cell, config, topo) -> dict:
     from jax.sharding import SingleDeviceSharding
 
     import bigdl_tpu.nn as nn
-    import bigdl_tpu.optim as optim
     from bigdl_tpu.dataset import LocalDataSet
     from bigdl_tpu.optim.optimizer import LocalOptimizer
+
+    from benchmark import training
     one = SingleDeviceSharding(topo.devices[0])
     job, mix = cell["job"], cell["mix"]
     if cell["role"] == "image_train":
@@ -150,7 +142,7 @@ def size_local_train(cell, config, topo) -> dict:
         targets = jax.ShapeDtypeStruct((b,), jnp.int32, sharding=one)
         criterion = nn.ClassNLLCriterion()
     else:
-        model = _lm_model(config)
+        model = _shape_model(config)
         b, t = int(mix["batch"]), int(mix["seq_len"])
         inputs = jax.ShapeDtypeStruct((b, t), jnp.int32, sharding=one)
         targets = jax.ShapeDtypeStruct((b, t), jnp.int32, sharding=one)
@@ -158,8 +150,7 @@ def size_local_train(cell, config, topo) -> dict:
                                                 size_average=True)
     opt = LocalOptimizer(model, LocalDataSet([]), criterion)
     opt.set_precision(job.get("precision", "bf16"))
-    method = optim.SGD(float(job["learning_rate"]),
-                       momentum=float(job["momentum"]))
+    method = training.optim_method(job)
     opt.set_optim_method(method)
     with _as_tpu():
         step = opt._build_step()
@@ -182,7 +173,7 @@ def size_serve(cell, config, topo) -> list:
     bs = int(eng.get("block_size", 16))
     MB = -(-ctx // bs)
     n_blocks = B * MB + 1
-    model = _lm_model(config)
+    model = _shape_model(config)
     for m in model.modules():
         if hasattr(m, "flash"):
             m.flash = False          # the server has its own attention
@@ -214,8 +205,11 @@ def size_serve(cell, config, topo) -> list:
     def i32(*shape):
         return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one)
 
-    decode = jax.jit(_lm._build_decode_fn(graph, bs, MB))
-    prefill = jax.jit(_lm._build_prefill_fn(graph, bs))
+    # the pools are donated, as the server's own steps donate them
+    decode = jax.jit(_lm._build_decode_fn(graph, bs, MB),
+                     donate_argnums=(1, 2))
+    prefill = jax.jit(_lm._build_prefill_fn(graph, bs),
+                      donate_argnums=(1, 2))
     out = []
     c = decode.lower(dp, pool, pool, i32(B, 1), i32(B), i32(B, MB),
                      jax.ShapeDtypeStruct((B,), jnp.bool_,

@@ -21,6 +21,61 @@ def test_manifest_agrees_with_the_files():
     assert manifest.build(_manifest()) == _manifest()
 
 
+def test_manifest_check_command_agrees(capsys):
+    assert manifest.main(["--check"]) == 0
+    assert "agrees" in capsys.readouterr().out
+
+
+TRAIN = {"step_ms.train", "held_hbm_gb.train", "data_wait.train",
+         "device_idle.train", "driver_busy.train", "step_trace_s",
+         "step_compile_s"}
+
+
+def _names(cell, end_to_end):
+    return {m["name"] for m in cells.load_metrics(cell, end_to_end)}
+
+
+def test_train_metrics_apply_by_role_whatever_the_configuration():
+    """A train cell of either family the tree has, under a name no metric
+    file lists, reports ``train_mfu`` and every ``*.train`` metric the day
+    it lands: metrics apply by role, so the role stays ``lm_train``.  The
+    kernel's metric is the one that asks the builder: a family whose
+    ``attention_kernel`` is ``None`` is not listed under it, because its
+    traced line could never hold it."""
+    mix = {"batch": 1, "seq_len": 8192}
+    dense = {"name": "a-cell-no-file-names", "role": "lm_train",
+             "config": "opt-6.7b-l2", "mix": mix}
+    latent = dict(dense, config="glm-5.2-l5-e16")
+    for cell in (dense, latent):
+        assert _names(cell, True) == {"train_mfu", "setup_s"}
+    assert _names(dense, False) == TRAIN | {"flash_roofline.train"}
+    assert _names(latent, False) == TRAIN
+    # under a role of its own it would get none of them
+    own = dict(latent, role="another_family_train")
+    assert _names(own, True) == {"setup_s"}
+    assert _names(own, False) == {"step_trace_s", "step_compile_s"}
+
+
+def test_where_builder_gives_narrows_and_never_widens():
+    """The key narrows what ``roles`` or ``workloads`` admit; it admits
+    nothing of its own, and the builder is not asked for a cell the
+    roles already turn away (an image configuration's has no such
+    function)."""
+    metric = {"roles": ["lm_train"], "where_builder_gives":
+              "attention_kernel"}
+    mix = {"batch": 2, "seq_len": 2048}
+    cell = {"name": "c", "role": "lm_train", "config": "opt-6.7b-l2",
+            "mix": mix}
+    assert cells.metric_applies(metric, cell)
+    assert not cells.metric_applies(metric, dict(cell, role="image_train"))
+    assert not cells.metric_applies(
+        metric, dict(cell, config="glm-5.2-l5-e16"))
+    named = {"workloads": ["c"], "where_builder_gives": "attention_kernel"}
+    assert cells.metric_applies(named, dict(cell, role="other"))
+    assert not cells.metric_applies(
+        named, dict(cell, role="other", config="glm-5.2-l5-e16"))
+
+
 def test_contract_limits():
     m = _manifest()
     assert set(m) == {"command", "paths", "run_seconds", "configs",

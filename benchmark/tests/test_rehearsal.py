@@ -28,7 +28,10 @@ def _run(args, devices=1, timeout=900):
 
 @pytest.mark.parametrize("cell,devices,seconds", [
     ("toy-lm-train", 1, 2), ("toy-lm-serve", 1, 3),
-    ("toy-lm-train-dp", 4, 2), ("toy-resnet-train", 1, 6)])
+    ("toy-lm-train-dp", 4, 2), ("toy-resnet-train", 1, 6),
+    # a second family under the same role, as new files only: Adam from
+    # the job, the builder's count, no flash shapes
+    ("toy-glm-train", 1, 2)])
 def test_role_rehearsal(cell, devices, seconds):
     r = _run(["--workload", cell, "--data-dir", TOY, "--allow-cpu",
               "--seed", str(2 ** 31 + 77), "--seconds", str(seconds),

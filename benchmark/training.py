@@ -99,11 +99,32 @@ def run_optimize(opt, window: Window) -> None:
         lg.setLevel(level)
 
 
-def configure(opt, job: Dict[str, Any], trace_dir: Optional[str]) -> None:
+def optim_method(job: Dict[str, Any]):
+    """The optimizer the job names, for the run and for ``sizing.py``
+    alike, so that a plan and a run hold the same slots.  A job trains
+    under SGD with its ``learning_rate`` and ``momentum``, or names
+    ``job["optim_method"] = {"name": "adam", ...}`` with Adam's own
+    hyper-parameters under the names ``bigdl_tpu.optim.Adam`` gives them:
+    one spelling of each, and never both in one job."""
     import bigdl_tpu.optim as optim
+    if "optim_method" not in job:
+        return optim.SGD(float(job["learning_rate"]),
+                         momentum=float(job["momentum"]))
+    both = sorted({"learning_rate", "momentum"} & set(job))
+    if both:
+        raise SystemExit(f"benchmark: the job names optim_method and, "
+                         f"beside it, {both}: which of them would train?")
+    hyper = dict(job["optim_method"])
+    name = hyper.pop("name")
+    if name != "adam":
+        raise SystemExit(f"benchmark: job.optim_method.name is {name!r}; "
+                         "known: 'adam' (SGD is the job without the key)")
+    return optim.Adam(**hyper)
+
+
+def configure(opt, job: Dict[str, Any], trace_dir: Optional[str]) -> None:
     opt.set_precision(job.get("precision", "bf16"))
-    opt.set_optim_method(optim.SGD(float(job["learning_rate"]),
-                                   momentum=float(job["momentum"])))
+    opt.set_optim_method(optim_method(job))
     if trace_dir:
         opt.set_trace_profile(
             trace_dir,
