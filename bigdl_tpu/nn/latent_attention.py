@@ -24,7 +24,8 @@ token a sequence attends what the block table holds):
   selection's mask, so no ``(T, T)`` array is ever whole.  Through the
   block table (decode) the top ``index_topk`` latent rows are gathered
   and ``kv_b`` is absorbed into the query and the output, so no key or
-  value is ever expanded.
+  value is ever expanded; only the live slots select and gather, a few
+  at a time in a device loop.
 
 Weights may be bf16: every product accumulates in float32; norm
 statistics, softmax and the indexer's scores are float32.
@@ -474,59 +475,143 @@ def _attend_own(spec, p, c_q, c_kv, k_rope, positions,
     return _over_key_groups(make, t, *rows)
 
 
+#: live slots one trip of the decode step's latent attention reads (their
+#: index keys, scores and top-k, then their selected latent rows).  The
+#: smallest chunk whose step at 16 live slots is within 3 % of reading
+#: every slot at once: on the v5e, the GLM-5.2 cell's decode step (16
+#: slots, 2,048 of 16,384 rows, five layers) took 12.78 ms reading every
+#: slot, and with chunks of 1 / 2 / 4 / 8 / 16 took 14.75 / 13.99 / 13.30
+#: / 12.63 / 13.21 ms at 16 live slots and 8.10 / 8.14 / 8.62 / 8.32 /
+#: 11.36 at 5 (10.93 reading every slot): a trip costs a sort and a few
+#: small gathers whatever its rows (PERF.md 6, PR 36)
+_SLOT_CHUNK = 8
+
+
+def slot_chunk(batch: int) -> int:
+    """Slots one trip of the decode step's loop over live slots reads."""
+    return min(_SLOT_CHUNK, batch)
+
+
+def live_trips(active, chunk: int, xp=jnp):
+    """Trips of the decode step's loop over live slots: ``ceil(live /
+    chunk)``, at least one.  The step calls it on its ``valid``
+    (``xp=jnp``) and the scheduler on the host copy of the same
+    (``xp=np``), which is how ``LM/kv_rows_fetched`` counts what the
+    device read without pulling anything."""
+    live = xp.sum(active, dtype=xp.int32)
+    return xp.maximum((live + chunk - 1) // chunk, 1)
+
+
+def _select_table(spec, q, w, pool, slot: int, tables, positions, valid):
+    """``(positions, valid)``, each ``(B, K)``: the ``index_topk`` context
+    positions of highest index score among those at or before each
+    sequence's position (all of them while the table holds no more than
+    ``index_topk``).  ``q`` (B, 1, Hi, Di) and ``w`` (B, 1, Hi) are the
+    indexer's queries and head weights; its keys are ``slot`` of the
+    index ``pool``, read through ``tables``."""
+    b = positions.shape[0]
+    bs = pool.shape[2]
+    s_len = tables.shape[1] * bs
+    ctx = jnp.arange(s_len)[None, :]
+    live = (ctx <= positions) & valid                            # (B, S)
+    if s_len <= spec.index_topk:
+        return jnp.broadcast_to(ctx, (b, s_len)), live
+    k_ctx = pool[slot, tables].reshape(b, s_len, -1)
+    scores = jnp.where(live, index_scores(q, k_ctx, w)[:, 0], -jnp.inf)
+    top, idx = jax.lax.top_k(scores, spec.index_topk)
+    return idx, top > -jnp.inf
+
+
+def _attend_rows(spec, pool, layer: int, tables, q_abs, q_rope, chosen,
+                 valid):
+    """``(B, H, kv_lora_rank)`` float32: the latent rows at the context
+    positions ``chosen`` (B, K), read through ``tables``, attended by the
+    absorbed queries ``q_abs`` (B, H, kv_lora_rank) and ``q_rope`` (B, H,
+    rope)."""
+    r, dtype = spec.kv_lora_rank, q_abs.dtype
+    bs = pool.shape[2]
+    with jax.named_scope("latent_gather"):
+        blk = jnp.take_along_axis(tables, chosen // bs, axis=1)
+        rows = pool[layer, blk, chosen % bs]                     # (B, K, row)
+        c_rows = rows[..., :r]
+        kr_rows = rows[..., r:r + spec.qk_rope_head_dim]
+    with jax.named_scope("core"):
+        s = (jnp.einsum("bhr,bkr->bhk", q_abs, c_rows,
+                        preferred_element_type=jnp.float32)
+             + jnp.einsum("bhd,bkd->bhk", q_rope, kr_rows,
+                          preferred_element_type=jnp.float32))
+        s = s / math.sqrt(spec.qk_nope_head_dim + spec.qk_rope_head_dim)
+        s = jnp.where(valid[:, None, :], s, _NEG)
+        a = jax.nn.softmax(s, axis=-1)
+        return jnp.einsum("bhk,bkr->bhr", a.astype(dtype), c_rows,
+                          preferred_element_type=jnp.float32)
+
+
 def _attend_table(spec, p, c_q, positions, cache: LatentCache, layer: int,
-                  sel: Selection):
+                  sel: Selection, valid, index_slot: Optional[int]):
     """One token a sequence attends the rows its selection names, read
-    through the block table; ``kv_b`` absorbed into query and output."""
+    through the block table; ``kv_b`` absorbed into query and output.
+    ``index_slot``: a layer with an indexer chooses those rows itself,
+    from that slot of the index pool; None: ``sel`` names them.
+    ``-> (out (B, 1, d), selection)``.
+
+    Only the live slots (``valid``) read anything.  The projections run
+    on every slot (weight reads, which rows cost nothing); the selection
+    and the attention over the chosen rows run in a device loop over
+    chunks of :func:`slot_chunk` slots, live ones first, for
+    :func:`live_trips` trips: the rows read follow the live slots, in one
+    compiled program.  A slot no trip reaches keeps an empty selection
+    and an output of zeros, which the dump block and the host discard."""
     b = c_q.shape[0]
     h, dn, dv, r = (spec.n_head, spec.qk_nope_head_dim, spec.v_head_dim,
                     spec.kv_lora_rank)
     dtype = p["kv_b"].dtype
-    bs = cache.latent.shape[2]
+    w_kvb = p["kv_b"].reshape(r, h, dn + dv)
     with jax.named_scope("q_latent"):
         q_nope, q_rope = _queries(spec, p, c_q, positions)
-    with jax.named_scope("latent_gather"):
-        blk = jnp.take_along_axis(cache.tables, sel.positions // bs, axis=1)
-        rows = cache.latent[layer, blk, sel.positions % bs]      # (B, K, row)
-        c_rows = rows[..., :r]
-        kr_rows = rows[..., r:r + spec.qk_rope_head_dim]
     with jax.named_scope("core"):
-        w_kvb = p["kv_b"].reshape(r, h, dn + dv)
         q_abs = jnp.einsum("bhd,rhd->bhr", q_nope[:, 0], w_kvb[..., :dn],
-                           preferred_element_type=jnp.float32)
-        s = (jnp.einsum("bhr,bkr->bhk", q_abs.astype(dtype), c_rows,
-                        preferred_element_type=jnp.float32)
-             + jnp.einsum("bhd,bkd->bhk", q_rope[:, 0], kr_rows,
-                          preferred_element_type=jnp.float32))
-        s = s / math.sqrt(dn + spec.qk_rope_head_dim)
-        s = jnp.where(sel.valid[:, None, :], s, _NEG)
-        a = jax.nn.softmax(s, axis=-1)
-        o_lat = jnp.einsum("bhk,bkr->bhr", a.astype(dtype), c_rows,
-                           preferred_element_type=jnp.float32)
-        o = jnp.einsum("bhr,rhd->bhd", o_lat.astype(dtype), w_kvb[..., dn:],
-                       preferred_element_type=jnp.float32)
+                           preferred_element_type=jnp.float32).astype(dtype)
+    choose = index_slot is not None
+    if choose:
+        with jax.named_scope("select"):
+            q_i = _index_queries(spec, sel.wq, sel.c_q, positions)
+    chunk = slot_chunk(b)
+    live = valid[:, 0]
+    # the live slots first, in slot order; the padding (slot b) is read
+    # as the last slot and written nowhere
+    order = jnp.pad(jnp.argsort(~live, stable=True), (0, -b % chunk),
+                    constant_values=b)
+
+    def trip(i, carry):
+        slots = jax.lax.dynamic_slice_in_dim(order, i * chunk, chunk)
+        at = jnp.minimum(slots, b - 1)
+        tables = cache.tables[at]
+        if choose:
+            with jax.named_scope("select"):
+                chosen, ok = _select_table(spec, q_i[at], sel.w[at],
+                                           cache.index, index_slot, tables,
+                                           positions[at], valid[at])
+        else:
+            chosen, ok = sel.positions[at], sel.valid[at]
+        o_lat = _attend_rows(spec, cache.latent, layer, tables, q_abs[at],
+                             q_rope[at, 0], chosen, ok)
+        done = (o_lat, chosen, ok) if choose else (o_lat,)
+        return tuple(buf.at[slots].set(v, mode="drop")
+                     for buf, v in zip(carry, done))
+
+    k = min(spec.index_topk, cache.tables.shape[1] * cache.latent.shape[2])
+    init = (jnp.zeros((b, h, r), jnp.float32),)
+    if choose:
+        init += (jnp.zeros((b, k), jnp.int32), jnp.zeros((b, k), bool))
+    carry = jax.lax.fori_loop(0, live_trips(live, chunk), trip, init)
+    if choose:
+        sel = Selection(positions=carry[1], valid=carry[2])
+    with jax.named_scope("core"):
+        o = jnp.einsum("bhr,rhd->bhd", carry[0].astype(dtype),
+                       w_kvb[..., dn:], preferred_element_type=jnp.float32)
     with jax.named_scope("out"):
-        return matmul(o.reshape(b, 1, h * dv), p["o"])
-
-
-def _select_table(spec, sel: Selection, cache: LatentCache, slot: int,
-                  positions, valid) -> Selection:
-    """The ``index_topk`` context positions of highest index score among
-    those at or before each sequence's position (all of them while the
-    table holds no more than ``index_topk``)."""
-    b = positions.shape[0]
-    bs = cache.index.shape[2]
-    s_len = cache.tables.shape[1] * bs
-    ctx = jnp.arange(s_len)[None, :]
-    live = (ctx <= positions) & valid                            # (B, S)
-    if s_len <= spec.index_topk:
-        return Selection(positions=jnp.broadcast_to(ctx, (b, s_len)),
-                         valid=live)
-    q = _index_queries(spec, sel.wq, sel.c_q, positions)
-    k_ctx = cache.index[slot][cache.tables].reshape(b, s_len, -1)
-    scores = jnp.where(live, index_scores(q, k_ctx, sel.w)[:, 0], -jnp.inf)
-    top, idx = jax.lax.top_k(scores, spec.index_topk)
-    return Selection(positions=idx, valid=top > -jnp.inf)
+        return matmul(o.reshape(b, 1, h * dv), p["o"]), sel
 
 
 def latent_attention(spec: LatentAttentionSpec, p, indexer, x, positions,
@@ -573,20 +658,20 @@ def latent_attention(spec: LatentAttentionSpec, p, indexer, x, positions,
                     s.index_n_heads * s.index_head_dim)
             if cache is not None:
                 cache = cache.put("index", index_slot, k_i)
-        with jax.named_scope("select"):
-            sel = Selection(c_q=c_q, wq=indexer["wq"], k=k_i, w=w_i)
-            if paged:
-                sel = _select_table(s, sel, cache, index_slot, positions,
-                                    valid)
-            elif t > s.index_topk:
-                sel = _select_own(s, sel, positions)
-            else:
-                sel = None        # every earlier key is among the top
+        sel = Selection(c_q=c_q, wq=indexer["wq"], k=k_i, w=w_i)
+        if not paged:
+            with jax.named_scope("select"):
+                if t > s.index_topk:
+                    sel = _select_own(s, sel, positions)
+                else:
+                    sel = None    # every earlier key is among the top
     if paged:
         if sel is None:
             raise ValueError("a layer typed shared needs the selection of "
                              "a full layer below it")
-        out = _attend_table(s, p, c_q, positions, cache, layer, sel)
+        out, sel = _attend_table(s, p, c_q, positions, cache, layer, sel,
+                                 valid,
+                                 None if indexer is None else index_slot)
     else:
         out = _attend_own(s, p, c_q, c_kv, k_rope, positions, sel)
     return out, cache, sel
@@ -621,6 +706,6 @@ class LatentAttention(Module):
 
 __all__ = ["LatentAttention", "LatentAttentionSpec", "LatentCache",
            "RMSNorm", "Selection", "SwiGLU", "index_scores", "kth_largest",
-           "latent_attention", "layer_norm", "matmul", "query_block",
-           "query_blocks",
-           "rms_norm", "rotary_interleaved", "swiglu"]
+           "latent_attention", "layer_norm", "live_trips", "matmul",
+           "query_block", "query_blocks", "rms_norm", "rotary_interleaved",
+           "slot_chunk", "swiglu"]

@@ -267,9 +267,14 @@ class _GlmGraph:
 
     def rows_fetched(self, positions, active, block_size: int,
                      max_blocks: int) -> int:
-        """The selected rows of every slot, live or not."""
-        return len(positions) * min(int(self.spec.attention.index_topk),
-                                    max_blocks * block_size)
+        """Selected rows of one layer the decode step's attention fetches
+        for these (host) arguments: ``index_topk`` (or the whole table,
+        while it holds fewer) for every slot of every trip the step's
+        loop over live slots makes."""
+        from bigdl_tpu.nn.latent_attention import live_trips, slot_chunk
+        chunk = slot_chunk(len(positions))
+        return chunk * int(live_trips(active, chunk, np)) * min(
+            int(self.spec.attention.index_topk), max_blocks * block_size)
 
     def steps(self, block_size: int, max_blocks: int):
         return _build_glm_steps(self, block_size)
@@ -998,7 +1003,9 @@ class LMServingEngine:
                  "rows of one pool and layer the decode steps' attention "
                  "fetched through the block tables: max_batch x what the "
                  "bound let through (whole chunks up to the longest live "
-                 "context), from the host's copy of the step's arguments"),
+                 "context), or under a learned selection the selected "
+                 "rows of the slots the step's loop over live slots "
+                 "read, from the host's copy of the step's arguments"),
                 ("moe_assignments",
                  "routed expert assignments of real tokens, the "
                  "scheduler's prefill and decode steps"),

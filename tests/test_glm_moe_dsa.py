@@ -429,6 +429,99 @@ def test_paged_selection_is_the_references(model):
         np.testing.assert_allclose(shared, out, atol=1e-6)
 
 
+@pytest.fixture(scope="module")
+def wide(model):
+    """An engine of the benchmark cell's 16 slots."""
+    return engine(model, max_batch=16, max_context=32, block_size=4)
+
+
+def _decode_live(eng, tokens, positions, tables, active):
+    """The family's decode step, not donating (the pools stay for the
+    next call), and the selection layer 0 makes inside it from the same
+    inputs."""
+    from bigdl_tpu.serving.lm import _build_glm_steps
+    a, bs = eng.graph.spec.attention, eng.block_size
+
+    def layer0(dp, latent, index, tokens, positions, tables, active):
+        lyr = dp["layers"][0]
+        x = jnp.take(dp["embed"], tokens - 1, axis=0).astype(jnp.float32)
+        blk = jnp.where(active, tables[jnp.arange(tables.shape[0]),
+                                       positions // bs], 0)
+        cache = la.LatentCache(latent, index, blk[:, None],
+                               (positions % bs)[:, None], tables)
+        _, _, sel = la.latent_attention(
+            a, lyr["attn"], lyr["indexer"], la.rms_norm(x, lyr["ln1"], a.eps),
+            positions[:, None], cache, valid=active[:, None])
+        return sel.positions, sel.valid
+
+    args = (eng._dp, *eng.cache.pools, tokens, positions, tables, active)
+    lp, pools, counts = jax.jit(_build_glm_steps(eng.graph, bs)[0])(*args)
+    chosen, ok = jax.jit(layer0)(*args)
+    return (np.asarray(lp), [np.array(p) for p in pools],
+            int(counts[2]), np.asarray(chosen), np.asarray(ok))
+
+
+@pytest.mark.parametrize("live", [(), (0,), (3, 7, 12), tuple(range(16))],
+                         ids=["none", "slot0", "holes", "all"])
+def test_decode_reads_the_live_slots_only(model, reference, wide, live,
+                                          monkeypatch):
+    """The decode step selects and attends for the live slots only, a
+    chunk of them a trip.  Against the same step in one trip over all
+    sixteen slots (every slot read, as before the loop): each live slot's
+    log-probs and selection, and the step's count of rows read, are the
+    same, and the log-probs are the reference's; idle slots write the
+    dump block and nothing else; ``rows_fetched`` on the host copies is
+    the chunk times the trips the step's own function makes."""
+    eng, B, bs, MB = wide, wide.max_batch, wide.block_size, wide._max_blocks
+    tokens = np.ones((B, 1), np.int32)
+    positions = np.zeros((B,), np.int32)
+    tables = np.zeros((B, MB), np.int32)
+    active = np.zeros((B,), bool)
+    seqs = {}
+    for i in live:
+        prompt = tokens_of(100 + i, 9 + 5 * (i % 3))     # all past TOPK
+        eng.cache.allocate(-100 - i, prompt.size + 1)
+        tok, row = eng._prefill_step_raw(-100 - i, prompt)
+        seqs[i] = np.append(prompt, tok)
+        tokens[i, 0], positions[i], tables[i], active[i] = (
+            tok, prompt.size, row, True)
+    try:
+        before = [np.array(p) for p in eng.cache.pools]
+        chunk = la.slot_chunk(B)
+        trips = int(jax.jit(la.live_trips, static_argnums=1)(active, chunk))
+        assert trips == max(1, -(-len(live) // chunk))
+        assert eng.graph.rows_fetched(positions, active, bs, MB) == (
+            chunk * trips * TOPK)
+        lp, pools, read, chosen, ok = _decode_live(
+            eng, tokens, positions, tables, active)
+        monkeypatch.setattr(la, "_SLOT_CHUNK", B)
+        lp1, pools1, read1, chosen1, ok1 = _decode_live(
+            eng, tokens, positions, tables, active)
+    finally:
+        for i in live:
+            eng.cache.free_seq(-100 - i)
+    rows = list(live)
+    assert read == read1 == TOPK * len(live)
+    np.testing.assert_array_equal(ok, ok1)
+    assert (ok.sum(axis=1) == np.where(active, TOPK, 0)).all()
+    np.testing.assert_array_equal(np.where(ok, chosen, -1),
+                                  np.where(ok1, chosen1, -1))
+    np.testing.assert_allclose(lp[rows], lp1[rows], atol=1e-6)
+    for n in {s.size for s in seqs.values()}:
+        group = [i for i in rows if seqs[i].size == n]
+        want = reference(np.stack([seqs[i] for i in group]))[:, -1]
+        np.testing.assert_allclose(lp[group], want, atol=2e-5)
+    # what the step wrote: the live slots' new rows (the same in both),
+    # the dump block (whose junk differs), and nothing else
+    for new, old, new1 in zip(pools, before, pools1):
+        for p in (new, old, new1):
+            p[:, 0] = 0
+        np.testing.assert_array_equal(new, new1)
+        for p in (new, old):
+            p[:, tables[rows, positions[rows] // bs], positions[rows] % bs] = 0
+        np.testing.assert_array_equal(new, old)
+
+
 def test_generate_and_submit_agree_with_the_reference(model, reference):
     eng = engine(model, max_new_tokens=8, deadline_ms=60000)
     eng.warmup()
@@ -615,13 +708,20 @@ def _scopes(lowered):
 
 
 def test_steps_carry_the_layer_scopes(model):
+    """Every scope of a layer's attention in both steps; in decode the
+    selection, the gather and the core run in the loop over live slots,
+    whose body's paths gain ``while/body``."""
     eng = engine(model)
     dec = _scopes(eng._decode_cached.lower(*eng._decode_specs))
     pre = _scopes(eng._prefill_cached.lower(
         *eng._prefill_specs[eng._buckets[-1]]))
+    flat = {s.replace("while/body/", "") for s in dec}
     for scope in ("q_latent", "kv_latent", "indexer", "select",
                   "latent_gather", "core", "out"):
-        assert any(s.startswith(f"layer0/attn/{scope}") for s in dec), scope
+        assert any(s.startswith(f"layer0/attn/{scope}") for s in flat), scope
+    for scope in ("select", "latent_gather", "core"):
+        assert any(s.startswith(f"layer0/attn/while/body/{scope}")
+                   for s in dec), scope
     for scope in ("q_latent", "kv_latent", "indexer", "select", "kv_expand",
                   "core", "out"):
         assert any(s.startswith(f"layer4/attn/{scope}") for s in pre), scope
