@@ -293,6 +293,9 @@ class Optimizer:
     :class:`LocalOptimizer` or the distributed trainer by dataset type.
     """
 
+    #: when the running optimize() was entered, until step 1 dispatches
+    _setup_t0: Optional[int] = None
+
     def __init__(self, model: Module, dataset: AbstractDataSet,
                  criterion: Criterion):
         self.model = model
@@ -454,6 +457,7 @@ class Optimizer:
         resumable marker within ``bigdl.elastic.gracePeriod`` and
         re-raises: the scheduler said leave, not rewind."""
         from bigdl_tpu.utils import config, elastic
+        self._setup_t0 = telemetry.clock_ns()
         retry_times = config.get_int("bigdl.failure.retryTimes", 5)
         base = config.get_float("bigdl.failure.retryTimeInterval", 120.0)
         cap = config.get_float("bigdl.failure.maxRetryInterval", 900.0)
@@ -760,26 +764,32 @@ class Optimizer:
         that the retry loop treats like divergence — restore and retry
         — instead of hanging the driver.  Trainers that cannot
         reproduce their step's argument tuple (no ``_cost_args_fn``)
-        simply compile at step 1 as before."""
+        simply compile at step 1 as before.  It also closes
+        ``optimize()``'s set-up (``Setup/program_ms{entry=optimize}``,
+        span ``driver/setup``)."""
         from bigdl_tpu.utils import compile_cache
         args_fn = getattr(self, "_cost_args_fn", None)
         step = self._step_fn
         target = getattr(step, "__wrapped__", step)
-        if args_fn is None or not isinstance(target,
-                                             compile_cache.CachedStep):
-            return
-        was_warm = target.warm
-        with telemetry.span("driver/compile_warmup"):
-            t0 = telemetry.clock_ns()
-            target.warmup(*args_fn(inputs, targets, hyper, rng))
-            warm_ms = (telemetry.clock_ns() - t0) / 1e6
-        telemetry.gauge("Compile/warmup_ms").set(warm_ms)
-        if not was_warm:
-            logger.info(
-                "Compile warmup complete in %.0f ms: fused step %r "
-                "ready before step 1 (%d cache hit(s), %d fresh "
-                "compile(s))", warm_ms, target.label, target.cache_hits,
-                target.compiles)
+        if args_fn is not None and isinstance(target,
+                                              compile_cache.CachedStep):
+            was_warm = target.warm
+            with telemetry.span("driver/compile_warmup"):
+                t0 = telemetry.clock_ns()
+                target.warmup(*args_fn(inputs, targets, hyper, rng))
+                warm_ms = (telemetry.clock_ns() - t0) / 1e6
+            telemetry.gauge("Compile/warmup_ms").set(warm_ms)
+            if not was_warm:
+                logger.info(
+                    "Compile warmup complete in %.0f ms: fused step %r "
+                    "ready before step 1 (%d cache hit(s), %d fresh "
+                    "compile(s))", warm_ms, target.label, target.cache_hits,
+                    target.compiles)
+        # step 1 dispatches next: optimize()'s own set-up ends here, once
+        # however many attempts it took
+        t_setup, self._setup_t0 = self._setup_t0, None
+        if t_setup is not None:
+            telemetry.setup_done("optimize", "driver/setup", t_setup)
 
     def _params_dead(self) -> bool:
         """True if any live model parameter buffer was donated-and-deleted

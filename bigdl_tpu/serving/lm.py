@@ -862,6 +862,7 @@ class LMServingEngine:
                  prefill_buckets=None,
                  start: bool = False):
         from bigdl_tpu.utils import config
+        t_setup = telemetry.clock_ns()
         self.graph = _graph_of(model)
         self.max_batch = int(max_batch if max_batch is not None else
                              config.get_int("bigdl.lm.maxBatch", 8))
@@ -1048,6 +1049,7 @@ class LMServingEngine:
         self.quantization_report: Optional[Dict[str, Any]] = None
         if self._dp_q is not None:
             self._quantization_gate()
+        telemetry.setup_done("lm_construct", "lm/construct", t_setup)
         if start:
             self.start()
 
@@ -1181,6 +1183,7 @@ class LMServingEngine:
         int8 tier when enabled) so no request ever pays a compile
         against its deadline: ``2 + len(buckets)`` cold compiles, one
         more with int8."""
+        t_setup = telemetry.clock_ns()
         self._decode_cached.warmup(*self._decode_specs)
         self.cache.warmup()
         for specs in self._prefill_specs.values():
@@ -1194,6 +1197,7 @@ class LMServingEngine:
                 "step that does not update the pools in place copies "
                 "them on every call", self.cache.aliased_bytes,
                 self.cache.pool_nbytes)
+        telemetry.setup_done("lm_warmup", "lm/warmup", t_setup)
 
     # -- int8 gate --------------------------------------------------------
 

@@ -73,6 +73,27 @@ def histogram(name, labels=None, summary=False, help="", window=512):
                               help=help, window=window)
 
 
+def observe_interval(name, labels, span_name, t0_ns, t1_ns, help=""):
+    """One interval timed by the caller's two :func:`clock_ns` reads,
+    recorded twice: its milliseconds observed into the histogram
+    ``name{labels}`` whether or not anything is armed (set-up runs before
+    any profiler starts), and the span ``span_name`` added to this
+    thread's lane while spans record.  Returns the milliseconds."""
+    ms = (t1_ns - t0_ns) / 1e6
+    REGISTRY.histogram(name, labels=labels, help=help).observe(ms)
+    add_span(span_name, t0_ns, t1_ns)
+    return ms
+
+
+def setup_done(entry, span_name, t0_ns):
+    """A set-up entry point's own time, from ``t0_ns`` to now:
+    ``Setup/program_ms{entry=...}`` and the span ``span_name``."""
+    return observe_interval(
+        "Setup/program_ms", {"entry": entry}, span_name, t0_ns, clock_ns(),
+        help="milliseconds of a set-up entry point: engine construction, "
+             "engine warm-up, optimize() to step 1's dispatch")
+
+
 def summary_scalars():
     """The one flush path: every chartable ``(tag, value)`` pair."""
     return REGISTRY.summary_scalars()
@@ -86,7 +107,8 @@ __all__ = [
     "self_times",
     # metrics
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "REGISTRY",
-    "counter", "gauge", "histogram", "summary_scalars",
+    "counter", "gauge", "histogram", "observe_interval", "setup_done",
+    "summary_scalars",
     # step stats
     "PARTS", "StepAccount", "WindowedPercentiles", "SlowStepDetector",
     # per-request tracing + incident flight recorder

@@ -17,9 +17,12 @@ the program's own spans for as long as it lasts, with no switch of its
 own.  A span recorded under a session also enters a
 ``jax.profiler.TraceAnnotation`` of the same name and args for its
 duration, so the same interval lands on the host plane of the
-``.xplane.pb``, on the clock the device operations are on — one ``with``
-block, two sinks, no offset arithmetic.  :func:`add_span`,
-:func:`add_span_s` and :func:`instant` write the ring only.
+``.xplane.pb`` — one ``with`` block, two sinks.  The host plane's clock
+is not the device plane's: they lie 0.8-2.2 ms apart, by another offset
+in every trace (PERF.md section 5), so a device gap is laid against a
+span only after aligning the planes on the runtime's enqueue and done
+events (ROADMAP Reach B1).  :func:`add_span`, :func:`add_span_s` and
+:func:`instant` write the ring only.
 
 A span's parent is the enclosing span on the same lane (spans are
 context managers, so containment is exact); :func:`self_times` gives

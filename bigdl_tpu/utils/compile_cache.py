@@ -62,6 +62,8 @@ import threading
 import time
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
+import jax
+
 from bigdl_tpu import telemetry
 from bigdl_tpu.utils import chaos as _chaos
 from bigdl_tpu.visualization.crc32c import crc32c
@@ -416,6 +418,11 @@ class CompileCache:
                 "recompiling", key, type(e).__name__, e)
             return None
 
+    def present(self, key: str) -> bool:
+        """Whether any file of the entry is on disk (a miss that found
+        something to read, as against a key never written)."""
+        return any(os.path.exists(p) for p in self._names(key))
+
     def _count_error(self) -> None:
         self.errors += 1
         telemetry.counter(
@@ -628,6 +635,48 @@ def _signature_hash(args: Tuple) -> str:
     return h.hexdigest()[:16]
 
 
+#: the phases of one executable's way in, in order.  Each is timed by two
+#: clock reads, observed into ``Compile/phase_ms{phase=...}`` (count: the
+#: executables that went through it, sum: their milliseconds; always, for
+#: the profiler is never on during set-up) and, while spans record, added
+#: as ``compile/<phase>`` under the ``compile/<label>`` span.  ``load`` is
+#: an executable taken from a persistent cache instead of compiled: the
+#: store's deserialize-and-load, or a ``lowered.compile()`` that XLA's
+#: persistent compilation cache answered
+PHASES = ("jaxpr", "lower", "key", "read", "load", "compile", "audit",
+          "store")
+
+_PHASE_HELP = ("milliseconds of one phase of a fused step's executable "
+               "(jaxpr, lower, key, read, load, compile, audit, store)")
+
+#: what JAX reports, on the compiling thread, when its persistent
+#: compilation cache answers a ``compile()`` (only then)
+_XLA_RETRIEVAL = "/jax/compilation_cache/cache_retrieval_time_sec"
+_xla = threading.local()
+
+
+def _on_jax_duration(event: str, duration: float, **kw) -> None:
+    if event == _XLA_RETRIEVAL:
+        _xla.retrieved_s = duration
+
+
+jax.monitoring.register_event_duration_secs_listener(_on_jax_duration)
+
+
+def _xla_cache_compile(lowered):
+    """``lowered.compile()``, and the seconds XLA's persistent cache took
+    to retrieve the executable, or ``None`` where the compiler ran."""
+    _xla.retrieved_s = None
+    exe = lowered.compile()
+    return exe, _xla.retrieved_s
+
+
+def _phase(name: str, t0_ns: int, t1_ns: int) -> float:
+    return telemetry.observe_interval("Compile/phase_ms", {"phase": name},
+                                      f"compile/{name}", t0_ns, t1_ns,
+                                      help=_PHASE_HELP)
+
+
 def _spec_of(x):
     """ShapeDtypeStruct mirror of one argument leaf (keeps an explicit
     sharding so AOT bucket variants lower with the placement the
@@ -644,10 +693,11 @@ class CachedStep:
     ``jax.jit`` wrapper the ``untracked-jit`` lint rule allows),
     executed exclusively through explicitly compiled executables.
 
-    Per distinct abstract signature the flow is: lower (trace) → try the
-    persistent cache (verified load + deserialize) → else compile — each
-    phase telemetry-spanned and supervised by the compile watchdog —
-    then store the serialized executable back (single-writer lock).
+    Per distinct abstract signature the flow is: trace to a jaxpr, lower
+    → key → try the persistent cache (verified read, then deserialize
+    and load) → else compile → audit → store the serialized executable
+    back (single-writer lock); each phase timed (:data:`PHASES`) and the
+    slow ones supervised by the compile watchdog.
     After warmup every call is a dictionary lookup plus the executable
     dispatch; nothing ever re-enters jit's implicit trace-and-compile.
 
@@ -688,7 +738,10 @@ class CachedStep:
         self.cache_hits = 0
         self.cache_misses = 0
         #: per-signature provenance: {signature, source: compile|cache,
-        #: trace_ms, compile_ms|load_ms} — the bench leg's raw material
+        #: compile_ms and trace_ms (= jaxpr_ms + lower_ms) | load_ms,
+        #: jaxpr_ms, lower_ms, key_ms, read_ms, store_ms}; a compile
+        #: entry also says whether XLA's persistent cache answered
+        #: (``xla_cache``)
         self.timings: List[Dict[str, Any]] = []
 
     # the MFU probe lowers the step for cost_analysis only — pass through
@@ -807,16 +860,21 @@ class CachedStep:
                      "topology": self.topology}
         timeout = compile_timeout()
 
+        # milliseconds of each phase this executable went through
+        ms = dict.fromkeys(("jaxpr", "lower", "key", "read", "store"), 0.0)
         with telemetry.span(f"compile/{self.label}", signature=sig_hash):
             t0 = telemetry.clock_ns()
             try:
                 with compile_watchdog(self.label, "trace", timeout,
                                       diagnosis):
-                    lowered = self._jitted.lower(*args)
+                    traced = self._jitted.trace(*args)
+                    t1 = telemetry.clock_ns()
+                    lowered = traced.lower()
             except CompileTimeoutError as e:
                 raise self._diagnosed(e, "trace", timeout, diagnosis)
-            trace_ms = (telemetry.clock_ns() - t0) / 1e6
-            telemetry.gauge("Compile/trace_ms").set(trace_ms)
+            t2 = telemetry.clock_ns()
+            ms["jaxpr"] = _phase("jaxpr", t0, t1)
+            ms["lower"] = _phase("lower", t1, t2)
 
             from bigdl_tpu.analysis import hlo_audit
             audit_armed = hlo_audit.armed()
@@ -829,21 +887,23 @@ class CachedStep:
                 # the armed auditor scans the same text); the executable
                 # is only worth serializing (tens of MB for big steps)
                 # when a persistent cache will actually consume it
+                t0 = telemetry.clock_ns()
                 try:
                     with compile_watchdog(self.label, "trace", timeout,
                                           diagnosis):
                         hlo = lowered.as_text()
                 except CompileTimeoutError as e:
                     raise self._diagnosed(e, "trace", timeout, diagnosis)
-            if self._cache is not None:
-                hlo_digest = hashlib.sha256(
-                    hlo.encode("utf-8")).hexdigest()
-                cache_key = CompileCache.entry_key(
-                    self.label, sig_hash, hlo_digest, self.topology,
-                    fingerprint)
-                if use_cache:
-                    exe = self._try_cache_load(cache_key, fingerprint,
-                                               timeout, diagnosis, _se)
+                if self._cache is not None:
+                    hlo_digest = hashlib.sha256(
+                        hlo.encode("utf-8")).hexdigest()
+                    cache_key = CompileCache.entry_key(
+                        self.label, sig_hash, hlo_digest, self.topology,
+                        fingerprint)
+                ms["key"] = _phase("key", t0, telemetry.clock_ns())
+            if cache_key is not None and use_cache:
+                exe = self._try_cache_load(cache_key, fingerprint, timeout,
+                                           diagnosis, _se, ms)
             loaded = exe is not None
             if exe is None:
                 if self._cache is not None:
@@ -853,30 +913,37 @@ class CachedStep:
                     with compile_watchdog(self.label, "compile", timeout,
                                           diagnosis):
                         _chaos.on_compile(self.label)
-                        exe = lowered.compile()
+                        exe, retrieved_s = _xla_cache_compile(lowered)
                 except CompileTimeoutError as e:
                     raise self._diagnosed(e, "compile", timeout, diagnosis)
-                compile_ms = (telemetry.clock_ns() - t1) / 1e6
-                telemetry.gauge("Compile/compile_ms").set(compile_ms)
+                xla_hit = retrieved_s is not None
+                compile_ms = _phase("load" if xla_hit else "compile", t1,
+                                    telemetry.clock_ns())
+                if xla_hit:
+                    telemetry.histogram(
+                        "Compile/xla_cache_read_ms",
+                        help="XLA's persistent compilation cache's own "
+                             "time to retrieve an executable, within a "
+                             "compile() it answered").observe(
+                                 retrieved_s * 1e3)
                 self.compiles += 1
-                self.timings.append({
-                    "signature": sig_hash, "source": "compile",
-                    "trace_ms": round(trace_ms, 3),
-                    "compile_ms": round(compile_ms, 3)})
                 logger.info(
                     "Compiled fused step %r (signature %s): trace "
-                    "%.0f ms, compile %.0f ms%s", self.label, sig_hash,
-                    trace_ms, compile_ms,
+                    "%.0f ms, compile %.0f ms%s%s", self.label, sig_hash,
+                    ms["jaxpr"] + ms["lower"], compile_ms,
+                    " (XLA's persistent cache)" if xla_hit else "",
                     "" if self._cache is None else " — caching")
             audit_summary = None
             if audit_armed and hlo is not None:
                 # audit BEFORE the store: a contract-violating program
                 # must never enter the persistent cache, and the census
                 # rides in the entry manifest for the offline auditor
+                t0 = telemetry.clock_ns()
                 report = hlo_audit.audit_step(
                     self.label, hlo, compiled=exe, contract=self.contract,
                     topology=self.topology)
                 audit_summary = report.census.summary()
+                _phase("audit", t0, telemetry.clock_ns())
                 report.raise_or_warn()
             # HBM preflight BEFORE the first dispatch (and before the
             # store): with bigdl.resources.deviceMemBudgetMB set, a step
@@ -885,10 +952,21 @@ class CachedStep:
             # the driver answers with a microbatch re-plan
             from bigdl_tpu.resources.device import preflight as _preflight
             _preflight(exe, self.label)
-            if (not loaded and self._cache is not None
-                    and cache_key is not None):
+            if not loaded and cache_key is not None:
+                t0 = telemetry.clock_ns()
                 self._store(cache_key, exe, sig_hash, fingerprint, _se,
                             audit=audit_summary)
+                ms["store"] = _phase("store", t0, telemetry.clock_ns())
+            entry = {f"{p}_ms": round(v, 3) for p, v in ms.items()}
+            if loaded:
+                entry.update(signature=cache_key, source="cache")
+            else:
+                entry.update(signature=sig_hash, source="compile",
+                             trace_ms=round(entry["jaxpr_ms"]
+                                            + entry["lower_ms"], 3),
+                             compile_ms=round(compile_ms, 3),
+                             xla_cache=xla_hit)
+            self.timings.append(entry)
         self._mem[key] = exe
         if self.on_executable is not None:
             self.on_executable(exe)
@@ -928,30 +1006,33 @@ class CachedStep:
                           help="fused-step signatures compiled fresh").inc()
 
     def _try_cache_load(self, cache_key: str, fingerprint: Dict[str, str],
-                        timeout: float, diagnosis: Dict[str, Any], _se):
-        """Verified load + deserialize, watchdog-supervised.  EVERY
-        failure mode here — corrupt payload, unpicklable blob, a wedged
-        deserialization aborted by the watchdog — degrades to a fresh
-        compile; a cache can slow a start, never kill one."""
+                        timeout: float, diagnosis: Dict[str, Any], _se,
+                        ms: Dict[str, float]):
+        """Verified read, then deserialize and load, watchdog-supervised;
+        the two phases' milliseconds go into ``ms`` (``read`` only where
+        a file was there to read).  EVERY failure mode here — corrupt
+        payload, unpicklable blob, a wedged deserialization aborted by
+        the watchdog — degrades to a fresh compile; a cache can slow a
+        start, never kill one."""
+        t0 = telemetry.clock_ns()
         data = self._cache.load(cache_key, self.topology, fingerprint)
+        t1 = telemetry.clock_ns()
+        if data is not None or self._cache.present(cache_key):
+            ms["read"] = _phase("read", t0, t1)
         if data is None:
             return None
-        t0 = telemetry.clock_ns()
         try:
-            with telemetry.span(f"compile/cache_load/{self.label}"):
-                with compile_watchdog(self.label, "cache_load", timeout,
-                                      diagnosis):
-                    payload, in_tree, out_tree, device_ids = \
-                        pickle.loads(data)
-                    # bind to the devices the step was compiled for:
-                    # the default is every device of the backend, which
-                    # turns a one-device step into one expecting a
-                    # shard per device
-                    import jax
-                    by_id = {d.id: d for d in jax.devices()}
-                    exe = _se.deserialize_and_load(
-                        payload, in_tree, out_tree,
-                        execution_devices=[by_id[i] for i in device_ids])
+            with compile_watchdog(self.label, "cache_load", timeout,
+                                  diagnosis):
+                payload, in_tree, out_tree, device_ids = pickle.loads(data)
+                # bind to the devices the step was compiled for: the
+                # default is every device of the backend, which turns a
+                # one-device step into one expecting a shard per device
+                import jax
+                by_id = {d.id: d for d in jax.devices()}
+                exe = _se.deserialize_and_load(
+                    payload, in_tree, out_tree,
+                    execution_devices=[by_id[i] for i in device_ids])
         except Exception as e:
             logger.warning(
                 "compile cache: entry %s failed to deserialize (%s: %s) "
@@ -961,11 +1042,8 @@ class CachedStep:
                 "Compile/cache_errors",
                 help="corrupt/torn cache entries skipped").inc()
             return None
-        load_ms = (telemetry.clock_ns() - t0) / 1e6
-        telemetry.gauge("Compile/load_ms").set(load_ms)
+        load_ms = ms["load"] = _phase("load", t1, telemetry.clock_ns())
         self._count_hit()
-        self.timings.append({"signature": cache_key, "source": "cache",
-                             "load_ms": round(load_ms, 3)})
         logger.info(
             "Warm start: fused step %r loaded from the compile cache "
             "in %.0f ms (entry %s) — no XLA compile", self.label,
