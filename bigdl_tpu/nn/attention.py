@@ -78,9 +78,13 @@ def paged_attention(q: jnp.ndarray, fetch, positions: jnp.ndarray,
     :func:`scaled_dot_product_attention` over the unpadded context up to
     the order of the sums: the decode-vs-full-forward parity proof leans
     on this.  A fully masked row (an inactive decode slot) softmaxes to
-    uniform junk rather than NaN; its output is discarded on the host."""
+    uniform junk rather than NaN; its output is discarded on the host.
+
+    Both products take their operands at the chunks' width (``q`` and
+    the probabilities are cast to it) and accumulate in float32; the
+    running softmax and the result are float32."""
     bsz, t, h, dh = q.shape
-    neg_big = jnp.asarray(jnp.finfo(q.dtype).min, q.dtype)
+    neg_big = jnp.asarray(jnp.finfo(jnp.float32).min, jnp.float32)
 
     def body(c, carry):
         m, l, acc = carry
@@ -88,20 +92,23 @@ def paged_attention(q: jnp.ndarray, fetch, positions: jnp.ndarray,
         with jax.named_scope("attn"):
             k_pos = c * chunk + jnp.arange(chunk)
             valid = (k_pos[None, :] <= positions[:, None]) & active[:, None]
-            s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / math.sqrt(dh)
+            s = jnp.einsum("bqhd,bkhd->bhqk", q.astype(k.dtype), k,
+                           preferred_element_type=jnp.float32
+                           ) / math.sqrt(dh)
             s = jnp.where(valid[:, None, None, :], s, neg_big)
             m_new = jnp.maximum(m, jnp.max(s, axis=-1))
             alpha = jnp.exp(m - m_new)
             p = jnp.exp(s - m_new[..., None])
             l = alpha * l + jnp.sum(p, axis=-1)
             acc = (alpha[..., None] * acc
-                   + jnp.einsum("bhqk,bkhd->bhqd", p, v))
+                   + jnp.einsum("bhqk,bkhd->bhqd", p.astype(v.dtype), v,
+                                preferred_element_type=jnp.float32))
         return m_new, l, acc
 
     with jax.named_scope("attn"):
         init = (jnp.full((bsz, h, t), neg_big),
-                jnp.zeros((bsz, h, t), q.dtype),
-                jnp.zeros((bsz, h, t, dh), q.dtype))
+                jnp.zeros((bsz, h, t), jnp.float32),
+                jnp.zeros((bsz, h, t, dh), jnp.float32))
     _, l, acc = lax.fori_loop(0, n_chunks, body, init)
     with jax.named_scope("attn"):
         return jnp.moveaxis(acc / l[..., None], 1, 2)

@@ -25,6 +25,11 @@ else queues.  This module serves tokens the Orca/vLLM way instead:
   preflight budget), a host free-list, and per-sequence block tables.
   Exhaustion is a structured retriable ``Overloaded`` at admission —
   never a device OOM mid-decode.
+- **Held at the width the products read.**  On a TPU at the default
+  matmul precision a float32 product reads its operands as bf16, so
+  ``transformer_lm``'s matrix weights and pools are held in bf16 and
+  multiplied with float32 accumulation: the same precision at half the
+  bytes (:func:`_held_dtype`); float32 elsewhere.
 - **One decode shape.**  The decode step always runs at ``(maxBatch,
   1)`` with inactive slots masked (their scatters land in the reserved
   dump block); prefill pads to a small bucket ladder.  Both compile
@@ -198,21 +203,25 @@ class _LMGraph:
         self.head_dim = int(self.layers[0]["attn"].head_dim)
         self.n_layers = len(self.layers)
         self.max_seq_len = int(pos.max_seq_len)
-
+        #: the width the steps' products read, so the width at which the
+        #: matrix weights and the K and V pools are held
+        self.dtype = _held_dtype(jax.default_backend(),
+                                 jax.config.jax_default_matmul_precision)
 
     # -- what the engine asks of a family --------------------------------
 
     def make_cache(self, n_blocks: int, block_size: int) -> PagedKVCache:
-        """Float32 K and V of every layer behind one block table."""
+        """K and V of every layer behind one block table, at
+        :attr:`dtype`."""
         return PagedKVCache.kv(self.n_layers, self.n_head, self.head_dim,
-                               n_blocks, block_size)
+                               n_blocks, block_size, dtype=self.dtype)
 
     def query_blocks(self, bucket: int) -> int:
         """Query blocks a prefill of ``bucket`` positions attends in."""
         return 1
 
     def params(self) -> Dict[str, Any]:
-        return _extract_params(self)
+        return _extract_params(self, self.dtype)
 
     def rows_fetched(self, positions, active, block_size: int,
                      max_blocks: int) -> int:
@@ -289,14 +298,47 @@ def _graph_of(model):
     return _LMGraph(model)
 
 
-def _linear_entry(weight, bias) -> Dict[str, Any]:
+#: matmul precisions at which a float32 product on a TPU rounds both
+#: operands to bf16 once and accumulates in float32, besides none set
+_ONE_BF16_PASS = ("default", "bfloat16", "fastest")
+
+
+def _held_dtype(backend: str, precision: Optional[str]):
+    """The width at which ``transformer_lm``'s engine holds what its
+    products read (the matrix weights, the K and V pools), from the
+    backend and ``jax_default_matmul_precision`` in force.
+
+    On a TPU at the default precision a float32 ``dot`` rounds both
+    operands to the nearest bf16 and accumulates in float32, so matrix
+    weights held in bf16 give the products the same results at half the
+    bytes read from HBM: bf16.  The K and V pools are held at the same
+    width; their rounding is new only where a product read them at
+    float32, as the decode step's one-row attention products may (XLA can
+    run those on the vector unit), and it is of the size of the default
+    precision's own.  Under a wider precision (``high``, ``highest``, ``float32``,
+    ``tensorfloat32``), or on a backend whose float32 product reads
+    float32 (the CPU), the products read float32, and so the engine
+    holds float32."""
+    one_pass = precision is None or precision.lower() in _ONE_BF16_PASS
+    return jnp.dtype(jnp.bfloat16 if backend == "tpu" and one_pass
+                     else jnp.float32)
+
+
+def _linear_entry(weight, bias, dtype) -> Dict[str, Any]:
+    if dtype is not None and weight.dtype != dtype:
+        weight = weight.astype(dtype)
     return {"w": weight, "b": bias}
 
 
-def _extract_params(graph: _LMGraph) -> Dict[str, Any]:
+def _extract_params(graph: _LMGraph, dtype=None) -> Dict[str, Any]:
     """Snapshot the model's weights into the decode pytree.  Root
     ``.params`` is touched first so lazy init + child adoption happen
-    once; every leaf is then the module's own adopted view."""
+    once; every leaf is then the module's own adopted view, except that
+    the matrix weights (``wq``, ``wk``, ``wv``, ``wo``, the FFN's ``up``
+    and ``down``, the head) are copies at ``dtype`` where it is given
+    and differs (the module's own arrays stay as they are).  The
+    embedding table (a gather), biases and LayerNorm parameters are
+    always the module's own."""
     _ = graph.model.params
     layers = []
     for l in graph.layers:
@@ -305,18 +347,19 @@ def _extract_params(graph: _LMGraph) -> Dict[str, Any]:
             "ln1": {"w": l["ln1"].params["weight"],
                     "b": l["ln1"].params["bias"]},
             "attn": {k: _linear_entry(ap[f"w{k[-1]}"],
-                                      ap[f"b{k[-1]}"] if wb else None)
+                                      ap[f"b{k[-1]}"] if wb else None,
+                                      dtype)
                      for k in ("wq", "wk", "wv", "wo")},
             "ln2": {"w": l["ln2"].params["weight"],
                     "b": l["ln2"].params["bias"]},
             "ffn": {"up": _linear_entry(
                         l["up"].params["weight"],
                         l["up"].params["bias"] if l["up"].with_bias
-                        else None),
+                        else None, dtype),
                     "down": _linear_entry(
                         l["down"].params["weight"],
                         l["down"].params["bias"] if l["down"].with_bias
-                        else None)},
+                        else None, dtype)},
         })
     return {"embed": graph.embed.params["weight"],
             "layers": layers,
@@ -325,7 +368,7 @@ def _extract_params(graph: _LMGraph) -> Dict[str, Any]:
             "head": _linear_entry(
                 graph.head.params["weight"],
                 graph.head.params["bias"] if graph.head.with_bias
-                else None)}
+                else None, dtype)}
 
 
 def _quantize_entry(entry: Dict[str, Any]) -> Dict[str, Any]:
@@ -342,7 +385,9 @@ def _quantize_entry(entry: Dict[str, Any]) -> Dict[str, Any]:
 
 def _quantize_params(dp: Dict[str, Any]) -> Dict[str, Any]:
     """int8-quantize every decode matmul; embeddings (a gather) and the
-    layer norms stay fp."""
+    layer norms stay fp.  The engine hands it the module's own weights
+    (:func:`_extract_params` without a ``dtype``), not the fp steps'
+    copies."""
     layers = []
     for l in dp["layers"]:
         layers.append({
@@ -358,11 +403,15 @@ def _quantize_params(dp: Dict[str, Any]) -> Dict[str, Any]:
 def _apply_linear(x, e):
     """One decode matmul against an fp (``w``) or int8 (``q``/``s``)
     entry — the branch is on pytree STRUCTURE, resolved at trace time,
-    so fp and int8 programs compile under their own labels."""
+    so fp and int8 programs compile under their own labels.  An fp entry
+    takes the activation at the weight's width (bf16 where the engine
+    holds bf16: :func:`_held_dtype`) and accumulates in float32."""
     if "q" in e:
         y = (x @ e["q"].astype(x.dtype)) * e["s"]
     else:
-        y = x @ e["w"]
+        w = e["w"]
+        y = jnp.matmul(x.astype(w.dtype), w,
+                       preferred_element_type=jnp.float32)
     if e.get("b") is not None:
         y = y + e["b"]
     return y
@@ -435,9 +484,11 @@ def _build_decode_fn(graph: _LMGraph, block_size: int, max_blocks: int):
     no copy of a layer), and only as far as the longest live context
     (:func:`_live_chunks` of ``positions`` and ``active``): a loop on the
     device with a running softmax, so one compiled program reads 128 rows
-    a slot or the tables' whole capacity as the traffic asks.  Inactive
-    slots compute junk that scatters into the dump block and is discarded
-    on the host — occupancy can never mint a new signature."""
+    a slot or the tables' whole capacity as the traffic asks.  The step's
+    k/v enter the pools at the pools' width, and the chunks are read at
+    it.  Inactive slots compute junk that scatters into the dump block
+    and is discarded on the host — occupancy can never mint a new
+    signature."""
     from bigdl_tpu.nn.attention import paged_attention
     pe = graph.pos.pe
     vocab_in = int(graph.embed.n_index)
@@ -469,8 +520,10 @@ def _build_decode_fn(graph: _LMGraph, block_size: int, max_blocks: int):
                                .reshape(B, 1, H, Dh)
                                for w in ("wq", "wk", "wv"))
                 with jax.named_scope("kv_scatter"):
-                    pool_k = pool_k.at[li, blk, slot].set(k[:, 0])
-                    pool_v = pool_v.at[li, blk, slot].set(v[:, 0])
+                    pool_k = pool_k.at[li, blk, slot].set(
+                        k[:, 0].astype(pool_k.dtype))
+                    pool_v = pool_v.at[li, blk, slot].set(
+                        v[:, 0].astype(pool_v.dtype))
                 with jax.named_scope("kv_gather"):
                     # the tables in whole chunks (the dump block pads
                     # the last one: every position of it is masked)
@@ -528,8 +581,10 @@ def _build_prefill_fn(graph: _LMGraph, block_size: int):
                                .reshape(1, T, H, Dh)
                                for w in ("wq", "wk", "wv"))
                 with jax.named_scope("kv_scatter"):
-                    pool_k = pool_k.at[li, blkrow, slotrow].set(k[0])
-                    pool_v = pool_v.at[li, blkrow, slotrow].set(v[0])
+                    pool_k = pool_k.at[li, blkrow, slotrow].set(
+                        k[0].astype(pool_k.dtype))
+                    pool_v = pool_v.at[li, blkrow, slotrow].set(
+                        v[0].astype(pool_v.dtype))
                 with jax.named_scope("attn"):
                     att = scaled_dot_product_attention(q, k, v, causal=True)
                 with jax.named_scope("out"):
@@ -925,7 +980,7 @@ class LMServingEngine:
 
         # -- compiled steps + retrace sentinels ---------------------------
         self._dp = self.graph.params()
-        self._dp_q = (_quantize_params(self._dp)
+        self._dp_q = (_quantize_params(_extract_params(self.graph))
                       if self.quantize == "int8" else None)
         self._build_steps()
 
@@ -1042,6 +1097,18 @@ class LMServingEngine:
             "LM/weight_bytes",
             help="bytes of the parameter arrays the steps read").set(
                 self.weight_bytes)
+        # of those, the weights the steps' products read: every array of
+        # two axes or more but the embedding table, which is gathered
+        dots = {id(x): x.nbytes for tree in (self._dp, self._dp_q)
+                if tree is not None
+                for x in jax.tree_util.tree_leaves(
+                    {k: v for k, v in tree.items() if k != "embed"})
+                if x.ndim >= 2}
+        self.dot_weight_bytes = int(sum(dots.values()))
+        telemetry.gauge(
+            "LM/dot_weight_bytes",
+            help="bytes of the weights the steps' products read, at the "
+                 "width the engine holds them").set(self.dot_weight_bytes)
         telemetry.gauge(
             "LM/pool_bytes", help="bytes of the cache's pools, all "
             "kinds").set(self.cache.pool_nbytes)
@@ -1525,6 +1592,7 @@ class LMServingEngine:
         out["cache_bytes_per_token"] = self.cache.bytes_per_token
         out["pool_bytes"] = dict(self.cache.kind_nbytes)
         out["weight_bytes"] = self.weight_bytes
+        out["dot_weight_bytes"] = self.dot_weight_bytes
         for name in self._tally_counters:
             out[name] = tally.get(name, 0)
         return out

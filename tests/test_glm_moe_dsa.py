@@ -758,6 +758,13 @@ def test_engine_holds_the_weights_once_and_the_pools():
     st = eng.stats()
     assert st["weight_bytes"] == weights
     assert st["weight_bytes"] + sum(st["pool_bytes"].values()) == want
+    # of those, what the products read: all but the embedding table and
+    # the vectors (norms, the router's bias), at the bf16 they are held
+    dots = sum(l.nbytes for l in jax.tree_util.tree_leaves(
+        {k: v for k, v in m.params.items() if k != "embed"})
+        if l.ndim >= 2)
+    assert st["dot_weight_bytes"] == dots
+    assert 0 < dots < weights - m.params["embed"].nbytes
     assert m._grads_tree is None
     eng.warmup()
     out = eng.generate(tokens_of(41, 12), max_new_tokens=4)

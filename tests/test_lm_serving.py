@@ -23,8 +23,10 @@ import numpy as np
 import pytest
 
 import jax
+import jax.numpy as jnp
 
 import bigdl_tpu.nn as nn
+from bigdl_tpu.serving import lm as _lm
 from bigdl_tpu.models.transformer import transformer_lm
 from bigdl_tpu.serving import (LMServingEngine, Overloaded, PagedKVCache,
                                QuantizationGateError, UnsupportedModelError,
@@ -974,6 +976,148 @@ class TestInt8Tier:
     def test_unknown_tier_is_refused(self):
         with pytest.raises(ValueError, match="int8"):
             _engine(warm=False, quantize="int4")
+
+
+# ---------------------------------------------------------------------------
+# the width the engine holds its matrix weights and pools at
+# ---------------------------------------------------------------------------
+
+@pytest.fixture
+def bf16_held(monkeypatch):
+    """The engine holds bf16, as it does on a TPU at the default
+    precision."""
+    monkeypatch.setattr(_lm, "_held_dtype",
+                        lambda backend, precision: jnp.dtype(jnp.bfloat16))
+
+
+@pytest.fixture
+def one_bf16_pass(monkeypatch):
+    """Every product at one bf16 pass, the precision a TPU's default
+    gives a float32 ``dot``: float32 operands rounded to the nearest
+    bf16, float32 accumulation (the CPU's own float32 product rounds
+    nothing).  Traces
+    made before or after would not see it, so the jit caches are
+    cleared on both sides."""
+    from jax._src.lax import lax as lax_internal
+    prim = lax_internal.dot_general_p
+    bind = prim.bind
+
+    def one_bf16_pass(x):
+        return (x.astype(jnp.bfloat16).astype(x.dtype)
+                if x.dtype == jnp.float32 else x)
+
+    jax.clear_caches()
+    monkeypatch.setattr(prim, "bind", lambda lhs, rhs, **kw: bind(
+        one_bf16_pass(lhs), one_bf16_pass(rhs), **kw))
+    yield
+    monkeypatch.undo()
+    jax.clear_caches()
+
+
+def _matrix_weights(eng):
+    return [e["w"] for l in eng._dp["layers"]
+            for e in (*l["attn"].values(), *l["ffn"].values())] + [
+        eng._dp["head"]["w"]]
+
+
+class TestHeldWidth:
+    @pytest.mark.parametrize("backend,precision,held", [
+        ("tpu", None, "bfloat16"), ("tpu", "default", "bfloat16"),
+        ("tpu", "bfloat16", "bfloat16"), ("tpu", "fastest", "bfloat16"),
+        ("tpu", "highest", "float32"), ("tpu", "float32", "float32"),
+        ("tpu", "tensorfloat32", "float32"), ("tpu", "high", "float32"),
+        ("cpu", None, "float32"), ("cpu", "bfloat16", "float32"),
+        ("gpu", None, "float32")])
+    def test_rule_of_backend_and_precision(self, backend, precision, held):
+        assert _lm._held_dtype(backend, precision) == jnp.dtype(held)
+
+    def test_graph_asks_with_the_precision_in_force(self, monkeypatch):
+        asked = []
+        monkeypatch.setattr(_lm, "_held_dtype", lambda b, p: asked.append(
+            (b, p)) or jnp.dtype(jnp.float32))
+        m = _model()
+        for precision in ("default", "highest"):
+            with jax.default_matmul_precision(precision):
+                _lm._LMGraph(m)
+        assert asked == [("cpu", "default"), ("cpu", "highest")]
+
+    def test_cpu_holds_the_modules_own_float32_arrays(self):
+        m = _model()
+        eng = _engine(m, warm=False)
+        graph = _lm._LMGraph(m)
+        assert eng.cache.k.dtype == eng.cache.v.dtype == jnp.float32
+        assert eng._dp["head"]["w"] is graph.head.params["weight"]
+        assert all(w.dtype == jnp.float32 for w in _matrix_weights(eng))
+        eng.close()
+
+    @pytest.mark.parametrize("long,n,new", [(False, 9, 12),
+                                            (True, 125, 5)],
+                             ids=["one_chunk", "first_edge"])
+    def test_bf16_steps_are_the_float32_steps_at_one_bf16_pass(
+            self, one_bf16_pass, monkeypatch, long, n, new):
+        """Prefill and decode (``generate``) and the full step
+        (``generate_sequential``): the same tokens as the float32 engine
+        whose products read bf16 operands, log-probs within 1e-5 (so
+        nothing else was rounded: not the residual, the softmax or the
+        log-probs), the pools bf16 after the steps and zero outside the
+        dump block once the sequence is freed."""
+        m, kw = (_model(max_len=512), _LONG) if long else (_model(), {})
+        ref = _engine(m, **kw)
+        monkeypatch.setattr(_lm, "_held_dtype",
+                            lambda b, p: jnp.dtype(jnp.bfloat16))
+        eng = _engine(m, **kw)
+        assert ref.cache.k.dtype == jnp.float32
+        assert all(w.dtype == jnp.bfloat16 for w in _matrix_weights(eng))
+        assert eng._dp["embed"].dtype == jnp.float32
+        for ask in ("generate", "generate_sequential"):
+            want_t, want_lp = getattr(ref, ask)(
+                _prompt(n, seed=5), max_new_tokens=new, return_logps=True)
+            got_t, got_lp = getattr(eng, ask)(
+                _prompt(n, seed=5), max_new_tokens=new, return_logps=True)
+            assert got_t == want_t, ask
+            np.testing.assert_allclose(np.asarray(got_lp),
+                                       np.asarray(want_lp), rtol=0,
+                                       atol=1e-5)
+        assert eng.cache.k.dtype == eng.cache.v.dtype == jnp.bfloat16
+        assert _pools_zero_outside_dump(eng.cache)
+        ref.close()
+        eng.close()
+
+    def test_bf16_halves_the_pools_and_the_weights_products_read(self):
+        m = _model()
+        f32 = _engine(m, warm=False)
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setattr(_lm, "_held_dtype",
+                       lambda b, p: jnp.dtype(jnp.bfloat16))
+            bf16 = _engine(m, warm=False)
+        n = sum(w.size for w in _matrix_weights(f32))
+        a, b = f32.stats(), bf16.stats()
+        assert a["dot_weight_bytes"] == 4 * n
+        assert b["dot_weight_bytes"] == 2 * n
+        assert b["cache_bytes_per_token"] * 2 == a["cache_bytes_per_token"]
+        assert sum(b["pool_bytes"].values()) * 2 == sum(
+            a["pool_bytes"].values())
+        # what LM/weight_bytes counts loses half of every matrix weight
+        assert a["weight_bytes"] - b["weight_bytes"] == 2 * n
+        f32.close()
+        bf16.close()
+
+    def test_int8_tier_quantizes_the_modules_float32_weights(self,
+                                                             bf16_held):
+        m = _model()
+        eng = _engine(m, warm=False, quantize="int8")
+        assert eng.quantization_report["allclose"]
+        want = _lm._quantize_params(_lm._extract_params(_lm._LMGraph(m)))
+        got_leaves, got_tree = jax.tree_util.tree_flatten(eng._dp_q)
+        want_leaves, want_tree = jax.tree_util.tree_flatten(want)
+        assert got_tree == want_tree
+        for g, w in zip(got_leaves, want_leaves):
+            assert g.dtype == w.dtype and np.array_equal(np.asarray(g),
+                                                         np.asarray(w))
+        n = sum(w.size for w in _matrix_weights(eng))
+        # the fp steps' bf16 weights and the int8 tier's bytes, each once
+        assert eng.stats()["dot_weight_bytes"] == 2 * n + n
+        eng.close()
 
 
 # ---------------------------------------------------------------------------
