@@ -226,11 +226,12 @@ class _LMGraph:
     def rows_fetched(self, positions, active, block_size: int,
                      max_blocks: int) -> int:
         """Rows of one pool and layer the decode step's attention fetches
-        for these (host) arguments: every slot's table in whole chunks,
-        as many as the step's own loop makes trips."""
+        for these (host) arguments: a group's tables in whole chunks, for
+        every trip of the step's own loops (:func:`_group_chunks`)."""
         chunk = _chunk_blocks(block_size, max_blocks) * block_size
-        return len(positions) * chunk * int(
-            _live_chunks(positions, active, chunk, np))
+        group = min(_KV_GROUP, len(positions))
+        _, chunks = _group_chunks(positions, active, chunk, group, np)
+        return group * chunk * int(chunks.sum())
 
     def steps(self, block_size: int, max_blocks: int):
         """``(decode, prefill, full)`` in the shape the engine dispatches
@@ -462,15 +463,42 @@ def _chunk_blocks(block_size: int, max_blocks: int) -> int:
     return max(1, min(max_blocks, _KV_CHUNK // block_size))
 
 
-def _live_chunks(positions, active, chunk: int, xp=jnp):
-    """Trips of the decode step's attention loop: whole chunks of
-    ``chunk`` positions up to the longest live context (``pos + 1`` of an
-    active slot), at least one.  The step calls it on its arguments
-    (``xp=jnp``) and the scheduler on the host copies of the same
-    (``xp=np``), which is how ``LM/kv_rows_fetched`` counts what the
-    device read without pulling anything."""
-    longest = xp.max(xp.where(active, positions + 1, 0))
-    return xp.maximum((longest + chunk - 1) // chunk, 1)
+#: slots one trip of the decode step's loop over slot groups reads: the
+#: smallest group whose step at 16 live slots is within 3 % of reading
+#: every slot at once.  On the v5e at the chat cell's widths (16 slots,
+#: every live context at 640 / 1,536) the step read every slot in 2.764 /
+#: 4.133 ms at any occupancy; in groups of 2 / 4 / 8 / 16 it took 3.095 /
+#: 2.897 / 2.816 / 2.776 and 4.820 / 4.415 / 4.232 / 4.135 ms at 16 live
+#: (+12 / +4.8 / +1.9 / +0.4 % at 640), and 1.982 / 2.063 / 2.302 / 2.769
+#: and 2.202 / 2.441 / 3.009 / 4.139 ms at one (PERF.md 6)
+_KV_GROUP = 8
+
+
+def _group_chunks(positions, active, chunk: int, group: int, xp=jnp):
+    """``(order, chunks)``: the trips of the decode step's attention.
+
+    ``order`` is every slot, longest context first (``pos + 1`` of an
+    active slot, 0 of an idle one; ties in slot order), padded to whole
+    groups of ``group`` with ``B``, the slot written nowhere.  ``chunks``
+    has one entry a group: the chunks of ``chunk`` positions its trip
+    reads, enough for its first, and so longest, member; at least one
+    (a trip of none would leave its slots a normaliser of 0) for the
+    groups up to ``ceil(live / group)`` (and the first group when none is
+    live), 0 for the groups no trip reaches.  The step
+    calls it on its arguments (``xp=jnp``) and the scheduler on the host
+    copies of the same (``xp=np``), which is how ``LM/kv_rows_fetched``
+    counts what the device read without pulling anything."""
+    b = positions.shape[0]
+    length = xp.where(active, positions + 1, 0)
+    order = xp.argsort(-length, stable=True)
+    pad = -b % group
+    longest = xp.pad(length[order], (0, pad))[::group]
+    live = xp.sum(active, dtype=xp.int32)
+    trips = xp.maximum((live + group - 1) // group, 1)
+    reached = xp.arange(longest.shape[0]) < trips
+    chunks = xp.where(reached, xp.maximum((longest + chunk - 1) // chunk, 1),
+                      0)
+    return xp.pad(order, (0, pad), constant_values=b), chunks
 
 
 def _build_decode_fn(graph: _LMGraph, block_size: int, max_blocks: int):
@@ -478,17 +506,22 @@ def _build_decode_fn(graph: _LMGraph, block_size: int, max_blocks: int):
     embed + positional row, per layer scatter this step's k/v into the
     paged pool (BEFORE the gather, so the current token attends itself),
     then attention over each sequence's table context, FFN; returns
-    next-token log-probs and the updated pools.  The context is read
-    through the block tables a chunk of :data:`_KV_CHUNK` positions at a
+    next-token log-probs and the updated pools.
+
+    The attention reads the live slots only, in groups of
+    :data:`_KV_GROUP` slots sorted by context length, each group only as
+    far as its own longest context (:func:`_group_chunks` of
+    ``positions`` and ``active``, once a step): a device loop over the
+    groups, each trip :func:`~bigdl_tpu.nn.attention.paged_attention`
+    over its slots' tables a chunk of :data:`_KV_CHUNK` positions at a
     time, straight out of the 5-D pools (layer and block in one gather,
-    no copy of a layer), and only as far as the longest live context
-    (:func:`_live_chunks` of ``positions`` and ``active``): a loop on the
-    device with a running softmax, so one compiled program reads 128 rows
-    a slot or the tables' whole capacity as the traffic asks.  The step's
-    k/v enter the pools at the pools' width, and the chunks are read at
-    it.  Inactive slots compute junk that scatters into the dump block
-    and is discarded on the host — occupancy can never mint a new
-    signature."""
+    no copy of a layer).  So one compiled program reads one group's 128
+    rows a slot or every table's whole capacity as the traffic asks.  A
+    slot no trip reaches attends nothing (zeros: finite, as the dump
+    block it scatters into must stay).  The step's k/v enter the pools
+    at the pools' width, and the chunks are read at it.  Inactive slots
+    compute junk that scatters into the dump block and is discarded on
+    the host — occupancy can never mint a new signature."""
     from bigdl_tpu.nn.attention import paged_attention
     pe = graph.pos.pe
     vocab_in = int(graph.embed.n_index)
@@ -501,6 +534,7 @@ def _build_decode_fn(graph: _LMGraph, block_size: int, max_blocks: int):
 
     def decode(dp, pool_k, pool_v, tokens, positions, tables, active):
         B = tokens.shape[0]
+        G = min(_KV_GROUP, B)
         with jax.named_scope("embed"):
             idx = jnp.clip(tokens.astype(jnp.int32) - 1, 0, vocab_in - 1)
             x = jnp.take(dp["embed"], idx, axis=0)
@@ -510,7 +544,8 @@ def _build_decode_fn(graph: _LMGraph, block_size: int, max_blocks: int):
                                            positions // block_size],
                             DUMP_BLOCK)
             slot = positions % block_size
-            n_chunks = _live_chunks(positions, active, chunk)
+            order, chunks = _group_chunks(positions, active, chunk, G)
+            trips = jnp.sum(chunks > 0)
         for li, lyr in enumerate(dp["layers"]):
             with jax.named_scope(f"layer{li}"):
                 with jax.named_scope("ln1"):
@@ -530,15 +565,25 @@ def _build_decode_fn(graph: _LMGraph, block_size: int, max_blocks: int):
                     padded = jnp.pad(tables, ((0, 0), (0, -max_blocks % cb)),
                                      constant_values=DUMP_BLOCK)
 
-                def fetch(c):
-                    with jax.named_scope("kv_gather"):
-                        part = jax.lax.dynamic_slice_in_dim(
-                            padded, c * cb, cb, axis=1)
-                        return (pool_k[li, part].reshape(B, chunk, H, Dh),
-                                pool_v[li, part].reshape(B, chunk, H, Dh))
+                def trip(g, out):
+                    slots = jax.lax.dynamic_slice_in_dim(order, g * G, G)
+                    at = jnp.minimum(slots, B - 1)
+                    rows = padded[at]
 
-                att = paged_attention(q, fetch, positions, active, n_chunks,
-                                      chunk)
+                    def fetch(c):
+                        with jax.named_scope("kv_gather"):
+                            part = jax.lax.dynamic_slice_in_dim(
+                                rows, c * cb, cb, axis=1)
+                            return (pool_k[li, part].reshape(G, chunk, H, Dh),
+                                    pool_v[li, part].reshape(G, chunk, H, Dh))
+
+                    att = paged_attention(q[at], fetch, positions[at],
+                                          active[at], chunks[g], chunk)
+                    return out.at[slots].set(att, mode="drop")
+
+                with jax.named_scope("attn"):
+                    att = jnp.zeros((B, 1, H, Dh), jnp.float32)
+                att = jax.lax.fori_loop(0, trips, trip, att)
                 with jax.named_scope("out"):
                     x = x + _apply_linear(att.reshape(B, 1, H * Dh),
                                           lyr["attn"]["wo"])
@@ -1057,11 +1102,12 @@ class LMServingEngine:
                  "step's own count under a learned selection, else all"),
                 ("kv_rows_fetched",
                  "rows of one pool and layer the decode steps' attention "
-                 "fetched through the block tables: max_batch x what the "
-                 "bound let through (whole chunks up to the longest live "
-                 "context), or under a learned selection the selected "
-                 "rows of the slots the step's loop over live slots "
-                 "read, from the host's copy of the step's arguments"),
+                 "fetched through the block tables: the slots of a group "
+                 "x the chunks of each trip of its loop over live groups "
+                 "(whole chunks up to the group's longest context), or "
+                 "under a learned selection the selected rows of the "
+                 "slots the step's loop over live slots read, from the "
+                 "host's copy of the step's arguments"),
                 ("moe_assignments",
                  "routed expert assignments of real tokens, the "
                  "scheduler's prefill and decode steps"),

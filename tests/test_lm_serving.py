@@ -330,8 +330,15 @@ class TestDecodeParity:
 
 
 # ---------------------------------------------------------------------------
-# the decode step reads the tables only as far as the longest live context
+# the decode step reads the live slots only, each group of them only as far
+# as the group's own longest context
 # ---------------------------------------------------------------------------
+
+#: slots a trip of the decode step's attention reads
+_G = _lm._KV_GROUP
+#: slots of the engines that hold more than one group
+_WIDE = 16
+
 
 def _chunks_of(eng):
     from bigdl_tpu.serving.lm import _chunk_blocks
@@ -339,42 +346,87 @@ def _chunks_of(eng):
     return cb, cb * eng.block_size
 
 
+def _trips_of(positions, active, chunk, group):
+    """Chunks each trip reads, counted here without the program: the
+    contexts longest first, a group of them a trip, each group as far as
+    its first member, at least one chunk and one trip."""
+    ctx = sorted(np.where(active, positions + 1, 0), reverse=True)
+    trips = max(1, -(-int(active.sum()) // group))
+    return [max(1, -(-int(ctx[t * group]) // chunk)) for t in range(trips)]
+
+
+def _wide_engine(**kw):
+    return _long_engine(max_batch=_WIDE, **kw)
+
+
+def _live_inputs(eng, live, seed=0):
+    """Inputs of one decode step with the slots of ``live`` (slot ->
+    position) active: random tokens, random positions for the idle slots
+    too, every slot's table blocks of its own, and pools of random
+    values."""
+    rng = np.random.default_rng(seed)
+    B, MB = eng.max_batch, eng._max_blocks
+    tokens = rng.integers(1, VOCAB + 1, size=(B, 1)).astype(np.int32)
+    positions = rng.integers(0, MB * eng.block_size, size=B).astype(np.int32)
+    active = np.zeros((B,), bool)
+    for s, p in live.items():
+        positions[s], active[s] = p, True
+    tables = (1 + rng.permutation(B * MB)).reshape(B, MB).astype(np.int32)
+    pools = [rng.standard_normal(p.shape).astype(np.float32)
+             for p in eng.cache.pools]
+    return tokens, positions, tables, active, pools
+
+
 class TestBoundedFetch:
     def test_host_and_device_agree_on_the_trip_count(self):
         """``LM/kv_rows_fetched`` is counted on the host by the function
         the step itself calls on the device: the two agree on every
-        input, no live slot included."""
-        import jax.numpy as jnp
-
-        from bigdl_tpu.serving.lm import _live_chunks
-        on_device = jax.jit(lambda p, a: _live_chunks(p, a, 128))
+        input, no live slot included, and with what is counted here
+        without the program; the order is every slot longest first, ties
+        in slot order, padded with the slot written nowhere."""
+        from bigdl_tpu.serving.lm import _group_chunks
         rng = np.random.default_rng(0)
-        for _ in range(40):
-            positions = rng.integers(0, 2048, size=16).astype(np.int32)
-            active = rng.random(16) < rng.random()
-            want = max(1, -(-int((positions[active] + 1).max(initial=0))
-                            // 128))
-            assert int(_live_chunks(positions, active, 128, np)) == want
-            assert int(on_device(jnp.asarray(positions),
-                                 jnp.asarray(active))) == want
+        for B, group in ((16, 2), (16, _G), (16, 16), (10, 4)):
+            on_device = jax.jit(
+                lambda p, a: _group_chunks(p, a, 128, group))
+            n_groups = -(-B // group)
+            for _ in range(30):
+                positions = rng.integers(0, 2048, size=B).astype(np.int32)
+                active = rng.random(B) < rng.random()
+                want = _trips_of(positions, active, 128, group)
+                want += [0] * (n_groups - len(want))
+                ctx = np.where(active, positions + 1, 0)
+                order = sorted(range(B), key=lambda s: (-ctx[s], s))
+                order += [B] * (n_groups * group - B)
+                for got in (_group_chunks(positions, active, 128, group, np),
+                            on_device(jnp.asarray(positions),
+                                      jnp.asarray(active))):
+                    np.testing.assert_array_equal(np.asarray(got[1]), want)
+                    np.testing.assert_array_equal(np.asarray(got[0]), order)
 
     @pytest.mark.parametrize("n,trips", [(100, 1), (128, 2), (300, 3),
                                          (511, 4)])
     def test_step_reads_the_chunks_the_host_counts_and_no_more(self, n,
                                                                trips):
-        """The step's own trip count, seen from outside: a table entry in
-        a chunk past the bound may point at a block of NaN and nothing
-        changes (never read); one in the last chunk inside the bound,
-        past the slot's own position and so masked, turns the row NaN
-        (read: 0 x NaN).  ``rows_fetched`` counts exactly those chunks."""
-        eng = _long_engine()
+        """The step's own trips, seen from outside.  A group of ``_G``
+        slots at 500 positions reads four chunks; slot 1, at ``n``, heads
+        the next group, which reads its own ``trips``.  A table entry of
+        slot 1 past its group's bound may point at a block of NaN and
+        nothing changes (never read); one in the last chunk inside the
+        bound, past the slot's own position and so masked, turns its row
+        NaN (read: 0 x NaN).  Every table entry of a slot no trip reaches
+        may point at NaN, and every row, its own included, stays finite.
+        ``rows_fetched`` counts exactly those chunks."""
+        eng = _long_engine(max_batch=3 * _G)
         cb, chunk = _chunks_of(eng)
         B, MB = eng.max_batch, eng._max_blocks
+        long_slots = list(range(B - _G, B))
         positions = np.zeros((B,), np.int32)
         active = np.zeros((B,), bool)
+        positions[long_slots], active[long_slots] = 500, True
         positions[1], active[1] = n, True
         assert eng.graph.rows_fetched(positions, active, eng.block_size,
-                                      MB) == B * chunk * trips
+                                      MB) == _G * chunk * (4 + trips)
         poison = eng.cache.n_blocks - 1
         eng.cache.pools = tuple(p.at[:, poison].set(np.nan)
                                 for p in eng.cache.pools)
@@ -383,25 +435,35 @@ class TestBoundedFetch:
             tokens = np.ones((B, 1), np.int32)
             _, lp, eng.cache.pools, _ = eng._decode(
                 eng._dp, *eng.cache.pools, tokens, positions, tables, active)
-            return np.asarray(lp)[1]
+            return np.asarray(lp)
 
         tables = np.full((B, MB), DUMP_BLOCK, np.int32)
-        tables[1, :n // eng.block_size + 1] = \
-            1 + np.arange(n // eng.block_size + 1)
+        for i, s in enumerate(long_slots + [1]):
+            end = positions[s] // eng.block_size + 1
+            tables[s, :end] = 1 + i * MB + np.arange(end)
         clean = step(tables)
         assert np.isfinite(clean).all()
         beyond = tables.copy()
-        beyond[:, trips * cb:] = poison           # every slot's later chunks
+        beyond[1, trips * cb:] = poison           # slot 1's later chunks
         np.testing.assert_array_equal(step(beyond), clean)
+        unread = np.arange(B)[~active][_G - 1:]   # past the second group
+        assert len(unread) == B - 2 * _G
+        nobody = tables.copy()
+        nobody[unread] = poison
+        got = step(nobody)
+        assert np.isfinite(got).all()
+        np.testing.assert_array_equal(got[active], clean[active])
         if trips * chunk > n + 1 + eng.block_size:
             inside = tables.copy()
             inside[1, trips * cb - 1] = poison    # masked, but fetched
-            assert np.isnan(step(inside)).all()
+            row = step(inside)
+            assert np.isnan(row[1]).all()
+            np.testing.assert_array_equal(row[long_slots], clean[long_slots])
         eng.close()
 
     def test_counter_is_the_sum_of_the_steps_own_counts(self):
         from bigdl_tpu import telemetry
-        with _long_engine() as eng:
+        with _long_engine(max_batch=2 * _G) as eng:
             _, chunk = _chunks_of(eng)
             real, seen = eng._decode, []
 
@@ -412,20 +474,25 @@ class TestBoundedFetch:
             eng._decode = spy
             before = telemetry.counter("LM/kv_rows_fetched").value
             eng.start()
-            streams = [eng.submit(_prompt(n, seed=n), max_new_tokens=8)
-                       for n in (5, 124, 250)]
+            # three long-lived streams, one crossing 384, and a group's
+            # worth of short-lived ones: one trip, then two, then one again
+            lengths = (5, 124, 380) + tuple(30 + 40 * i for i in range(_G))
+            streams = [eng.submit(_prompt(n, seed=n),
+                                  max_new_tokens=8 if i < 3 else 3)
+                       for i, n in enumerate(lengths)]
             for s in streams:
                 s.result(timeout=60)
             stats = eng.stats()
-        want = sum(eng.max_batch * chunk
-                   * max(1, -(-int((p[a] + 1).max()) // chunk))
+        want = sum(_G * chunk * sum(_trips_of(p, a, chunk, _G))
                    for p, a in seen)
         assert len(seen) == stats["decode_steps"] and want > 0
         assert stats["kv_rows_fetched"] == want
         assert telemetry.counter("LM/kv_rows_fetched").value - before == want
-        # contexts crossed 128 and 256: the steps did not all read alike,
-        # and never fewer rows than the live contexts hold
-        assert len({int((p[a] + 1).max()) // chunk for p, a in seen}) > 1
+        # contexts crossed a chunk's edge and more than a group was live
+        # at once: the steps did not all read alike, and never fewer rows
+        # than the live contexts hold
+        assert len({tuple(_trips_of(p, a, chunk, _G)) for p, a in seen}) > 1
+        assert max(int(a.sum()) for _, a in seen) > _G
         assert stats["kv_rows_fetched"] >= stats["attn_context_tokens"] > 0
         # offline generate() is not the scheduler's: it counts nothing
         eng2 = _long_engine()
@@ -437,8 +504,10 @@ class TestBoundedFetch:
     def test_lowered_decode_holds_no_whole_layer_of_a_pool(self, quantize):
         """No slice, copy or gather in the lowered step has a whole layer
         of a pool, or every slot's whole table, as its result: the only
-        arrays of a pool's extent are the 5-D pools themselves."""
-        eng = _long_engine(quantize=quantize)
+        arrays of a pool's extent are the 5-D pools themselves; and the
+        loop's body gathers one chunk of a group's tables, not of every
+        slot's."""
+        eng = _wide_engine(quantize=quantize)
         cached, specs = ((eng._decode_q_cached, eng._decode_q_specs)
                          if quantize == "int8"
                          else (eng._decode_cached, eng._decode_specs))
@@ -448,11 +517,12 @@ class TestBoundedFetch:
         cb, chunk = _chunks_of(eng)
         assert f"tensor<{L}x{NB}x{bs}x{H}x{Dh}xf32>" in text
         for shape in ((NB, bs, H, Dh), (1, NB, bs, H, Dh),
-                      (B, MB, bs, H, Dh), (B, MB * bs, H, Dh)):
+                      (B, MB, bs, H, Dh), (B, MB * bs, H, Dh),
+                      (B, cb, bs, H, Dh)):
             assert "tensor<" + "x".join(map(str, shape)) + "xf32>" \
                 not in text, shape
-        # what the loop's body does gather: one chunk of every table
-        assert f"tensor<{B}x{cb}x{bs}x{H}x{Dh}xf32>" in text
+        # what the loop's body does gather: one chunk of a group's tables
+        assert f"tensor<{_G}x{cb}x{bs}x{H}x{Dh}xf32>" in text
         assert "stablehlo.while" in text
         eng.close()
 
@@ -471,6 +541,89 @@ class TestBoundedFetch:
         assert len(toks) == 4
         _zero_retraces(eng)
         assert len(eng.timings["lm_decode_int8"]) == 1
+        eng.close()
+
+
+#: live slot -> position, in a step of ``_WIDE`` slots: how many are live
+#: (none, one, three, a group, a group and one, all), where the longest
+#: sits, and a group whose members end in different chunks of its trips
+_OCCUPANCY = {
+    "none": {},
+    "one": {13: 300},
+    "three, longest last": {1: 40, 6: 130, 15: 390},
+    "a group, each in its own chunk": {
+        (3 + 5 * i) % _WIDE: 20 + 491 * i // max(_G - 1, 1)
+        for i in range(_G)},
+    "a group and one, longest in the middle": {
+        (11 * i) % _WIDE: (61 * i + 7) % 512 for i in range(_G + 1)},
+    "all": {s: (97 * s + 5) % 512 for s in range(_WIDE)},
+}
+
+
+@pytest.fixture(scope="module", params=["off", "int8"])
+def wide_tier(request):
+    eng = _wide_engine(quantize=request.param)
+    yield eng
+    eng.close()
+
+
+class TestSlotGroups:
+    @pytest.mark.parametrize("live", list(_OCCUPANCY), ids=list(_OCCUPANCY))
+    def test_grouped_step_equals_the_all_slot_step(self, wide_tier, live,
+                                                   monkeypatch):
+        """The served step, its slots read in groups of ``_G``, against
+        the same step built here with one group of every slot (what
+        ``paged_attention`` read before the groups: every slot as far as
+        the longest live context), on the same float32 pools: every live
+        row's log-probs agree to 1e-6 and its token is the same, and the
+        pools differ in the dump block alone."""
+        eng = wide_tier
+        fn, dp = _tier(eng)
+        B = eng.max_batch
+        monkeypatch.setattr(_lm, "_KV_GROUP", B)
+        every_slot = jax.jit(_lm._choosing(_lm._pooled(_lm._build_decode_fn(
+            eng.graph, eng.block_size, eng._max_blocks))))
+        tokens, positions, tables, active, pools = _live_inputs(
+            eng, _OCCUPANCY[live], seed=len(live))
+        got = fn(dp, *map(jnp.asarray, pools), tokens, positions, tables,
+                 active)
+        want = every_slot(dp, *map(jnp.asarray, pools), tokens, positions,
+                          tables, active)
+        (tok, lp, got_pools, _), (tok_w, lp_w, want_pools, _) = (
+            jax.tree_util.tree_map(np.asarray, got),
+            jax.tree_util.tree_map(np.asarray, want))
+        np.testing.assert_allclose(lp[active], lp_w[active], rtol=0,
+                                   atol=1e-6)
+        np.testing.assert_array_equal(tok[active], tok_w[active])
+        for g, w in zip(got_pools, want_pools):
+            assert np.isfinite(g).all()
+            np.testing.assert_array_equal(np.delete(g, DUMP_BLOCK, axis=1),
+                                          np.delete(w, DUMP_BLOCK, axis=1))
+        assert np.isfinite(lp).all()
+
+    def test_pools_stay_finite_and_one_program_serves_every_live_count(self):
+        """Steps at 1 to ``_WIDE`` live slots, one after another on the
+        same pools: the slots no trip reaches write the dump block, and it
+        and every pool stay free of NaN; one ``lm_decode`` program serves
+        every count, no retrace."""
+        config.set_property("bigdl.analysis.retrace", "strict")
+        eng = _wide_engine()
+        B = eng.max_batch
+        tokens, _, tables, _, pools = _live_inputs(eng, {}, seed=1)
+        eng.cache.pools = tuple(map(jnp.asarray, pools))
+        rng = np.random.default_rng(1)
+        for live in range(1, B + 1):
+            active = np.zeros((B,), bool)
+            active[rng.permutation(B)[:live]] = True
+            positions = rng.integers(0, 512, size=B).astype(np.int32)
+            tok, lp, eng.cache.pools, _ = eng._decode(
+                eng._dp, *eng.cache.pools, tokens, positions, tables, active)
+            assert np.isfinite(np.asarray(lp)).all(), live
+            for p in eng.cache.pools:
+                assert np.isfinite(np.asarray(p)).all(), live
+            tokens = np.asarray(tok)
+        _zero_retraces(eng)
+        assert len(eng.timings["lm_decode"]) == 1
         eng.close()
 
 

@@ -89,10 +89,14 @@ def test_lm_decode_reads_no_whole_layer_of_a_pool_on_the_chip(one_chip):
     """The LM server's decode step as the chip's compiler leaves it, pools
     donated: both pools aliased to their outputs (the scatters run in
     place, the attention loop carries the pools without a copy), no
-    operation whose result is a whole layer of a pool or every slot's
-    whole table, and temporaries smaller than one layer of one pool."""
+    operation whose result is a whole layer of a pool, every slot's whole
+    table or every slot's chunk (the loop reads a group of slots a trip),
+    and temporaries smaller than one layer of one pool.  Sixteen slots,
+    as the chat cell serves, so that the slots make more than one
+    group."""
     from bigdl_tpu.serving import lm as _lm
-    B, bs, MB = 4, 16, 32
+    B, bs, MB = 16, 16, 32
+    assert B > _lm._KV_GROUP
     model = transformer_lm(512, d_model=256, n_head=2, n_layers=2,
                            max_len=MB * bs)
     graph = _lm._LMGraph(model)
@@ -113,10 +117,14 @@ def test_lm_decode_reads_no_whole_layer_of_a_pool_on_the_chip(one_chip):
     assert mem.alias_size_in_bytes == 2 * L * layer_bytes
     assert mem.temp_size_in_bytes < layer_bytes, mem
     text = compiled.as_text()
+    cb = _lm._chunk_blocks(bs, MB)
     for shape in ((NB, bs, H, Dh), (1, NB, bs, H, Dh), (B, MB, bs, H, Dh),
-                  (B * MB, bs, H, Dh), (B, MB * bs, H, Dh)):
+                  (B * MB, bs, H, Dh), (B, MB * bs, H, Dh),
+                  (B, cb, bs, H, Dh)):
         made = [ln.strip()[:200] for ln in text.splitlines()
                 if re.search(r"= f32\[" + ",".join(map(str, shape)) + r"\]",
                              ln)]
         assert not made, made
+    group = ",".join(map(str, (_lm._KV_GROUP, cb, bs, H, Dh)))
+    assert re.search(r"= f32\[" + group + r"\]", text)
     assert " while(" in text
